@@ -14,39 +14,16 @@ import os as _os
 # framework default float stays float32.
 import jax as _jax
 _jax.config.update("jax_enable_x64", True)
-if not hasattr(_jax, "enable_x64"):
-    # jax >= 0.4.27 removed the deprecated jax.enable_x64 alias; the
-    # Pallas kernels trace under `with jax.enable_x64(False)` (their
-    # literals must stay 32-bit with the global x64 default above), so
-    # restore the alias from its new home
-    from jax.experimental import enable_x64 as _enable_x64
-    _jax.enable_x64 = _enable_x64
-if not hasattr(_jax, "shard_map"):
-    # older jax ships shard_map under jax.experimental with the
-    # check_rep keyword; the framework is written against the promoted
-    # jax.shard_map API (check_vma).  Bridge the call convention so the
-    # SPMD layers and the multichip dryrun run on either version.
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    def _shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                   check_vma=None, axis_names=None, **kwargs):
-        if check_vma is not None:
-            kwargs.setdefault("check_rep", check_vma)
-        if axis_names is not None:
-            # new API names the MANUAL axes; old API names the
-            # complement (axes left automatic)
-            kwargs.setdefault("auto", frozenset(mesh.axis_names)
-                              - frozenset(axis_names))
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, **kwargs)
-
-    _jax.shard_map = _shard_map
-if not hasattr(_jax.lax, "axis_size"):
-    # promoted in newer jax; psum of a literal 1 is folded statically
-    def _axis_size(axis_name):
-        return _jax.lax.psum(1, axis_name)
-
-    _jax.lax.axis_size = _axis_size
+# The one place that decides where compiled programs persist.  When
+# JAX_COMPILATION_CACHE_DIR is set the directory is JAX's own business
+# and nothing in this package names another; otherwise it is a fixed
+# path beside the checkout (the path is part of the cache key, so it
+# must not move between runs or processes).
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        _os.path.join(_os.path.dirname(_os.path.dirname(
+            _os.path.abspath(__file__))), ".jax_cache"))
 
 __version__ = "0.3.0"  # kept in sync with paddle.version.full_version
 

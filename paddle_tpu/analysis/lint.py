@@ -1046,7 +1046,7 @@ def iter_python_files(paths: Sequence[str]) -> List[str]:
             for root, dirs, files in os.walk(p):
                 dirs[:] = [d for d in dirs
                            if d not in ("__pycache__", ".git",
-                                        ".xla_cache")]
+                                        ".jax_cache")]
                 for f in sorted(files):
                     if f.endswith(".py"):
                         out.append(os.path.join(root, f))

@@ -118,15 +118,16 @@ def current_place() -> Place:
 
 
 def jax_device(place: Optional[Place] = None):
-    """Resolve a Place to a jax.Device (None → framework default)."""
+    """Resolve a Place to a jax.Device (None → framework default).  An
+    id past the attached devices raises."""
     place = place or current_place()
-    if isinstance(place, CPUPlace):
-        try:
-            return jax.devices("cpu")[place.get_device_id()]
-        except RuntimeError:
-            return jax.devices()[0]
-    devs = jax.devices()
-    return devs[place.get_device_id() % len(devs)]
+    devs = jax.devices("cpu") if isinstance(place, CPUPlace) \
+        else jax.devices()
+    i = place.get_device_id()
+    if not 0 <= i < len(devs):
+        raise ValueError(f"{place!r}: device id {i} out of range "
+                         f"({len(devs)} {devs[0].platform} device(s))")
+    return devs[i]
 
 
 def get_all_devices():
@@ -204,16 +205,19 @@ TPU_PEAK_BF16 = {
 }
 
 
-def chip_peak_flops(device=None, default: float = 1e12) -> float:
-    """Peak bf16 FLOPs of the attached chip, keyed on device_kind;
-    ``default`` for non-TPU backends (CPU test mesh)."""
+def chip_peak_flops(device=None) -> float:
+    """Peak bf16 FLOPs of the attached chip, keyed on device_kind.  A
+    kind that is not in the table raises: a utilization computed from a
+    guessed peak is worse than none."""
     d = device if device is not None else jax.devices()[0]
     kind = getattr(d, "device_kind", "").lower().replace(" ", "")
     for key, peak in sorted(TPU_PEAK_BF16.items(),
                             key=lambda kv: -len(kv[0])):
         if key in kind:
             return peak
-    return default
+    raise ValueError(f"no peak FLOPs on record for device_kind "
+                     f"{getattr(d, 'device_kind', None)!r} "
+                     f"(known: {sorted(TPU_PEAK_BF16)})")
 
 
 class _CudaNamespace:

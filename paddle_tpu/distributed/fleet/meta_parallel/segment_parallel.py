@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ....core.dispatch import call_op
+from ....flags import get_flag
 from ....ops.ring_attention import ring_attention_bhsd
 from ....ops.ulysses import ulysses_attention
 from ....ops.flash_attention import DEFAULT_BLOCK_Q
@@ -68,8 +69,7 @@ def active_seq_parallel_axis() -> Optional[Tuple[str, int]]:
 
 
 def _interpret() -> bool:
-    # Pallas kernels need interpret mode off-TPU (the CPU test mesh)
-    return jax.default_backend() != "tpu"
+    return bool(get_flag("pallas_interpret"))
 
 
 def sep_attention(query, key, value, is_causal: bool = True, scale=None):

@@ -96,6 +96,18 @@ def in_axis_scope(axis_name) -> bool:
         return False
 
 
+def auto_axes() -> Tuple[str, ...]:
+    """Axes of the global mesh with degree > 1 that the current trace
+    leaves to GSPMD (not bound by an enclosing shard_map).  Non-empty
+    means a Mosaic kernel called here would have to be partitioned
+    automatically, which XLA refuses."""
+    mesh = _global_mesh
+    if mesh is None:
+        return ()
+    return tuple(a for a in mesh.axis_names
+                 if mesh.shape[a] > 1 and not in_axis_scope(a))
+
+
 def axis_degree(mesh: Mesh, axis_name) -> int:
     names = axis_name if isinstance(axis_name, (tuple, list)) else (axis_name,)
     d = 1

@@ -79,10 +79,7 @@ def _is_staged(v) -> bool:
     """True iff `v` is (or wraps, through JVP/batch tracer levels) a
     jaxpr-staging tracer — i.e. we are inside a jit/pjit trace rather
     than an eagerly-executing vjp/vmap over concrete arrays."""
-    try:
-        from jax._src.interpreters.partial_eval import DynamicJaxprTracer
-    except ImportError:  # jax internals moved: conservatively say staged
-        return isinstance(v, jax.core.Tracer)
+    from jax._src.interpreters.partial_eval import DynamicJaxprTracer
     seen = set()
     while isinstance(v, jax.core.Tracer):
         if isinstance(v, DynamicJaxprTracer):

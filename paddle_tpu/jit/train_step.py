@@ -307,8 +307,13 @@ class TrainStep:
         # decommit the key from this step's mesh — otherwise every later
         # random init (jax.random.split chains shardings) is pinned to it.
         # device_put avoids the host round-trip sync np.asarray would force.
+        # Without a mesh nothing is committed, and committing the key
+        # here would commit every output of the next step and cost the
+        # step after it a third XLA compile.
+        rng = new_state["rng"]
         default_generator.set_state(
-            jax.device_put(new_state["rng"], jax.devices()[0]))
+            rng if self.mesh is None
+            else jax.device_put(rng, jax.devices()[0]))
         return Tensor(loss)
 
 

@@ -73,25 +73,16 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         if _pfa.available():
             reason = _pfa.reject_reason(
                 query.shape[1], key.shape[1], query.shape[-1], is_causal,
-                hq, hkv)
+                hq, hkv, query.shape[0])
             if reason is not None:
                 # the user ASKED for the flash path (flag on, backend
                 # eligible) and a shape detail silently denied it —
                 # tell them once per cause, keep counts queryable
                 _pfa.note_fallback(reason)
         if reason is None:
-            try:
-                return _pfa.pallas_flash_attention(query, key, value,
-                                                   causal=is_causal)
-            except Exception as e:
-                # eager-mode Mosaic failures fall back to XLA — loudly,
-                # so real wrapper bugs aren't silently masked.  (Under an
-                # enclosing jit, lowering errors surface at compile time
-                # and propagate regardless.)
-                import warnings
-                warnings.warn(
-                    f"pallas flash attention failed ({type(e).__name__}: "
-                    f"{e}); falling back to the XLA path", RuntimeWarning)
+            # a selected kernel that fails raises: no XLA retry
+            return _pfa.pallas_flash_attention(query, key, value,
+                                               causal=is_causal)
     _expand_kv()
     args = [query, key, value]
     if has_mask:

@@ -20,16 +20,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ...flags import get_flag
+from . import kernel_enabled
 
 _LANES = 128
 
 
 def available() -> bool:
-    if not get_flag("use_pallas_adamw"):
-        return False
-    if get_flag("pallas_interpret"):
-        return True
-    return jax.default_backend() == "tpu"
+    return kernel_enabled("use_pallas_adamw")
 
 
 def _adamw_kernel(s_ref, p_ref, g_ref, m_ref, v_ref,

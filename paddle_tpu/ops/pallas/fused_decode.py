@@ -43,6 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ...flags import get_flag
+from . import kernel_enabled
 
 # VMEM budget gate: a kernel whose resident weights exceed this falls
 # back to the reference composition (XLA streams it instead)
@@ -50,11 +51,7 @@ _VMEM_BUDGET_BYTES = 10 << 20
 
 
 def available() -> bool:
-    if not get_flag("use_pallas_fused_decode"):
-        return False
-    if get_flag("pallas_interpret"):
-        return True
-    return jax.default_backend() == "tpu"
+    return kernel_enabled("use_pallas_fused_decode")
 
 
 def _interpret() -> bool:

@@ -17,19 +17,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import kernel_enabled
+
 DEFAULT_BLOCK_N = 256
 
 
 def available() -> bool:
-    from ...flags import get_flag
-    if not get_flag("use_pallas_layer_norm"):
-        return False
-    if get_flag("pallas_interpret"):
-        return True
-    return jax.default_backend() == "tpu"
+    return kernel_enabled("use_pallas_layer_norm")
 
 
-def _fwd_kernel(x_ref, w_ref, b_ref, o_ref, m_ref, r_ref, *, eps: float):
+def _ln_fwd_kernel(x_ref, w_ref, b_ref, o_ref, m_ref, r_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
     w = w_ref[...].astype(jnp.float32)
     b = b_ref[...].astype(jnp.float32)
@@ -41,7 +38,7 @@ def _fwd_kernel(x_ref, w_ref, b_ref, o_ref, m_ref, r_ref, *, eps: float):
     r_ref[...] = r
 
 
-def _bwd_kernel(x_ref, w_ref, m_ref, r_ref, g_ref, dx_ref):
+def _ln_bwd_kernel(x_ref, w_ref, m_ref, r_ref, g_ref, dx_ref):
     x = x_ref[...].astype(jnp.float32)
     w = w_ref[...].astype(jnp.float32)
     m = m_ref[...]
@@ -61,7 +58,7 @@ def _fwd(x2d, w, b, eps: float, block_n: int, interpret: bool):
     grid = (pl.cdiv(n, bn),)
     with jax.enable_x64(False):
         out, m, r = pl.pallas_call(
-            functools.partial(_fwd_kernel, eps=eps),
+            functools.partial(_ln_fwd_kernel, eps=eps),
             grid=grid,
             in_specs=[pl.BlockSpec((bn, h), lambda i: (i, 0)),
                       pl.BlockSpec((h,), lambda i: (0,)),
@@ -83,7 +80,7 @@ def _bwd_dx(x2d, w, m, r, g2d, block_n: int, interpret: bool):
     grid = (pl.cdiv(n, bn),)
     with jax.enable_x64(False):
         return pl.pallas_call(
-            _bwd_kernel,
+            _ln_bwd_kernel,
             grid=grid,
             in_specs=[pl.BlockSpec((bn, h), lambda i: (i, 0)),
                       pl.BlockSpec((h,), lambda i: (0,)),

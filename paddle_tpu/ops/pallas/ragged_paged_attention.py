@@ -54,6 +54,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...flags import get_flag
+from . import kernel_enabled
 
 __all__ = ["ragged_paged_attention", "ragged_paged_attention_ref",
            "append_positions", "available"]
@@ -77,11 +78,7 @@ def append_positions(kv_lens, tables, live, page_size, sink):
 
 
 def available() -> bool:
-    if not get_flag("use_pallas_ragged_attention"):
-        return False
-    if get_flag("pallas_interpret"):
-        return True
-    return jax.default_backend() == "tpu"
+    return kernel_enabled("use_pallas_ragged_attention")
 
 
 def _interpret() -> bool:
@@ -138,14 +135,37 @@ def ragged_paged_attention_ref(q, k_pages, v_pages, kv_lens, q_lens,
 # the Pallas kernel
 # ---------------------------------------------------------------------------
 
+# Scoped VMEM is 16 MiB on v5e; the q tile is sized to use at most half
+# of it, leaving the rest to the k/v page blocks and Mosaic's own temps.
+_VMEM_TILE_BUDGET = 8 << 20
+_MAX_BLOCK_Q = 128
+
+
+def _block_q(nh: int, hd: int, itemsize: int) -> int:
+    """Query rows per grid tile: the largest power of two (8..128)
+    whose VMEM residents fit ``_VMEM_TILE_BUDGET`` at this model's
+    ``(nh, hd, dtype)``.  Per (head, row) the kernel holds the q and
+    out blocks double-buffered (4 x itemsize), the fp32 accumulator and
+    the fp32 copy of q (8 bytes) over ``hd`` padded to the 128-lane
+    tile, plus the lane-padded ``[rows, 1]`` max/denominator scratch
+    and the ``[rows, page]`` logits/probabilities (~6 fp32 lane rows)."""
+    lanes = -(-hd // 128) * 128
+    per_row = nh * (lanes * (4 * itemsize + 8) + 6 * 128 * 4)
+    bq = _MAX_BLOCK_Q
+    while bq > 8 and bq * per_row > _VMEM_TILE_BUDGET:
+        bq //= 2
+    return bq
+
+
 def _ragged_kernel(kv_lens_ref, q_lens_ref, tables_ref, q_ref, k_ref,
                    v_ref, o_ref, acc_ref, m_ref, d_ref, *, n_kv: int,
-                   n_rep: int, q_width: int, page_size: int,
+                   n_rep: int, block_q: int, page_size: int,
                    pages_per_seq: int, scale: float):
     b = pl.program_id(0)
-    p = pl.program_id(1)
+    t = pl.program_id(1)
+    p = pl.program_id(2)
     nh = n_kv * n_rep
-    rows = nh * q_width
+    rows = nh * block_q
 
     @pl.when(p == 0)
     def _init():
@@ -155,29 +175,37 @@ def _ragged_kernel(kv_lens_ref, q_lens_ref, tables_ref, q_ref, k_ref,
 
     kv_len = kv_lens_ref[b]
     q_len = q_lens_ref[b]
+    q0 = jnp.int32(block_q) * t          # first query row of this tile
+    page0 = jnp.int32(page_size) * p     # first kv position of the page
 
-    # pages at or past ceil(kv_len / page_size) hold no attendable
-    # slot for this sequence (their table entries fetch page 0, fully
-    # masked) — skip their dot products entirely, so per-step compute
-    # scales with the sequence's OWN length, not the padded maximum
-    @pl.when(jnp.int32(page_size) * p < kv_len)
+    # skip the dot products of (a) pages at or past ceil(kv_len / ps)
+    # (their table entries fetch page 0, fully masked), (b) query tiles
+    # past q_len (pure padding — a decode lane in a prefill-wide step
+    # computes one tile) and (c) pages wholly above the tile's last
+    # causal position — so compute scales with the sequence's OWN
+    # lengths, not the padded maxima
+    @pl.when((page0 < kv_len) & (q0 < q_len)
+             & (page0 < kv_len - q_len + q0 + jnp.int32(block_q)))
     def _compute():
         # [rows, ps] index planes: query row i of head h sits at flat
-        # row h*Q + i; its absolute position is kv_len - q_len + i
-        qi = jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0) \
-            % jnp.int32(q_width)
-        kvpos = jnp.int32(page_size) * p \
-            + jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 1)
+        # row h*block_q + i; its absolute position is kv_len - q_len +
+        # q0 + i
+        qi = q0 + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, page_size), 0) % jnp.int32(block_q)
+        kvpos = page0 + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, page_size), 1)
         qpos = kv_len - q_len + qi
         mask = (kvpos <= qpos) & (kvpos < kv_len)
-        qf = jnp.swapaxes(q_ref[0], 0, 1).reshape(rows, -1) \
-            .astype(jnp.float32)                         # [nh*Q, hd]
+        # the wrapper hands q heads-major with block_q a multiple of
+        # the 8-sublane tile, so this collapse is layout-trivial
+        qf = q_ref[0].astype(jnp.float32).reshape(rows, -1)
         for g in range(n_kv):                            # static GQA loop
-            sl = slice(g * n_rep * q_width, (g + 1) * n_rep * q_width)
+            sl = slice(g * n_rep * block_q, (g + 1) * n_rep * block_q)
             kg = k_ref[g, 0].astype(jnp.float32)         # [ps, hd]
             vg = v_ref[g, 0].astype(jnp.float32)
             s = jax.lax.dot_general(qf[sl], kg,
-                                    (((1,), (1,)), ((), ()))) \
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) \
                 * jnp.float32(scale)
             s = jnp.where(mask[sl], s, jnp.float32(-1e30))
             m_prev = m_ref[sl]                           # [rows_g, 1]
@@ -192,7 +220,8 @@ def _ragged_kernel(kv_lens_ref, q_lens_ref, tables_ref, q_ref, k_ref,
                 + jnp.sum(prob, axis=-1, keepdims=True)
             acc_ref[sl] = acc_ref[sl] * alpha \
                 + jax.lax.dot_general(prob, vg,
-                                      (((1,), (0,)), ((), ())))
+                                      (((1,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
             m_ref[sl] = m_new
 
     @pl.when(p == pages_per_seq - 1)
@@ -200,8 +229,7 @@ def _ragged_kernel(kv_lens_ref, q_lens_ref, tables_ref, q_ref, k_ref,
         d = d_ref[...]
         out = jnp.where(d > jnp.float32(0.0), acc_ref[...] / d,
                         jnp.float32(0.0))
-        o_ref[0] = jnp.swapaxes(out.reshape(nh, q_width, -1), 0, 1) \
-            .astype(o_ref.dtype)
+        o_ref[0] = out.reshape(nh, block_q, -1).astype(o_ref.dtype)
 
 
 def _ragged_pallas(q, k_pages, v_pages, kv_lens, q_lens, page_tables,
@@ -209,36 +237,48 @@ def _ragged_pallas(q, k_pages, v_pages, kv_lens, q_lens, page_tables,
     b, qw, nh, hd = q.shape
     nkv, _, ps, _ = k_pages.shape
     ppseq = page_tables.shape[1]
+    # Mosaic tiles the second-minor dim by 8 sublanes: a decode step's
+    # one-row chunk is padded up to a whole tile, and a wide prefill
+    # chunk is cut into block_q-row tiles along a grid axis so VMEM
+    # holds one tile, not the whole chunk
+    bq = min(_block_q(nh, hd, q.dtype.itemsize), -(-qw // 8) * 8)
+    qp = -(-qw // bq) * bq
+    # heads-major [B, nh, Q, hd]: the kernel collapses (nh, block_q)
+    # into flat rows without an in-kernel transpose
+    qt = jnp.swapaxes(q, 1, 2)
+    if qp != qw:
+        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, qp - qw), (0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, ppseq),
+        grid=(b, qp // bq, ppseq),
         in_specs=[
-            pl.BlockSpec((1, qw, nh, hd),
-                         lambda i, p, kl, ql, tb: (i, 0, 0, 0)),
+            pl.BlockSpec((1, nh, bq, hd),
+                         lambda i, t, p, kl, ql, tb: (i, 0, t, 0)),
             pl.BlockSpec((nkv, 1, ps, hd),
-                         lambda i, p, kl, ql, tb: (0, tb[i, p], 0, 0)),
+                         lambda i, t, p, kl, ql, tb: (0, tb[i, p], 0, 0)),
             pl.BlockSpec((nkv, 1, ps, hd),
-                         lambda i, p, kl, ql, tb: (0, tb[i, p], 0, 0)),
+                         lambda i, t, p, kl, ql, tb: (0, tb[i, p], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, qw, nh, hd),
-                               lambda i, p, kl, ql, tb: (i, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, nh, bq, hd),
+                               lambda i, t, p, kl, ql, tb: (i, 0, t, 0)),
         scratch_shapes=[
-            pltpu.VMEM((nh * qw, hd), jnp.float32),   # acc
-            pltpu.VMEM((nh * qw, 1), jnp.float32),    # running max
-            pltpu.VMEM((nh * qw, 1), jnp.float32),    # denominator
+            pltpu.VMEM((nh * bq, hd), jnp.float32),   # acc
+            pltpu.VMEM((nh * bq, 1), jnp.float32),    # running max
+            pltpu.VMEM((nh * bq, 1), jnp.float32),    # denominator
         ],
     )
     with jax.enable_x64(False):
-        return pl.pallas_call(
+        out = pl.pallas_call(
             functools.partial(_ragged_kernel, n_kv=nkv,
-                              n_rep=nh // nkv, q_width=qw,
+                              n_rep=nh // nkv, block_q=bq,
                               page_size=ps, pages_per_seq=ppseq,
                               scale=float(scale)),
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, qw, nh, hd), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((b, nh, qp, hd), q.dtype),
             interpret=_interpret(),
         )(kv_lens.astype(jnp.int32), q_lens.astype(jnp.int32),
-          page_tables.astype(jnp.int32), q, k_pages, v_pages)
+          page_tables.astype(jnp.int32), qt, k_pages, v_pages)
+    return jnp.swapaxes(out[:, :, :qw], 1, 2)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, kv_lens, q_lens,

@@ -20,18 +20,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import kernel_enabled
+
 DEFAULT_BLOCK_N = 256
 
 
 def available() -> bool:
-    """Pallas rms_norm routing gate — its own flag, independent of the
-    attention kernel's."""
-    from ...flags import get_flag
-    if not get_flag("use_pallas_rms_norm"):
-        return False
-    if get_flag("pallas_interpret"):
-        return True
-    return jax.default_backend() == "tpu"
+    return kernel_enabled("use_pallas_rms_norm")
 
 
 def _fwd_kernel(x_ref, w_ref, o_ref, r_ref, *, eps: float):

@@ -24,15 +24,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ...flags import get_flag
+from . import kernel_enabled
 
 
 def available() -> bool:
-    if not get_flag("use_pallas_rope"):
-        return False
-    if get_flag("pallas_interpret"):
-        return True
-    return jax.default_backend() == "tpu"
+    return kernel_enabled("use_pallas_rope")
 
 
 def supports(d: int) -> bool:

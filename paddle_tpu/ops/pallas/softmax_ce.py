@@ -18,16 +18,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import kernel_enabled
+
 DEFAULT_BLOCK_N = 16      # x (bn, V) fp32 in VMEM: 16 x 50304 x 4 = 3.2MB
 
 
 def available() -> bool:
-    from ...flags import get_flag
-    if not get_flag("use_pallas_softmax_ce"):
-        return False
-    if get_flag("pallas_interpret"):
-        return True
-    return jax.default_backend() == "tpu"
+    return kernel_enabled("use_pallas_softmax_ce")
 
 
 def _fwd_kernel(x_ref, lab_ref, o_ref, lse_ref, *, ignore_index: int):

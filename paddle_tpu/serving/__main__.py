@@ -50,6 +50,7 @@ def main(argv=None) -> int:
 
     engine = ServingEngine(model, max_batch=args.max_batch,
                            page_size=16)
+    failed = []
     with engine:
         if args.serve:
             from paddle_tpu.flags import set_flags
@@ -60,8 +61,14 @@ def main(argv=None) -> int:
             print(f"serving on {srv.url}  (POST /generate)")
 
             def run(i, ids):
-                toks = list(generate_http(srv.url, ids,
-                                          max_new_tokens=args.max_new))
+                try:
+                    toks = list(generate_http(
+                        srv.url, ids, max_new_tokens=args.max_new))
+                except (RuntimeError, OSError) as e:
+                    failed.append(i)
+                    print(f"request {i}: prompt[{len(ids)}] -> "
+                          f"FAILED: {e}")
+                    return
                 print(f"request {i}: prompt[{len(ids)}] -> {toks}")
 
             threads = [threading.Thread(target=run, args=(i, p))
@@ -80,13 +87,14 @@ def main(argv=None) -> int:
                 except (RuntimeError, TimeoutError) as e:
                     # a quarantined / deadline-cancelled request fails
                     # alone — the remaining streams still complete
+                    failed.append(i)
                     print(f"request {req.id}: prompt[{len(prompts[i])}] "
                           f"-> FAILED ({req.error_kind}): {e}")
                     continue
                 print(f"request {req.id}: prompt[{len(prompts[i])}] "
                       f"-> {toks}")
         print("engine stats:", engine.stats())
-    return 0
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":

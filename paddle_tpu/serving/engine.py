@@ -49,7 +49,9 @@ in-flight stream.  Four interlocking pieces bound the blast radius:
   machinery makes re-running a chunk token-exact under greedy
   decode); innocents complete unchanged, the isolated offender fails
   alone with a ``quarantine`` event, and its prompt hash is rejected
-  at admission from then on;
+  at admission from then on (a step that fails while its program is
+  still compiling, or after its pools were donated, is a program
+  fault instead: it fails the engine with the compiler's message);
 * **hung-step watchdog** — ``FLAGS_serving_step_timeout_s`` bounds
   every device dispatch; on expiry the flight recorder dumps, the
   iteration loop relaunches under a new epoch with fresh device pools
@@ -564,6 +566,15 @@ class ServingEngine:
             except Exception as e:  # noqa: BLE001 — containment, not
                 # crash-out: the batch is retried by bisection and
                 # only the isolated offender fails
+                if self._dispatch_cold or self._pools[0][0].is_deleted():  # noqa: PTL902 — loop thread is the sole writer of both
+                    # a PROGRAM fault, not a poisoned request: the
+                    # dispatch was still tracing/compiling (a Mosaic or
+                    # VMEM refusal would repeat for every request that
+                    # reaches this Q bucket), or the failure came after
+                    # the pools were donated and there is nothing left
+                    # to retry against.  _loop fails the engine loudly
+                    # with the compiler's message on every request
+                    raise
                 warnings.warn(f"serving step failed: "
                               f"{type(e).__name__}: {e}", stacklevel=1)
                 self._contain_step_failure(plan, e, epoch)
@@ -863,7 +874,11 @@ class ServingEngine:
             ",".join(map(str, prompt)).encode()).hexdigest()[:16]
 
     def _contain_step_failure(self, plan, exc, epoch: int) -> None:
-        """A dispatch raised.  Nothing was committed (tokens only land
+        """A WARM dispatch raised before it consumed its inputs (the
+        caller routes cold dispatches and failures that arrive after
+        the pools were donated to the engine-level failure path; off
+        CPU the pools are donated, so a retry is only sound while they
+        are still alive).  Nothing was committed (tokens only land
         after the boundary read), so re-feeding the same chunks to the
         same pages is idempotent — instead of failing the whole batch,
         split its live members in half and probe each half as its own

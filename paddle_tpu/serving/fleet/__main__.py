@@ -40,16 +40,7 @@ def _build_tiny_model(args):
 
 
 def run_worker(args) -> int:
-    # honor an env-pinned platform before any device is touched (the
-    # supervisor forwards JAX_PLATFORMS so CPU tests/benches stay off
-    # the accelerator)
-    platform = os.environ.get("JAX_PLATFORMS")
-    if platform:
-        try:
-            import jax
-            jax.config.update("jax_platforms", platform)
-        except (ImportError, ValueError):
-            pass
+    import jax
     from paddle_tpu.flags import set_flags
     from paddle_tpu.inference.serving import InferenceServer
     from paddle_tpu.serving import ServingEngine
@@ -61,6 +52,8 @@ def run_worker(args) -> int:
     engine.start()
     srv = InferenceServer(engine=engine, host=args.host, port=args.port,
                           max_in_flight=args.max_in_flight).start()
+    print(f"replica {args.replica_id}: {srv.url} on {jax.devices()}",
+          flush=True)
     # atomic publish: the supervisor polls for this file; a torn read
     # must be impossible
     tmp = args.port_file + ".tmp"
@@ -92,6 +85,7 @@ def run_demo(args) -> int:
                    "--max-batch", str(args.max_batch),
                    "--page-size", str(args.page_size)]
     sup = ReplicaSupervisor(args.replicas, worker_args=worker_args)
+    failed = []
     print(f"launching {args.replicas} replica(s)...")
     with sup:
         router = FleetRouter(sup, page_size=args.page_size)
@@ -109,8 +103,14 @@ def run_demo(args) -> int:
                                        - len(prompts))]
 
             def run(i, ids):
-                toks = list(generate_http(
-                    router.url, ids, max_new_tokens=args.max_new))
+                try:
+                    toks = list(generate_http(
+                        router.url, ids, max_new_tokens=args.max_new))
+                except (RuntimeError, OSError) as e:
+                    failed.append(i)
+                    print(f"request {i}: prompt[{len(ids)}] -> "
+                          f"FAILED: {e}")
+                    return
                 print(f"request {i}: prompt[{len(ids)}] -> {toks}")
 
             threads = [threading.Thread(target=run, args=(i, p))
@@ -123,7 +123,7 @@ def run_demo(args) -> int:
             for t in threads:
                 t.join()
             print("fleet stats:", router.fleet_stats())
-    return 0
+    return 1 if failed else 0
 
 
 def main(argv=None) -> int:

@@ -154,6 +154,14 @@ class ReplicaSupervisor:
     # -- process control --------------------------------------------------
     def _child_env(self, handle: ReplicaHandle) -> Dict[str, str]:
         env = dict(os.environ)
+        if env.get("JAX_PLATFORMS") != "cpu":
+            # a chip belongs to one process: unless the fleet is held
+            # to the CPU, replica i sees only chip i (and is its own
+            # one-process TPU job), or every worker would claim every
+            # chip of the host
+            env.update(TPU_VISIBLE_CHIPS=handle.id,
+                       TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                       TPU_PROCESS_BOUNDS="1,1,1")
         for k, v in self._env.items():
             env[k] = v.format(replica=handle.id) if "{replica}" in v \
                 else v

@@ -592,6 +592,27 @@ def stub_supervisor(tmp_path, obs_dir):
         sup.stop()
 
 
+def test_supervisor_gives_each_replica_its_own_chip(monkeypatch):
+    """A chip belongs to one process: unless the fleet is held to the
+    CPU, replica i is launched seeing only chip i (as a one-process
+    TPU job); the caller's ``env`` overlay still wins."""
+    sup = ReplicaSupervisor(3, env={"FLAGS_observability_dir":
+                                    "/obs/{replica}"})
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    env = sup._child_env(sup.replicas[2])
+    assert "TPU_VISIBLE_CHIPS" not in env
+    assert env["FLAGS_observability_dir"] == "/obs/2"
+    monkeypatch.delenv("JAX_PLATFORMS")
+    envs = [sup._child_env(h) for h in sup.replicas]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2"]
+    assert all(e["TPU_PROCESS_BOUNDS"] == "1,1,1"
+               and e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+               for e in envs)
+    pinned = ReplicaSupervisor(1, env={"TPU_VISIBLE_CHIPS": "3"})
+    assert pinned._child_env(pinned.replicas[0])["TPU_VISIBLE_CHIPS"] \
+        == "3"
+
+
 def test_supervisor_restarts_killed_replica(stub_supervisor, obs_dir):
     sup = stub_supervisor
     assert all(h.url for h in sup.replicas)

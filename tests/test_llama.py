@@ -20,7 +20,7 @@ from paddle_tpu.models import (LlamaForCausalLM, llama_config,
 def _private_xla_cache(tmp_path_factory):
     """De-flake: the hybrid tp x dp step SIGSEGVs/SIGABRTs ~60% of runs
     when its executable loads WARM from the shared persistent XLA cache
-    (tests/.xla_cache) — a pre-existing jax-0.4.37 CPU-executable
+    (<checkout>/.jax_cache) — a pre-existing jax-0.4.37 CPU-executable
     deserialization fragility; cold-cache runs are stable.  Point this
     module at a fresh per-run cache dir so its compiles are always cold
     (a few extra seconds) and restore the shared cache afterwards."""

@@ -36,9 +36,14 @@ def _fresh():
 
 @pytest.fixture(autouse=True)
 def _cleanup():
+    # the sep/cp routes call the flash kernels directly: on the CPU
+    # mesh they run in interpret mode, like every other kernel test
+    keep = paddle.get_flags(["FLAGS_pallas_interpret"])
+    paddle.set_flags({"FLAGS_pallas_interpret": True})
     _fresh()
     yield
     _fresh()
+    paddle.set_flags(keep)
 
 
 def _init_fleet(**degrees):

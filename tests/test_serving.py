@@ -48,6 +48,27 @@ def _greedy_reference(model, prompts, n_new):
     return out
 
 
+def _prompt_with_midway_eos(model, n_new=8):
+    """(prompt, full greedy continuation, eos): a prompt whose greedy
+    run emits a second distinct token MIDWAY, so that stopping on it is
+    observable.  The tiny random GPT mostly repeats one token, and
+    which prompts do not depends on the installation's RNG — so scan a
+    seeded batch instead of pinning one seed."""
+    cand = np.random.RandomState(0).randint(0, 128, (64, 5))
+    out = np.asarray(model.generate(
+        Tensor(cand.astype("int64")), max_new_tokens=n_new,
+        decode_strategy="greedy")._data)[:, 5:]
+    for p, row in zip(cand.tolist(), out.tolist()):
+        first_other = next((i for i, t in enumerate(row) if t != row[0]),
+                           None)
+        if first_other is None or not 3 <= first_other <= n_new - 2:
+            continue
+        [full] = _greedy_reference(model, [p], n_new)
+        if full == row:
+            return p, full, full[first_other]
+    raise AssertionError("no candidate prompt changes token midway")
+
+
 # ---------------------------------------------------------------------------
 # ragged paged attention kernel
 # ---------------------------------------------------------------------------
@@ -87,6 +108,41 @@ def test_ragged_kernel_matches_reference_interpret(flags_guard, rng,
     # the zero-length padding row must be exactly zero, never NaN
     assert np.all(np.isfinite(np.asarray(out)))
     assert np.all(np.asarray(out)[2] == 0.0)
+
+
+@pytest.mark.parametrize("qw", [1, 300], ids=["decode-only", "wide"])
+def test_ragged_kernel_q1_and_tiled_chunk(flags_guard, rng, qw):
+    """The two shapes the chip refused before the repair: Q=1 (every
+    decode-only step; the wrapper pads it to the 8-sublane tile) and a
+    chunk wider than one VMEM tile (cut into block_q-row grid tiles;
+    300 rows = three 128-row tiles with a ragged tail), with a decode
+    lane and an empty lane riding in the wide step."""
+    from paddle_tpu.ops.pallas import ragged_paged_attention as rpa
+    import jax.numpy as jnp
+    set_flags({"FLAGS_pallas_interpret": True})
+    nh, nkv, hd, ps, ppseq, b = 4, 2, 8, 16, 20, 4
+    assert qw == 1 or qw > rpa._block_q(nh, hd, 4)
+    q = jnp.asarray(rng.randn(b, qw, nh, hd).astype("float32"))
+    kp = jnp.asarray(rng.randn(nkv, b * ppseq, ps, hd).astype("float32"))
+    vp = jnp.asarray(rng.randn(nkv, b * ppseq, ps, hd).astype("float32"))
+    tables = jnp.asarray(rng.permutation(b * ppseq).reshape(b, ppseq)
+                         .astype("int32"))
+    if qw == 1:
+        kv_lens = np.array([1, 17, 0, 320], "int32")
+        q_lens = np.array([1, 1, 0, 1], "int32")
+    else:
+        kv_lens = np.array([300, 77, 0, 310], "int32")
+        q_lens = np.array([300, 1, 0, 130], "int32")
+    ref = np.asarray(rpa.ragged_paged_attention_ref(
+        q, kp, vp, kv_lens, q_lens, tables))
+    out = np.asarray(rpa.ragged_paged_attention(
+        q, kp, vp, kv_lens, q_lens, tables))
+    assert out.shape == ref.shape
+    for i in range(b):
+        n = int(q_lens[i])
+        np.testing.assert_allclose(out[i, :n], ref[i, :n],
+                                   rtol=2e-5, atol=2e-5)
+    assert np.all(np.isfinite(out)) and np.all(out[2] == 0.0)
 
 
 def test_ragged_reference_matches_dense_attention(rng):
@@ -368,12 +424,9 @@ def test_engine_matches_generate_llama_gqa():
 
 
 def test_engine_eos_stops_and_frees_pages(gpt_model):
-    rs = np.random.RandomState(0)
-    prompt = rs.randint(0, 128, (5,)).tolist()
-    [full] = _greedy_reference(gpt_model, [prompt], 8)
-    # pick an eos the greedy run first emits MIDWAY so the truncation
-    # is observable (seed 0: [67 x5, 63, 63, 63] -> eos=63)
-    eos = next(t for t in full if t != full[0])
+    # an eos the greedy run first emits MIDWAY so the truncation is
+    # observable
+    prompt, full, eos = _prompt_with_midway_eos(gpt_model)
     # eager generate() with the same eos is the parity oracle
     want_t = gpt_model.generate(Tensor(np.asarray([prompt], "int64")),
                                 max_new_tokens=8, eos_token_id=eos,
@@ -764,10 +817,7 @@ def test_fused_engine_eos_mid_window_early_exit(gpt_model, fused_flags,
     iteration (not at the window bound), output truncates exactly like
     the eager oracle, and the batch_step record says why it exited."""
     from paddle_tpu.observability import events as obs_events
-    rs = np.random.RandomState(0)
-    prompt = rs.randint(0, 128, (5,)).tolist()
-    [full] = _greedy_reference(gpt_model, [prompt], 8)
-    eos = next(t for t in full if t != full[0])
+    prompt, full, eos = _prompt_with_midway_eos(gpt_model)
     want_t = gpt_model.generate(Tensor(np.asarray([prompt], "int64")),
                                 max_new_tokens=8, eos_token_id=eos,
                                 decode_strategy="greedy")
@@ -1092,6 +1142,36 @@ def test_quarantine_bisection_isolates_offender(gpt_model, chaos):
     states = [e["state"] for e in obs_events.read_events(
         chaos, kinds=["health_transition"])]
     assert "quarantining" in states and "degraded" in states
+
+
+def test_cold_dispatch_failure_fails_engine_not_requests(gpt_model):
+    """A dispatch that raises while its program is still tracing or
+    compiling is a PROGRAM fault (on the chip: a Mosaic or VMEM
+    refusal, which would repeat for every request that reaches the
+    same Q bucket): the engine fails loudly — health ``failed``, every
+    request errored with the compiler's message, later submits refused
+    — instead of bisecting the batch into one quarantine per
+    request."""
+    rs = np.random.RandomState(3)
+    prompts = [rs.randint(0, 128, (n,)).tolist() for n in (5, 9, 7)]
+    engine = ServingEngine(gpt_model, max_batch=4, page_size=8)
+
+    def refuse(*a, **kw):
+        raise RuntimeError("RESOURCE_EXHAUSTED: Ran out of memory in "
+                           "memory space vmem")
+
+    engine._step_fn = refuse
+    with engine, pytest.warns(UserWarning, match="loop died"):
+        reqs = [engine.submit(p, max_new_tokens=4) for p in prompts]
+        for r in reqs:
+            with pytest.raises(RuntimeError, match="memory space vmem"):
+                r.wait(timeout=60)
+        assert engine.health == "failed"
+        stats = engine.stats()
+        assert stats["quarantined"] == 0
+        assert stats["quarantined_prompts"] == 0
+        late = engine.submit(prompts[0], max_new_tokens=4)
+        assert late.done and late.error_kind == "unhealthy"
 
 
 @pytest.mark.chaos

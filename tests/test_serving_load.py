@@ -5,7 +5,7 @@ histograms (the ISSUE 13 headline acceptance).
 
 Marked ``slow`` (tier-1 stays inside the timeout budget) and runs on a
 PRIVATE per-run XLA cache dir — warm-cache executable load from the
-shared tests/.xla_cache is a known ~60% segfault trigger on hybrid
+shared <checkout>/.jax_cache is a known ~60% segfault trigger on hybrid
 runs (see test_llama's identical fixture)."""
 import json
 import threading
@@ -31,7 +31,7 @@ P99_SLO_S = 30.0
 def _private_xla_cache(tmp_path_factory):
     """De-flake by construction: this module compiles its own
     executables against a fresh per-run XLA cache so nothing loads
-    WARM from the shared tests/.xla_cache (the jax-0.4.37 CPU
+    WARM from the shared <checkout>/.jax_cache (the jax-0.4.37 CPU
     deserialization fragility test_llama documents)."""
     import jax
     from jax.experimental.compilation_cache import (compilation_cache as

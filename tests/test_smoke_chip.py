@@ -1,0 +1,102 @@
+"""What keeps the chip bring-up from rotting, checked without a chip:
+``chip_smoke.py`` still runs end to end in its CPU rehearsal and still
+refuses to produce a result without an accelerator; the compile cache
+is placed by the one rule; an unknown device has no peak FLOPs."""
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_SMOKE = os.path.join(_REPO, "chip_smoke.py")
+
+
+def _run(argv, env=None, timeout=600):
+    return subprocess.run([sys.executable, *argv], capture_output=True,
+                          text=True, timeout=timeout, cwd=_REPO,
+                          env={**os.environ, **(env or {})})
+
+
+def test_chip_smoke_rehearsal_is_green():
+    """Tiny preset, Pallas interpret mode, both phases as children of a
+    parent that never imports jax.  The last line is the result object
+    with exactly the keys the chip check reads, and says it ran on the
+    CPU; the line before it holds the phases' reports."""
+    proc = _run([_SMOKE, "--rehearse"])
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+    assert "platform: cpu" in proc.stdout
+    report, last = proc.stdout.strip().splitlines()[-2:]
+    out = json.loads(last)
+    assert set(out) == {"ok", "device"} and out["ok"] is True
+    assert set(out["device"]) == {"platform", "kind", "count"}
+    assert out["device"]["platform"] == "cpu"
+    assert isinstance(out["device"]["kind"], str)
+    assert type(out["device"]["count"]) is int
+    detail = json.loads(report.removeprefix("report: "))
+    assert detail["rehearsal"] is True
+    assert set(detail["phases"]) == {"train", "serve"}
+
+
+def test_chip_smoke_without_a_chip_fails_and_prints_no_result():
+    proc = _run([_SMOKE], env={"JAX_PLATFORMS": "cpu"})
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+    assert "needs platform 'tpu'" in proc.stderr
+
+
+_REPORT_CACHE_DIR = """
+import jax
+import paddle_tpu
+from paddle_tpu.flags import set_flags
+before = jax.config.jax_compilation_cache_dir
+set_flags({"FLAGS_tuning_cache_dir": %r})
+set_flags({"FLAGS_tuning_cache_dir": ""})
+assert jax.config.jax_compilation_cache_dir == before
+print(before)
+"""
+
+
+def test_compile_cache_dir_left_to_the_environment_when_set(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: neither the import nor set_flags
+    names another directory."""
+    proc = _run(["-c", _REPORT_CACHE_DIR % str(tmp_path / "tune")],
+                env={"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "xla"),
+                     "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip() == str(tmp_path / "xla")
+
+
+def test_compile_cache_dir_defaults_beside_the_checkout(tmp_path):
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    proc = subprocess.run(
+        [sys.executable, "-c", _REPORT_CACHE_DIR % str(tmp_path / "tune")],
+        capture_output=True, text=True, timeout=600, cwd=str(tmp_path),
+        env={**env, "JAX_PLATFORMS": "cpu", "PYTHONPATH": _REPO})
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip() == os.path.join(_REPO, ".jax_cache")
+
+
+def test_chip_peak_flops_raises_on_an_unknown_kind():
+    import jax
+    from paddle_tpu.device import chip_peak_flops
+
+    class Dev:
+        device_kind = "TPU v5 lite"
+
+    assert chip_peak_flops(Dev()) == 197e12
+    Dev.device_kind = "TPU v99"
+    with pytest.raises(ValueError, match="TPU v99"):
+        chip_peak_flops(Dev())
+    with pytest.raises(ValueError, match="no peak FLOPs"):
+        chip_peak_flops(jax.devices()[0])        # the CPU test mesh
+
+
+def test_jax_device_rejects_an_id_past_the_attached_devices():
+    import jax
+    from paddle_tpu.device import CPUPlace, TPUPlace, jax_device
+    assert jax_device(CPUPlace()) == jax.devices("cpu")[0]
+    with pytest.raises(ValueError, match="out of range"):
+        jax_device(TPUPlace(len(jax.devices())))

@@ -269,45 +269,6 @@ def test_param_update_visible_in_replay():
     np.testing.assert_allclose(out.numpy(), 2.0 * np.ones((1, 2)))
 
 
-def test_persistent_jit_cache_across_processes(tmp_path):
-    """FLAGS_jit_cache_dir: compiled programs survive a process restart
-    (the reference's kernel/program caches role).  Child 1 compiles and
-    populates the dir; child 2 must find cache files already present."""
-    import os
-    import subprocess
-    import sys
-    cache = str(tmp_path / "jitcache")
-    prog = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-import jax; jax.config.update("jax_platforms", "cpu")
-import numpy as np
-import paddle_tpu as paddle
-paddle.set_flags({"FLAGS_jit_cache_dir": %r})
-from paddle_tpu.jit import to_static
-
-@to_static
-def f(x):
-    return (x * 2 + 1).sum()
-
-print(float(f(paddle.to_tensor(np.ones((4, 4), "float32"))).numpy()))
-"""
-    env = dict(os.environ)
-    out1 = subprocess.run([sys.executable, "-c", prog % cache],
-                          capture_output=True, text=True, env=env,
-                          timeout=240)
-    assert out1.returncode == 0, out1.stderr[-800:]
-    files = []
-    for root, _, fs in os.walk(cache):
-        files += fs
-    assert files, "first process did not populate the cache"
-    out2 = subprocess.run([sys.executable, "-c", prog % cache],
-                          capture_output=True, text=True, env=env,
-                          timeout=240)
-    assert out2.returncode == 0, out2.stderr[-800:]
-    assert out1.stdout.strip() == out2.stdout.strip()
-
-
 def test_logged_scalar_guard_relaxes_with_flag():
     """With FLAGS_sot_relax_guards on, a host-read scalar that is ONLY
     logged must not re-record forever: the second record demonstrates

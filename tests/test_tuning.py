@@ -30,21 +30,12 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.fixture
 def cache_dir(tmp_path):
-    """FLAGS_tuning_cache_dir → tmp dir; restores the suite's XLA
-    compile-cache config afterwards (the flag's on_change rewires it)."""
-    prev_dir = jax.config.jax_compilation_cache_dir
-    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    prev_size = jax.config.jax_persistent_cache_min_entry_size_bytes
+    """FLAGS_tuning_cache_dir → tmp dir."""
     d = str(tmp_path / "tuning")
     flags.set_flags({"FLAGS_tuning_cache_dir": d})
     yield d
     flags.set_flags({"FLAGS_tuning_cache_dir": ""})
     cache_mod._active = None
-    jax.config.update("jax_compilation_cache_dir", prev_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      prev_min)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                      prev_size)
 
 
 @pytest.fixture
@@ -174,27 +165,9 @@ def test_cache_concurrent_writers_stay_atomic(tmp_path):
         {k: len(v) for k, v in per_writer.items()}
 
 
-def test_cache_flag_wires_xla_compilation_cache(cache_dir):
-    assert jax.config.jax_compilation_cache_dir == \
-        os.path.join(cache_dir, "xla")
+def test_cache_flag_enables_tuning_store(cache_dir):
     assert get_cache() is not None
     assert cache_stats()["enabled"]
-
-
-def test_cache_flag_defers_to_explicit_jit_cache_dir(tmp_path):
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        flags.set_flags({"FLAGS_jit_cache_dir": str(tmp_path / "jit")})
-        flags.set_flags({"FLAGS_tuning_cache_dir":
-                         str(tmp_path / "tune")})
-        # the explicit compilation-cache flag keeps ownership
-        assert jax.config.jax_compilation_cache_dir == \
-            str(tmp_path / "jit")
-    finally:
-        flags.set_flags({"FLAGS_tuning_cache_dir": "",
-                         "FLAGS_jit_cache_dir": ""})
-        cache_mod._active = None
-        jax.config.update("jax_compilation_cache_dir", prev)
 
 
 # ---------------------------------------------------------------------------
