@@ -74,6 +74,8 @@ def _aval(v):
 
 
 def _emit_op_event(op_name, arrays, outs, multi):
+    if not _op_stream_hooks:
+        return
     vals = list(outs) if multi and isinstance(outs, (tuple, list)) \
         else [outs]
     ev = OpEvent(op_name or "op", [_aval(a) for a in arrays],

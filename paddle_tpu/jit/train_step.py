@@ -162,7 +162,7 @@ class TrainStep:
                                        self.loss_fn, self.scaler)
         params, buffers = self.params, self.buffers
 
-        def step(state, lr, batch):
+        def train_step(state, lr, batch):
             # 1. install traced state into the eager objects
             for p, v in zip(params, state["p"]):
                 p._data = v
@@ -209,7 +209,7 @@ class TrainStep:
                     opt._lr_override = None
                 default_generator.set_state(saved_key)
 
-        return step
+        return train_step
 
     def _current_lr(self) -> float:
         if self.optimizer is None:

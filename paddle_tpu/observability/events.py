@@ -108,13 +108,19 @@ EVENT_SCHEMA: Dict[str, Dict[str, str]] = {
                       "resumed": "bool", "predicted_cost_s": "float"},
     # one ragged batch iteration (mixed prefill+decode, one launch);
     # step_s + page_occupancy make each record a (features, seconds)
-    # training sample for the learned perf model
+    # training sample for the learned perf model.  The *_s phase fields
+    # are the loop thread's perf_counter seconds under the engine:*
+    # profiler annotations of the same names (serving/engine.py)
     "batch_step": {"batch": "int", "prefill_seqs": "int",
                    "decode_seqs": "int", "q_width": "int",
                    "tokens": "int", "queue_depth": "int",
                    "step_s": "float", "page_occupancy": "float",
                    "cold_start": "bool", "fused_steps": "int",
-                   "exit_reason": "str"},
+                   "exit_reason": "str",
+                   "plan_s": "float", "prepare_s": "float",
+                   "dispatch_s": "float", "read_s": "float",
+                   "commit_s": "float", "host_gap_s": "float",
+                   "wait_s": "float", "admit_queue_s": "object"},
     # learned performance model lifecycle (tuning.learned): a versioned
     # model file was fitted/saved from accumulated telemetry
     "perf_model": {"action": "str", "version": "int", "heads": "object",
@@ -268,17 +274,18 @@ class EventLog:
         self.run_id = os.environ.get("PADDLE_OBS_RUN_ID") or \
             f"{os.getpid()}-{int(time.time() * 1000)}"
         self.dropped_writes = 0
+        # the handle stays open between records (line-buffered: every
+        # line reaches the OS as it is written, so a crash still costs
+        # at most the line in flight); _ino says which file it is onto
+        self._fh = None
+        self._ino = -1
 
     # -- rotation ---------------------------------------------------------
     def _rotated_name(self, k: int) -> str:
         return os.path.join(self.directory, f"events-{k}.jsonl")
 
-    def _maybe_rotate_locked(self) -> None:
-        try:
-            if os.path.getsize(self.path) < self.rotate_bytes:
-                return
-        except OSError:
-            return
+    def _rotate_locked(self) -> None:
+        self._close_locked()
         mets = _log_metrics()
         if mets is not None:
             mets["rotations"].inc()
@@ -299,6 +306,39 @@ class EventLog:
             pass
 
     # -- writing ----------------------------------------------------------
+    def _close_locked(self) -> None:
+        fh, self._fh = self._fh, None
+        if fh is not None:
+            try:
+                fh.close()
+            except OSError:
+                pass
+
+    def close(self) -> None:
+        with self._lock:
+            self._close_locked()
+
+    def _handle_locked(self):
+        """The open handle onto ``events.jsonl``: rotated first when the
+        file is full, (re)opened — directory included — when there is
+        none yet or the path no longer names the file it is onto
+        (another process sharing the directory rotated it, or it was
+        removed).  One ``stat`` a record; no open, close or mkdir."""
+        try:
+            st = os.stat(self.path)
+        except OSError:
+            st = None
+        if st is not None and st.st_size >= self.rotate_bytes:
+            self._rotate_locked()
+            st = None
+        if self._fh is None or st is None or st.st_ino != self._ino:
+            self._close_locked()
+            os.makedirs(self.directory, exist_ok=True)
+            self._fh = open(self.path, "a", buffering=1,
+                            encoding="utf-8")
+            self._ino = os.fstat(self._fh.fileno()).st_ino
+        return self._fh
+
     def write(self, kind: str, fields: Dict[str, Any]) -> None:
         rec = {"v": SCHEMA_VERSION, "ts": time.time(), "pid": os.getpid(),
                "run": self.run_id, "kind": kind}
@@ -320,16 +360,15 @@ class EventLog:
         mets = _log_metrics()
         with self._lock:
             try:
-                os.makedirs(self.directory, exist_ok=True)
-                self._maybe_rotate_locked()
-                with open(self.path, "a", encoding="utf-8") as fh:
-                    fh.write(line)
+                self._handle_locked().write(line)
                 if mets is not None:
                     mets["records"].inc()
                     mets["bytes"].inc(len(line))
             except OSError:
                 # telemetry must never take the training run down; the
                 # drop is visible in the counters (instance + registry)
+                # and the next record reopens the file
+                self._close_locked()
                 self.dropped_writes += 1
                 if mets is not None:
                     mets["dropped"].inc()
@@ -354,6 +393,8 @@ def configure(directory: Optional[str],
     import wires worker processes automatically."""
     global _LOG, _PENDING_DIR
     with _lock:
+        if _LOG is not None:
+            _LOG.close()
         if not directory:
             _uninstall_hooks_locked()
             _LOG = None

@@ -105,6 +105,7 @@ def _kv_row(n_rep):
     return lambda b: b // n_rep
 
 
+@jax.named_scope("flash_fwd")
 def _fwd_call(q, k, v, scale, causal, block_q, block_k, interpret,
               bh, sq, sk, d, grid, q_offset, n_rep):
     kv_row = _kv_row(n_rep)
@@ -261,23 +262,25 @@ def _flash_bwd(q, k, v, out, lse, do, scale: float, causal: bool,
 def _bwd_calls(q, k, v, do, lse3, delta3, scale, causal, block_q, block_k,
                interpret, bh, bhkv, sq, sk, d, q_offset, n_rep):
     kv_row = _kv_row(n_rep)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_k=sk,
-                          q_offset=q_offset),
-        grid=(bh, pl.cdiv(sq, block_q)),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, sk, d), lambda b, i: (kv_row(b), 0, 0)),
-            pl.BlockSpec((1, sk, d), lambda b, i: (kv_row(b), 0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        interpret=interpret,
-    )(q, k, v, do, lse3, delta3)
+    with jax.named_scope("flash_bwd_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
+                              block_q=block_q, block_k=block_k, seq_k=sk,
+                              q_offset=q_offset),
+            grid=(bh, pl.cdiv(sq, block_q)),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((1, sk, d), lambda b, i: (kv_row(b), 0, 0)),
+                pl.BlockSpec((1, sk, d), lambda b, i: (kv_row(b), 0, 0)),
+                pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, d),
+                                   lambda b, i: (b, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+            interpret=interpret,
+        )(q, k, v, do, lse3, delta3)
 
     if n_rep == 1:
         grid = (bhkv, pl.cdiv(sk, block_k))
@@ -289,33 +292,35 @@ def _bwd_calls(q, k, v, do, lse3, delta3, scale, causal, block_q, block_k,
         grid = (bhkv, pl.cdiv(sk, block_k), n_rep)
         q_row = lambda b, j, r: b * n_rep + r
         kv_idx = lambda b, j, r: (b, j, 0)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_q=sq,
-                          q_offset=q_offset, n_rep=n_rep),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, sq, d), lambda b, j, *r: (q_row(b, j, *r), 0, 0)),
-            pl.BlockSpec((1, block_k, d), kv_idx),
-            pl.BlockSpec((1, block_k, d), kv_idx),
-            pl.BlockSpec((1, sq, d), lambda b, j, *r: (q_row(b, j, *r), 0, 0)),
-            pl.BlockSpec((1, sq, 1), lambda b, j, *r: (q_row(b, j, *r), 0, 0)),
-            pl.BlockSpec((1, sq, 1), lambda b, j, *r: (q_row(b, j, *r), 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), kv_idx),
-            pl.BlockSpec((1, block_k, d), kv_idx),
-        ],
-        out_shape=[
-            # fp32 outputs under GQA: the rep-axis revisiting accumulation
-            # must not round per-add in bf16 (cast once below instead)
-            jax.ShapeDtypeStruct((bhkv, sk, d),
-                                 jnp.float32 if n_rep > 1 else k.dtype),
-            jax.ShapeDtypeStruct((bhkv, sk, d),
-                                 jnp.float32 if n_rep > 1 else v.dtype),
-        ],
-        interpret=interpret,
-    )(q, k, v, do, lse3, delta3)
+    q_idx = lambda b, j, *r: (q_row(b, j, *r), 0, 0)
+    with jax.named_scope("flash_bwd_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
+                              block_q=block_q, block_k=block_k, seq_q=sq,
+                              q_offset=q_offset, n_rep=n_rep),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, sq, d), q_idx),
+                pl.BlockSpec((1, block_k, d), kv_idx),
+                pl.BlockSpec((1, block_k, d), kv_idx),
+                pl.BlockSpec((1, sq, d), q_idx),
+                pl.BlockSpec((1, sq, 1), q_idx),
+                pl.BlockSpec((1, sq, 1), q_idx),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_k, d), kv_idx),
+                pl.BlockSpec((1, block_k, d), kv_idx),
+            ],
+            out_shape=[
+                # fp32 outputs under GQA: the rep-axis revisiting accumulation
+                # must not round per-add in bf16 (cast once below instead)
+                jax.ShapeDtypeStruct((bhkv, sk, d),
+                                     jnp.float32 if n_rep > 1 else k.dtype),
+                jax.ShapeDtypeStruct((bhkv, sk, d),
+                                     jnp.float32 if n_rep > 1 else v.dtype),
+            ],
+            interpret=interpret,
+        )(q, k, v, do, lse3, delta3)
     if n_rep > 1:
         dk = dk.astype(k.dtype)
         dv = dv.astype(v.dtype)

@@ -75,7 +75,7 @@ def fused_adamw_update(pv, gv, m, v, lr, b1p, b2p, b1: float, b2: float,
                          jnp.zeros((), jnp.float32)]).reshape(1, 4)
     br = min(block_rows, rows)
     grid = (pl.cdiv(rows, br),)
-    with jax.enable_x64(False):
+    with jax.enable_x64(False), jax.named_scope("adamw"):
         po, mo, vo = pl.pallas_call(
             functools.partial(_adamw_kernel, b1=float(b1), b2=float(b2),
                               eps=float(eps), wd=float(wd)),

@@ -56,7 +56,7 @@ def _fwd(x2d, w, b, eps: float, block_n: int, interpret: bool):
     n, h = x2d.shape
     bn = min(block_n, n)
     grid = (pl.cdiv(n, bn),)
-    with jax.enable_x64(False):
+    with jax.enable_x64(False), jax.named_scope("ln_fwd"):
         out, m, r = pl.pallas_call(
             functools.partial(_ln_fwd_kernel, eps=eps),
             grid=grid,
@@ -78,7 +78,7 @@ def _bwd_dx(x2d, w, m, r, g2d, block_n: int, interpret: bool):
     n, h = x2d.shape
     bn = min(block_n, n)
     grid = (pl.cdiv(n, bn),)
-    with jax.enable_x64(False):
+    with jax.enable_x64(False), jax.named_scope("ln_bwd"):
         return pl.pallas_call(
             _ln_bwd_kernel,
             grid=grid,

@@ -267,7 +267,7 @@ def _ragged_pallas(q, k_pages, v_pages, kv_lens, q_lens, page_tables,
             pltpu.VMEM((nh * bq, 1), jnp.float32),    # denominator
         ],
     )
-    with jax.enable_x64(False):
+    with jax.enable_x64(False), jax.named_scope("ragged_paged_attn"):
         out = pl.pallas_call(
             functools.partial(_ragged_kernel, n_kv=nkv,
                               n_rep=nh // nkv, block_q=bq,

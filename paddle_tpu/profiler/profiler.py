@@ -13,9 +13,10 @@ Two tracers, matching the reference's host-tracer + device-tracer split:
 - **Device (XPlane) traces**: the real device timeline comes from XLA's
   own profiler.  ``Profiler`` starts/stops ``jax.profiler`` tracing when a
   ``trace_dir`` is given (TensorBoard/perfetto-compatible XPlane dumps),
-  and ``RecordEvent`` doubles as ``jax.profiler.TraceAnnotation`` so host
-  spans show up inside the device timeline — the TraceMe/RecordEvent
-  parity called for in SURVEY.md §5.
+  and ``RecordEvent`` always doubles as ``jax.profiler.TraceAnnotation``,
+  so host spans show up inside the device timeline of any live trace,
+  whoever started it — the TraceMe/RecordEvent parity called for in
+  SURVEY.md §5.
 
 The scheduler state machine (CLOSED/READY/RECORD/RECORD_AND_RETURN,
 ``make_scheduler``) and the ``on_trace_ready`` export-handler contract are
@@ -29,6 +30,8 @@ import threading
 import time
 from enum import Enum
 from typing import Any, Callable, Dict, List, Optional, Sequence
+
+import jax
 
 
 class ProfilerState(Enum):
@@ -110,42 +113,40 @@ def _op_profile_hook(op_name: str, start: float, end: float):
 class RecordEvent:
     """User-defined span (ref: profiler.RecordEvent).
 
-    Context manager / begin-end pair.  While a device trace is live it
-    also enters ``jax.profiler.TraceAnnotation`` so the span appears in
-    the XPlane timeline.
+    Context manager / begin-end pair.  Every span also enters
+    ``jax.profiler.TraceAnnotation``, so it lands on the host plane of
+    ANY live profiler trace — paddle_tpu's own ``Profiler``, a bare
+    ``jax.profiler.start_trace`` or TensorBoard's capture — on the same
+    clock as the device plane.  A TraceMe is itself a no-op while no
+    trace is live, so an unobserved span costs one small object and no
+    clock read.
     """
+
+    __slots__ = ("name", "event_type", "_t0", "_annotation")
 
     def __init__(self, name: str,
                  event_type: TracerEventType = TracerEventType.UserDefined):
         self.name = name
         self.event_type = event_type
         self._t0: Optional[float] = None
-        self._live = False
         self._annotation = None
 
     def begin(self):
-        # only spans fully inside a record window count: a span opened
-        # before the window would otherwise be stored with a pre-window
-        # start time (inflated duration in the trace)
-        self._live = _recorder.recording
-        self._t0 = time.perf_counter()
-        if self._live:
-            try:
-                import jax
-                self._annotation = jax.profiler.TraceAnnotation(self.name)
-                self._annotation.__enter__()
-            except Exception:
-                self._annotation = None
+        # only spans fully inside a record window count for the host
+        # recorder: a span opened before the window would otherwise be
+        # stored with a pre-window start time (inflated duration)
+        self._t0 = time.perf_counter() if _recorder.recording else None
+        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        self._annotation.__enter__()
 
     def end(self):
         if self._annotation is not None:
             self._annotation.__exit__(None, None, None)
             self._annotation = None
-        if self._t0 is not None and self._live and _recorder.recording:
+        if self._t0 is not None and _recorder.recording:
             _recorder.add(self.name, self.event_type, self._t0,
                           time.perf_counter())
         self._t0 = None
-        self._live = False
 
     def __enter__(self):
         self.begin()
@@ -258,7 +259,6 @@ class Profiler:
         if (self.trace_dir and ProfilerTarget.TPU in self.targets
                 and not self._xplane_live):
             try:
-                import jax
                 jax.profiler.start_trace(self.trace_dir)
                 self._xplane_live = True
             except Exception:
@@ -272,7 +272,6 @@ class Profiler:
         self._events = list(_recorder.events)
         if self._xplane_live:
             try:
-                import jax
                 jax.profiler.stop_trace()
             except Exception:
                 pass

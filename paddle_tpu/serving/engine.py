@@ -34,11 +34,14 @@ Observability: ``serving_admit`` / ``batch_step`` / ``evict`` events
 gauges, per-request end-to-end and time-to-first-token histograms —
 all through the PR 4 metrics registry, which is what ``GET /metrics``
 exports when the engine serves behind ``InferenceServer``
-(``FLAGS_serving_engine``).  Each step also emits ``serving_prefill``
-/ ``serving_decode`` markers into the op-dispatch stream
-(``core.dispatch.observe_op_stream``) carrying the REAL fed-token
-counts, so tests and the analyzer can prove prefix-cache sharing
-skips prefill work.
+(``FLAGS_serving_engine``).  The loop thread's time is cut into seven
+sibling ``engine:*`` phases (plan, prepare, dispatch, host_read, commit
+and two waits) that show in any live profiler trace and, as seconds, in
+``batch_step`` (``_LoopPhases``).  While a test or the analyzer
+observes the op-dispatch stream (``core.dispatch.observe_op_stream``)
+each step also emits ``serving_prefill`` / ``serving_decode`` markers
+carrying the REAL fed-token counts, which prove that prefix-cache
+sharing skips prefill work.
 
 Fault containment: co-batching couples failure domains — one poisoned
 request or one wedged dispatch would otherwise take down every
@@ -85,6 +88,7 @@ import numpy as np
 
 __all__ = ["ServingEngine"]
 
+from ..core import dispatch as _dispatch
 from ..observability import events as _events
 from ..observability import metrics as _metrics
 from ..observability import tracing as _tracing
@@ -165,6 +169,133 @@ def _bucket(n: int) -> int:
     while b < n:
         b <<= 1
     return b
+
+
+def _mark_op_stream(name: str, n: int) -> None:
+    """A marker in the op-dispatch stream (``core.dispatch.
+    observe_op_stream``) whose one input has ``n`` elements: the REAL
+    count of tokens fed, or of iterations a host read covered.  Costs
+    one check when nobody listens."""
+    if n and _dispatch._op_stream_hooks:
+        _dispatch._emit_op_event(name, [np.empty((n,), "int8")], [], True)
+
+
+# The loop thread's time, cut into sibling phases.  Each is a
+# ``profiler.RecordEvent`` of this name, so it lands on the host plane
+# of whatever profiler trace is live (``jax.profiler``, TensorBoard,
+# paddle_tpu's own ``Profiler``) on the device plane's clock; the same
+# stretches, as seconds, are the ``*_s`` fields of ``batch_step``.
+_IDLE_WAIT, _NO_CAPACITY_WAIT, _PLAN, _PREPARE, _DISPATCH, _HOST_READ, \
+    _COMMIT = range(7)
+_PHASE_NAMES = ("engine:idle_wait", "engine:no_capacity_wait",
+                "engine:plan", "engine:prepare", "engine:dispatch",
+                "engine:host_read", "engine:commit")
+_NO_PHASES = (None,) * 8
+
+
+class _LoopPhases:
+    """One loop thread's phase clock.  ``switch`` closes the running
+    phase and opens the next, so the phases tile the thread's time with
+    no gap between them and none inside another.
+
+    With the event log off that is all it does: two annotation calls a
+    switch, no clock read, nothing allocated.  With it on, ``switch``
+    also reads ``perf_counter`` once and sums the closed phase into
+    ``seconds`` (indexed by phase), which ``take`` hands to the
+    ``batch_step`` record with:
+
+    * ``host_gap_s`` — from the end of the previous step's host read to
+      the end of this step's dispatch call, the waits for work or
+      capacity in between taken out (``wait_s``): the stretch in which
+      this thread, not the device, sets the pace;
+    * ``admit_queue_s`` — the queue wait of each request admitted since
+      the last record.
+    """
+
+    __slots__ = ("_spans", "_cur", "_t_cur", "seconds", "_read_end",
+                 "_waited", "_gap", "admit_queue_s")
+
+    def __init__(self):
+        from ..profiler.profiler import RecordEvent
+        self._spans = tuple(RecordEvent(n) for n in _PHASE_NAMES)
+        self._cur = -1                  # the running phase
+        self._t_cur = None              # its start; None: not timed
+        self.seconds = None             # this step's seconds by phase
+        self._read_end = None           # end of the last host read
+        self._waited = 0.0              # waits since then
+        self._gap = None                # (host_gap_s, wait_s)
+        self.admit_queue_s = None
+
+    def switch(self, phase: int) -> None:
+        cur = self._cur
+        if cur >= 0:
+            self._spans[cur].end()
+        if _events.enabled():
+            now = time.perf_counter()  # noqa: PTL501 — the batch_step record's own phase seconds: they reach the event log, and the profiler's clock is no registry histogram
+            if self._t_cur is not None:
+                self._close(cur, now)
+            self._t_cur = now
+        elif self._t_cur is not None or self._read_end is not None:
+            self._forget()              # the log was switched off
+        self._cur = phase
+        if phase >= 0:
+            self._spans[phase].begin()
+
+    def stop(self) -> None:
+        """Close the running phase (the loop thread is leaving)."""
+        self.switch(-1)
+
+    def _close(self, cur: int, now: float) -> None:
+        dt = now - self._t_cur
+        if cur <= _NO_CAPACITY_WAIT:
+            self._waited += dt
+            return
+        if self.seconds is None:
+            self.seconds = [0.0] * len(_PHASE_NAMES)
+        self.seconds[cur] += dt
+        if cur == _DISPATCH and self._read_end is not None:
+            self._gap = (now - self._read_end - self._waited,
+                         self._waited)
+        elif cur == _HOST_READ:
+            self._read_end, self._waited = now, 0.0
+
+    def _forget(self) -> None:
+        self.drop()
+        self._read_end = self.admit_queue_s = None
+        self._waited = 0.0
+
+    def admitted(self, queue_s: float) -> None:
+        if self._t_cur is not None:
+            if self.admit_queue_s is None:
+                self.admit_queue_s = []
+            self.admit_queue_s.append(queue_s)
+
+    def drop(self) -> None:
+        """A step that failed leaves no record: its seconds go; the
+        queue waits of what it admitted ride the next record."""
+        self._t_cur = self.seconds = self._gap = None
+
+    def take(self):
+        """``(plan_s, prepare_s, dispatch_s, read_s, commit_s,
+        host_gap_s, wait_s, admit_queue_s)`` of the step that is about
+        to be recorded, the running phase counted up to now; all None
+        with the event log off.  What the thread does from here to the
+        next ``switch`` (the record's own write) stays under the running
+        annotation and inside the next ``host_gap_s``, and is summed
+        into no phase."""
+        if self._t_cur is None:
+            return _NO_PHASES
+        self._close(self._cur, time.perf_counter())  # noqa: PTL501 — as in switch()
+        secs, gap, queue = self.seconds, self._gap, self.admit_queue_s
+        self.drop()
+        self.admit_queue_s = None
+        out = [round(secs[i], 6) for i in range(_PLAN, _COMMIT + 1)]
+        if gap is None:
+            out += [None, None]
+        else:
+            out += [round(gap[0], 6), round(gap[1], 6) or None]
+        out.append(queue)
+        return out
 
 
 class ServingEngine:
@@ -457,8 +588,9 @@ class ServingEngine:
         (a planning bug, not a step failure — those are contained
         per-step) so the engine fails LOUDLY instead of leaving every
         consumer blocked on a dead thread."""
+        phases = _LoopPhases()
         try:
-            self._loop_body(epoch)
+            self._loop_body(epoch, phases)
         except Exception as e:  # noqa: BLE001 — last-resort
             # containment: the loop thread dying silently would hang
             # every consumer; report + fail everything + mark failed
@@ -472,21 +604,29 @@ class ServingEngine:
                                            f"{type(e).__name__}")
                 self._fail_all_locked(f"engine loop failed: "
                                       f"{type(e).__name__}: {e}")
+        finally:
+            phases.stop()
 
-    def _loop_body(self, epoch: int):
+    def _loop_body(self, epoch: int, phases: _LoopPhases):
         from ..flags import get_flag
         while True:
+            # the wait for _wake counts as planning: it is where the
+            # client threads contend with this one
+            phases.switch(_PLAN)
             with self._wake:
                 if not self._running or epoch != self._epoch:
                     return
                 self._sweep_deadlines_locked()
                 if not self.scheduler.has_work():
+                    phases.switch(_IDLE_WAIT)
                     self._wake.wait(0.05)
                     continue
                 plan, admitted, evicted = self.scheduler.plan_step()
                 now = time.monotonic()
                 for seq in admitted:
                     req = seq.req
+                    queue_s = round(now - req.submitted_at, 6)
+                    phases.admitted(queue_s)
                     qs, req._queue_span = req._queue_span, None
                     if qs is not None:
                         # queue-wait over: prefix-cache hit + resume
@@ -498,7 +638,7 @@ class ServingEngine:
                         "serving_admit", request=req.id,
                         prompt_len=len(req.prompt),
                         cached_tokens=seq.cached_tokens,
-                        queue_s=round(now - req.submitted_at, 6),
+                        queue_s=queue_s,
                         resumed=req.evictions > 0,
                         predicted_cost_s=(
                             round(seq.predicted_cost_s, 6)
@@ -547,8 +687,10 @@ class ServingEngine:
                 # runnable work exists but no pages/slots right now
                 # (e.g. the queue head cannot fit until a decode
                 # finishes) — yield briefly instead of spinning
+                phases.switch(_NO_CAPACITY_WAIT)
                 time.sleep(0.005)
                 continue
+            phases.switch(_PREPARE)
             with self._lock:
                 if epoch != self._epoch:
                     return
@@ -560,9 +702,9 @@ class ServingEngine:
             try:
                 if fused_w > 1:
                     self._run_window(plan, fused_w, fused_max,
-                                     fused_reason, epoch)
+                                     fused_reason, epoch, phases)
                 else:
-                    self._run_step(plan, epoch)
+                    self._run_step(plan, epoch, phases)
             except Exception as e:  # noqa: BLE001 — containment, not
                 # crash-out: the batch is retried by bisection and
                 # only the isolated offender fails
@@ -579,6 +721,7 @@ class ServingEngine:
                               f"{type(e).__name__}: {e}", stacklevel=1)
                 self._contain_step_failure(plan, e, epoch)
             finally:
+                phases.drop()
                 with self._lock:
                     if epoch == self._epoch:
                         self._dispatch_t0 = None
@@ -606,7 +749,7 @@ class ServingEngine:
             lane = i                      # kind "nan": poison on device
         return lane
 
-    def _run_step(self, plan, epoch: int):
+    def _run_step(self, plan, epoch: int, phases: _LoopPhases):
         # one SHARED step span for the whole ragged iteration, linked
         # from every member request's trace — each request's timeline
         # pulls its batch steps in through the links without owning
@@ -617,10 +760,9 @@ class ServingEngine:
                  for s in plan.seqs if s.req.trace is not None]
         with _tracing.trace_span("batch_step", links=links or None,
                                  attrs={"engine": self.engine_id}):
-            self._run_step_traced(plan, epoch)
+            self._run_step_traced(plan, epoch, phases)
 
-    def _run_step_traced(self, plan, epoch: int):
-        from ..core.dispatch import _emit_op_event
+    def _run_step_traced(self, plan, epoch: int, phases: _LoopPhases):
         # snapshot the device state FIRST: if this thread stalls and
         # the watchdog relaunches around it, the zombie must keep
         # writing into the ABANDONED buffers it captured here — never
@@ -644,30 +786,26 @@ class ServingEngine:
         poison = np.zeros((self.max_batch,), "float32")
         if nan_lane is not None:
             poison[nan_lane] = np.nan
+        phases.switch(_DISPATCH)
         with self._h_step.time() as step_timer:
             nxt, pools, rng = prog(
                 self._params, tok, pos, pools_in, page_ids, slots,
                 plan.kv_lens, plan.q_lens, plan.tables, plan.temps,
                 key_in, poison)
+            phases.switch(_HOST_READ)
             # THE boundary sync: exactly one device read per window
             # (this path is the degenerate one-iteration window) —
             # admission, eviction and EOS all key off it
             toks = np.asarray(nxt)  # noqa: PTL701 — window boundary
+        phases.switch(_COMMIT)
         # dispatch-stream markers with the REAL fed-token counts (the
         # prefix-cache FLOPs-skip proof reads these); the host-sync
         # marker carries the iteration count the read covered, so the
         # bench's host_syncs_per_100_tokens / steps_per_dispatch and
         # the one-read-per-window test are measured, not claimed
-        if plan.fed_prefill:
-            _emit_op_event("serving_prefill",
-                           [np.empty((plan.fed_prefill,), "int8")],
-                           [], True)
-        if plan.fed_decode:
-            _emit_op_event("serving_decode",
-                           [np.empty((plan.fed_decode,), "int8")],
-                           [], True)
-        _emit_op_event("serving_host_sync",
-                       [np.empty((1,), "int8")], [], True)
+        _mark_op_stream("serving_prefill", plan.fed_prefill)
+        _mark_op_stream("serving_decode", plan.fed_decode)
+        _mark_op_stream("serving_host_sync", 1)
         with self._wake:
             if epoch != self._epoch:
                 return    # watchdog relaunched mid-dispatch: zombie
@@ -721,27 +859,42 @@ class ServingEngine:
                         not seq.cache_inserted:
                     self._cache_prompt(seq)
             self._g_occ.set(len(self.scheduler.running))
-            # step_s + page_occupancy make each record a ready-made
-            # (features, seconds) sample for the learned perf model
-            # (analysis.perf_features / tuning.learned); cold_start
-            # marks the program-cache-miss steps whose step_s is
-            # trace+compile, not steady-state work — the featurizer
-            # and the divergence watchdog skip them
-            _events.emit("batch_step", batch=len(plan.seqs),
-                         prefill_seqs=plan.n_prefill,
-                         decode_seqs=plan.n_decode,
-                         q_width=int(qw),
-                         tokens=plan.fed_prefill + plan.fed_decode,
-                         queue_depth=self.scheduler.queue_depth(),
-                         step_s=round(step_timer.seconds, 6),
-                         cold_start=cold_start or None,
-                         page_occupancy=round(
-                             1.0 - self.pool.available()
-                             / max(self.pool.num_pages - 1, 1), 4),
-                         fused_steps=1, exit_reason="single_step")
+            self._emit_batch_step(
+                phases, plan, plan.n_prefill, int(qw),
+                plan.fed_prefill + plan.fed_decode, step_timer.seconds,
+                cold_start, 1, "single_step")
+
+    def _emit_batch_step(self, phases: _LoopPhases, plan, prefill_seqs,
+                         q_width, tokens, step_s, cold_start,
+                         fused_steps, exit_reason) -> None:
+        """The step's ``batch_step`` record (under ``_wake``).  step_s +
+        page_occupancy make each record a ready-made (features, seconds)
+        sample for the learned perf model (analysis.perf_features /
+        tuning.learned); cold_start marks the program-cache-miss steps
+        whose step_s is trace+compile, not steady-state work — the
+        featurizer and the divergence watchdog skip them."""
+        if not _events.enabled():
+            return
+        plan_s, prepare_s, dispatch_s, read_s, commit_s, host_gap_s, \
+            wait_s, admit_queue_s = phases.take()
+        _events.emit("batch_step", batch=len(plan.seqs),
+                     prefill_seqs=prefill_seqs,
+                     decode_seqs=plan.n_decode, q_width=q_width,
+                     tokens=tokens,
+                     queue_depth=self.scheduler.queue_depth(),
+                     step_s=round(step_s, 6),
+                     cold_start=cold_start or None,
+                     page_occupancy=round(
+                         1.0 - self.pool.available()
+                         / max(self.pool.num_pages - 1, 1), 4),
+                     fused_steps=fused_steps, exit_reason=exit_reason,
+                     plan_s=plan_s, prepare_s=prepare_s,
+                     dispatch_s=dispatch_s, read_s=read_s,
+                     commit_s=commit_s, host_gap_s=host_gap_s,
+                     wait_s=wait_s, admit_queue_s=admit_queue_s)
 
     def _run_window(self, plan, w, max_window, clamp_reason,
-                    epoch: int):
+                    epoch: int, phases: _LoopPhases):
         """Fused serving window: up to ``w`` decode iterations in one
         compiled dispatch (same shared batch_step span contract as
         ``_run_step``)."""
@@ -752,11 +905,10 @@ class ServingEngine:
                                  attrs={"engine": self.engine_id,
                                         "fused": True}):
             self._run_window_traced(plan, w, max_window, clamp_reason,
-                                    epoch)
+                                    epoch, phases)
 
     def _run_window_traced(self, plan, w, max_window, clamp_reason,
-                           epoch: int):
-        from ..core.dispatch import _emit_op_event
+                           epoch: int, phases: _LoopPhases):
         # snapshot the device state FIRST (see _run_step_traced): a
         # zombie thread must only ever write into these captured,
         # abandoned buffers after a watchdog relaunch
@@ -794,6 +946,7 @@ class ServingEngine:
             eos = seq.req.eos_token_id
             eos_ids[i] = -1 if eos is None else int(eos)
             budgets[i] = seq.req.max_new_tokens - len(seq.req.tokens)
+        phases.switch(_DISPATCH)
         with self._h_step.time() as step_timer:
             packed, pools, rng = prog(
                 self._params, tok0, pools_in, kv0, live,
@@ -802,20 +955,23 @@ class ServingEngine:
             # double-buffered plan: the device is running the window —
             # pre-stage the next boundary's admission work NOW, while
             # the host is otherwise idle (async dispatch means the
-            # blocking read below is where the wait happens)
+            # blocking read below is where the wait happens).  It is
+            # planning, so plan_s of a window counts it, though it lies
+            # outside host_gap_s
+            phases.switch(_PLAN)
             with self._wake:
                 if epoch == self._epoch:
                     self.scheduler.prestage_plan(plan, w)
+            phases.switch(_HOST_READ)
             # THE boundary sync: ONE packed device read per fused
             # window — tokens, finished mask and iteration count ride
             # a single int32 array
             out = np.asarray(packed)  # noqa: PTL701 — window boundary
+        phases.switch(_COMMIT)
         steps = int(out[0, max_window + 1])
         fed = len(plan.seqs) * steps
-        _emit_op_event("serving_decode",
-                       [np.empty((fed,), "int8")], [], True)
-        _emit_op_event("serving_host_sync",
-                       [np.empty((steps,), "int8")], [], True)
+        _mark_op_stream("serving_decode", fed)
+        _mark_op_stream("serving_host_sync", steps)
         with self._wake:
             if epoch != self._epoch:
                 return    # zombie window result after a relaunch
@@ -846,18 +1002,9 @@ class ServingEngine:
                     self.scheduler.finish(seq)
                     self._h_latency.observe(now - req.submitted_at)
             self._g_occ.set(len(self.scheduler.running))
-            exit_reason = "finished" if any_finished else clamp_reason
-            _events.emit("batch_step", batch=len(plan.seqs),
-                         prefill_seqs=0,
-                         decode_seqs=plan.n_decode,
-                         q_width=1, tokens=fed,
-                         queue_depth=self.scheduler.queue_depth(),
-                         step_s=round(step_timer.seconds, 6),
-                         cold_start=cold_start or None,
-                         page_occupancy=round(
-                             1.0 - self.pool.available()
-                             / max(self.pool.num_pages - 1, 1), 4),
-                         fused_steps=steps, exit_reason=exit_reason)
+            self._emit_batch_step(
+                phases, plan, 0, 1, fed, step_timer.seconds, cold_start,
+                steps, "finished" if any_finished else clamp_reason)
 
     def _cache_prompt(self, seq):
         """Share the finished prompt's full pages through the prefix
@@ -1126,6 +1273,8 @@ class ServingEngine:
             nxt = jnp.where(bad, jnp.int32(-1), nxt)
             return nxt, pools, rng
 
+        # the name the program carries in a profiler trace and in HLO
+        program.__name__ = f"serve_step_q{qw}"
         # pools are index 3; donated so XLA reuses the page buffers in
         # place across iterations (CPU has no donation support)
         donate = (3,) if jax.default_backend() != "cpu" else ()
@@ -1148,6 +1297,7 @@ class ServingEngine:
         if prog is not None:
             return prog
         _, window = self.model.build_fused_window_step(int(max_window))
+        window.__name__ = f"serve_window_w{int(max_window)}"
         # pools are index 2; donated like the single-step program
         donate = (2,) if jax.default_backend() != "cpu" else ()
         prog = jax.jit(window, donate_argnums=donate)

@@ -90,3 +90,54 @@ def test_profiler_timer_only():
     p.step(num_samples=8)
     p.stop()
     assert p.current_state == ProfilerState.CLOSED
+
+
+def _host_events(trace_dir):
+    """(line name, event name, start_ns, end_ns) of every host-plane
+    event of the newest .xplane.pb under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    paths = [os.path.join(base, f) for base, _, files in os.walk(trace_dir)
+             for f in files if f.endswith(".xplane.pb")]
+    data = ProfileData.from_file(max(paths, key=os.path.getmtime))
+    return [(ln.name, e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in data.planes if plane.name.startswith("/host:")
+            for ln in plane.lines for e in ln.events]
+
+
+def test_spans_reach_a_bare_jax_profiler_trace(tmp_path):
+    """No paddle Profiler: a trace started with jax.profiler alone (as
+    the benchmark and TensorBoard do) still holds a RecordEvent and the
+    serving loop's engine:* phases on its host plane — the phases as
+    siblings, none containing another."""
+    import jax
+    from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining
+    from paddle_tpu.serving import ServingEngine
+    from paddle_tpu.serving.engine import _PHASE_NAMES
+    paddle.seed(0)
+    model = GPTForPretraining(GPTConfig(
+        num_layers=1, hidden_size=32, num_heads=2, vocab_size=64,
+        max_position_embeddings=64, hidden_dropout_prob=0.0,
+        attention_dropout_prob=0.0))
+    model.eval()
+    engine = ServingEngine(model, max_batch=2, page_size=8)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with engine:
+        engine.generate([1, 2, 3, 4], max_new_tokens=2)      # compile
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with RecordEvent("bare_span"):
+                engine.generate([4, 3, 2, 1], max_new_tokens=4)
+        finally:
+            jax.profiler.stop_trace()
+    events = _host_events(str(tmp_path))
+    assert any(name == "bare_span" for _, name, _, _ in events)
+    phases = sorted((e for e in events if e[1].startswith("engine:")),
+                    key=lambda e: e[2])
+    assert {e[1] for e in phases} <= set(_PHASE_NAMES)
+    for name in ("engine:plan", "engine:prepare", "engine:dispatch",
+                 "engine:host_read", "engine:commit"):
+        assert sum(e[1] == name for e in phases) >= 4, name
+    assert len({e[0] for e in phases}) == 1        # one thread's line
+    for a, b in zip(phases, phases[1:]):
+        assert b[2] >= a[3], (a, b)                # b starts after a ends

@@ -6,6 +6,7 @@ import json
 import os
 import signal
 import threading
+import time
 import urllib.request
 
 import numpy as np
@@ -559,3 +560,91 @@ def test_trace_span_kind_in_schema_and_doc():
     assert "trace_id" in events.ENVELOPE_FIELDS
     findings = check_event_schema()
     assert findings == [], "\n".join(f.render() for f in findings)
+
+
+# ---------------------------------------------------------------------------
+# phases of the serving loop (engine:* annotations + batch_step fields)
+# ---------------------------------------------------------------------------
+
+_PHASE_FIELDS = ("plan_s", "prepare_s", "dispatch_s", "read_s", "commit_s")
+
+
+@pytest.fixture
+def fused_guard():
+    keep = get_flags(["FLAGS_serving_fused_steps"])
+    yield
+    set_flags(keep)
+
+
+@pytest.mark.parametrize("fused", [1, 4], ids=["single_step", "fused"])
+def test_batch_step_carries_the_loop_phases(gpt_model, obs_dir,
+                                            fused_guard, fused):
+    """Every warm batch_step holds the five phase seconds; host_gap_s
+    runs from the last host read to this dispatch's end without the
+    waits for work; admit_queue_s repeats serving_admit's queue_s."""
+    from paddle_tpu.serving import ServingEngine
+    set_flags({"FLAGS_serving_fused_steps": fused})
+    rs = np.random.RandomState(9)
+    prompts = [rs.randint(0, 128, (7,)).tolist() for _ in range(4)]
+    engine = ServingEngine(gpt_model, max_batch=2, page_size=8,
+                           prefix_caching=False)
+    with engine:
+        for r in [engine.submit(p, max_new_tokens=6) for p in prompts[:3]]:
+            r.wait(timeout=120)
+        time.sleep(0.2)            # the loop polls for work: idle_wait
+        engine.submit(prompts[3], max_new_tokens=6).wait(timeout=120)
+    recs = events.read_events(obs_dir)
+    steps = [r for r in recs if r["kind"] == "batch_step"]
+    warm = [s for s in steps if not s.get("cold_start")]
+    assert len(warm) >= 6
+    if fused > 1:
+        assert any(s["fused_steps"] > 1 for s in warm)
+    for s in warm:
+        assert all(s[f] >= 0.0 for f in _PHASE_FIELDS), s
+        # step_s brackets the dispatch and the host read, as before
+        assert abs(s["dispatch_s"] + s["read_s"] - s["step_s"]) \
+            <= 0.2 * s["step_s"], s
+    assert "host_gap_s" not in steps[0]
+    for s in steps[1:]:
+        if s["fused_steps"] == 1 and s["exit_reason"] == "single_step":
+            # (a fused window's plan_s also counts the pre-staging it
+            # does while the device runs, outside the gap)
+            assert s["host_gap_s"] >= s["plan_s"] + s["prepare_s"] \
+                + s["dispatch_s"] - 1e-5, s
+        else:
+            assert s["host_gap_s"] >= s["dispatch_s"] - 1e-5, s
+    admits = [r["queue_s"] for r in recs if r["kind"] == "serving_admit"]
+    assert len(admits) == 4
+    assert [q for s in steps for q in s.get("admit_queue_s", ())] == admits
+    # the one step after the pause: its wait is in wait_s, not in the gap
+    waited = [s for s in steps if s.get("wait_s", 0.0) >= 0.15]
+    assert len(waited) == 1 and waited[0]["admit_queue_s"] == admits[3:]
+    assert waited[0]["host_gap_s"] < waited[0]["wait_s"]
+
+
+def test_phase_clock_is_idle_with_the_log_off(gpt_model, monkeypatch):
+    """With FLAGS_observability_dir unset a step enters and leaves its
+    annotations and nothing else: no phase record, no OpEvent."""
+    from paddle_tpu.core import dispatch
+    from paddle_tpu.serving import Request, ServingEngine
+    from paddle_tpu.serving import engine as engine_mod
+    assert not events.enabled() and not dispatch._op_stream_hooks
+
+    def no_op_event(*a, **kw):
+        raise AssertionError("an OpEvent was built with nobody listening")
+    monkeypatch.setattr(dispatch, "OpEvent", no_op_event)
+    engine = ServingEngine(gpt_model, max_batch=2, page_size=8)
+    req = Request([5, 6, 7, 8], max_new_tokens=2)
+    engine.scheduler.submit(req)
+    phases = engine_mod._LoopPhases()
+    for _ in range(2):
+        phases.switch(engine_mod._PLAN)
+        plan, admitted, _ = engine.scheduler.plan_step()
+        for seq in admitted:
+            phases.admitted(0.001)
+        phases.switch(engine_mod._PREPARE)
+        engine._run_step_traced(plan, engine._epoch, phases)
+        assert phases.seconds is None and phases.admit_queue_s is None
+        assert phases.take() is engine_mod._NO_PHASES
+    phases.stop()
+    assert len(req.wait(timeout=5)) == 2
