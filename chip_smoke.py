@@ -403,6 +403,81 @@ def _engine_program_args(engine, qw: int):
             like(engine._key), sds((b,), jnp.float32))
 
 
+# the batch cell's attention geometry (mistral-7b-8l: 8 lanes, 32 heads
+# over 8 kv heads of 128, 2049 pages of 16, 256 of them a sequence).  A
+# head of 128 is a whole lane tile, so the compiler keeps such a pool
+# row-major and the k/v write has to leave no copy of it
+_HD128_LAYER = dict(b=8, nh=32, nkv=8, hd=128, pages=2049, ps=16, ppseq=256)
+
+
+def _pool_copies(text: str, pool) -> int:
+    """``copy`` instructions of a compiled program that produce a whole
+    page pool: a layout change around the k/v write or the Mosaic call,
+    the pool read and written once each."""
+    import re
+    dt = {"float32": "f32", "bfloat16": "bf16"}[str(pool.dtype)]
+    shape = f"{dt}[{','.join(str(n) for n in pool.shape)}]"
+    return len(re.findall(rf"= {re.escape(shape)}\S* copy\(", text))
+
+
+def _compile_serve_layer(qw: int, b, nh, nkv, hd, pages, ps, ppseq,
+                         sharding=None):
+    """One layer's attention of the ragged step (the k/v write into both
+    donated pools, then the ragged kernel) compiled at chunk width
+    ``qw``, for the attached device or for the described one that
+    ``sharding`` names.  Returns ``(compiled, pool)``, the pool as its
+    abstract shape."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models.generation import _scatter_pages
+    from paddle_tpu.ops.pallas.ragged_paged_attention import _ragged_pallas
+
+    def layer(pools, q, k, v, page_ids, slots, kv_lens, q_lens, tables):
+        kp = _scatter_pages(pools[0], k, page_ids, slots)
+        vp = _scatter_pages(pools[1], v, page_ids, slots)
+        return _ragged_pallas(q, kp, vp, kv_lens, q_lens, tables,
+                              1.0 / math.sqrt(hd)), (kp, vp)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    pool = sds((nkv, pages, ps, hd), jnp.float32)
+    compiled = jax.jit(layer, donate_argnums=(0,)).lower(
+        (pool, pool), sds((b, qw, nh, hd), jnp.float32),
+        sds((b, qw, nkv, hd), jnp.float32),
+        sds((b, qw, nkv, hd), jnp.float32), sds((b, qw), jnp.int32),
+        sds((b, qw), jnp.int32), sds((b,), jnp.int32),
+        sds((b,), jnp.int32), sds((b, ppseq), jnp.int32)).compile()
+    return compiled, pool
+
+
+def _check_pool_copies(engine, rehearse: bool) -> dict:
+    """Whole-pool copies in the compiled decode-only step.  At heads of
+    128 there must be none: the k/v write is a row scatter into the
+    pool seen as ``[nkv * P * ps, hd]``, whose layout is the Mosaic
+    call's.  The smoke's own heads of 96 are not a whole lane tile and
+    the compiler still re-lays such a pool around the kernel: counted
+    and reported, not failed.  The CPU backend donates nothing and has
+    layouts of its own, so the rehearsal only exercises the code."""
+    own = engine._program(1).lower(
+        *_engine_program_args(engine, 1)).compile()
+    n_own = _pool_copies(own.as_text(), engine._pools[0][0])
+    geometry = dict(_HD128_LAYER)
+    if rehearse:
+        geometry.update(pages=33, ppseq=4)
+    layer, pool = _compile_serve_layer(1, **geometry)
+    n_128 = _pool_copies(layer.as_text(), pool)
+    print(f"  whole-pool copies in the compiled Q=1 programs: "
+          f"{n_own} in the engine's step (pools "
+          f"{list(engine._pools[0][0].shape)}), {n_128} in one layer at "
+          f"the batch cell's geometry (pools {list(pool.shape)})",
+          flush=True)
+    if not rehearse:
+        _check(n_128 == 0, "the k/v write and the ragged kernel at heads "
+                           "of 128 compile with no copy of a page pool")
+    return {"pool_copies_q1": n_own, "pool_copies_q1_hd128_layer": n_128}
+
+
 def phase_serve(rehearse: bool) -> dict:
     import numpy as np
     sz = _sizes(rehearse)
@@ -517,12 +592,13 @@ def phase_serve(rehearse: bool) -> dict:
                f"(Q={max(buckets)})")
         _check_kernels(engine._program(1), _engine_program_args(engine, 1),
                        ("_ragged_kernel",), rehearse, "ragged step (Q=1)")
+        pool_copies = _check_pool_copies(engine, rehearse)
     report = {"phase": "serve", "device": dev, "preset": sz["preset"],
               "requests_per_wave": len(sz["prompts"]) + 2,
               "new_tokens": n_new,
               "q_buckets": buckets, "cold_wave_s": cold_wall,
               "warm_wave_s": warm_wall, "warm_request_s": warm_secs,
-              "peak_bytes_in_use": _peak_bytes()}
+              "peak_bytes_in_use": _peak_bytes(), **pool_copies}
     print(f"serve: cold wave (compiles included) {cold_wall} s, warm wave "
           f"{warm_wall} s, peak_bytes_in_use "
           f"{report['peak_bytes_in_use']}", flush=True)

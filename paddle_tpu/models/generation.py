@@ -424,15 +424,53 @@ def _compiled_decode(model, arr, max_new_tokens, decode_strategy,
 # the ragged batched decode step (continuous-batching serving engine)
 # ---------------------------------------------------------------------------
 
+# XLA's TPU scatter takes index rows as they come at ~73 ns a row and,
+# once they are sorted, at ~0.4 ms flat plus ~9 ns a row; by itself it
+# sorts only from 65,536 rows.  The step's write sorts from this many
+# (Q=128 at eight lanes and eight kv heads), where sorting starts to pay
+# (v5e, f32 rows of 128: PERF.md section 6, PR 26)
+_SORT_ROWS_FROM = 8192
+
+
 def _scatter_pages(pages, vals, page_ids, slots):
     """Write one step's new k/v rows into the page pools.  ``pages
     [nkv, P, ps, hd]``; ``vals [B, Q, nkv, hd]``; ``page_ids/slots
     [B, Q]`` (padding slots target the engine's sink page, never read
-    back)."""
-    nkv, hd = vals.shape[2], vals.shape[3]
-    flat = jnp.swapaxes(vals.reshape(-1, nkv, hd), 0, 1)   # [nkv, BQ, hd]
-    return pages.at[:, page_ids.reshape(-1), slots.reshape(-1)].set(
-        flat.astype(pages.dtype))
+    back; several may name one row, and any of them wins).  A row whose
+    page or slot lies outside the pool is dropped.
+
+    The pool is written as ``[nkv * P * ps, hd]``: one scattered row
+    per (token, kv head), ``hd`` the only window axis.  That view is a
+    bitcast of the row-major layout the ragged kernel's Mosaic call
+    takes, so with the pools donated the scatter runs in place and the
+    compiled step holds no copy and no temporary of a pool's size.
+    (Scattered over axes 1 and 2 with ``nkv`` as a window, as
+    ``pages.at[:, page_ids, slots].set``, XLA's layout assignment puts
+    the scattered axes first and the step re-lays every pool twice.)
+    ``tests/test_smoke_chip.py`` guards that on a described v5e and
+    ``chip_smoke.py`` on the chip; ``tests/test_serving.py`` holds the
+    values to the old expression's.  A wide chunk's rows are sorted
+    first (``_SORT_ROWS_FROM``; the sort is the same for every pool of
+    a step, so XLA keeps one).  Index constants are pinned int32
+    (``jax_enable_x64`` is on)."""
+    nkv, n_pages, ps, hd = pages.shape
+    p = page_ids.reshape(-1).astype(jnp.int32)
+    s = slots.reshape(-1).astype(jnp.int32)
+    n_rows = nkv * n_pages * ps
+    ok = (p >= 0) & (p < n_pages) & (s >= 0) & (s < ps)
+    row = jnp.where(ok, p * jnp.int32(ps) + s, jnp.int32(n_rows))
+    head0 = jnp.arange(nkv, dtype=jnp.int32) * jnp.int32(n_pages * ps)
+    idx = (row[:, None] + head0[None, :]).reshape(-1)       # [BQ * nkv]
+    upd = vals.reshape(-1, hd).astype(pages.dtype)
+    in_order = idx.shape[0] >= _SORT_ROWS_FROM
+    if in_order:
+        idx, perm = jax.lax.sort(
+            (idx, jnp.arange(idx.shape[0], dtype=jnp.int32)), num_keys=1)
+        upd = upd.at[perm].get(mode="promise_in_bounds",
+                               unique_indices=True)
+    flat = pages.reshape(n_rows, hd).at[idx].set(
+        upd, mode="drop", indices_are_sorted=in_order)
+    return flat.reshape(pages.shape)
 
 
 def _last_valid_rows(h, q_lens):

@@ -1275,8 +1275,12 @@ class ServingEngine:
 
         # the name the program carries in a profiler trace and in HLO
         program.__name__ = f"serve_step_q{qw}"
-        # pools are index 3; donated so XLA reuses the page buffers in
-        # place across iterations (CPU has no donation support)
+        # pools are index 3; donated, so each output pool aliases its
+        # input buffer (CPU has no donation support).  Donation alone
+        # does not keep a step from copying them: the k/v write has to
+        # leave each pool in the layout the ragged kernel takes, which
+        # generation._scatter_pages does and tests/test_smoke_chip.py
+        # guards on a described v5e
         donate = (3,) if jax.default_backend() != "cpu" else ()
         prog = jax.jit(program, donate_argnums=donate)
         self._programs[key] = prog

@@ -180,6 +180,75 @@ def test_ragged_reference_matches_dense_attention(rng):
 
 
 # ---------------------------------------------------------------------------
+# the step's k/v write into the page pools
+# ---------------------------------------------------------------------------
+
+def _scatter_pages_oracle(pages, vals, page_ids, slots):
+    """The write as it stood before the flat-row form: right, and on a
+    TPU two layout copies of every pool a step."""
+    import jax.numpy as jnp
+    nkv, hd = vals.shape[2], vals.shape[3]
+    flat = jnp.swapaxes(vals.reshape(-1, nkv, hd), 0, 1)
+    return pages.at[:, page_ids.reshape(-1), slots.reshape(-1)].set(
+        flat.astype(pages.dtype))
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in eqn.params.values():
+            if hasattr(sub, "jaxpr"):
+                yield from _eqns(sub.jaxpr)
+
+
+@pytest.mark.parametrize("qw", [1, 40, 1024])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("hd", [96, 128])
+def test_scatter_pages_matches_the_old_write(rng, hd, dtype, qw):
+    """Lane 0 writes a full chunk that starts mid-page and so crosses
+    page boundaries, lane 1 a third of one and pads the rest onto the
+    sink, lane 2 aims past the pool and is dropped.  Every page but the
+    sink (duplicate writes, never read back) equals the old expression's,
+    with the rows sorted first (Q 1024) and as they come, and the
+    scatter's index operand is int32 though the ids arrive as int64
+    under ``jax_enable_x64``."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models.generation import _SORT_ROWS_FROM, _scatter_pages
+    assert jax.config.jax_enable_x64
+    nkv, ps, b = 4, 16, 3
+    ppseq = -(-(qw + 5) // ps)
+    sink = b * ppseq
+    tables = rng.permutation(sink).reshape(b, ppseq)
+    page_ids = np.full((b, qw), sink, "int64")
+    slots = np.zeros((b, qw), "int64")
+    for lane, (start, count) in enumerate([(5, qw), (0, -(-qw // 3))]):
+        pos = np.arange(start, start + count)
+        page_ids[lane, :count] = tables[lane, pos // ps]
+        slots[lane, :count] = pos % ps
+    page_ids[2] = sink + 1 + np.arange(qw) % 3
+    pages = jnp.asarray(rng.randn(nkv, sink + 1, ps, hd), dtype)
+    vals = jnp.asarray(rng.randn(b, qw, nkv, hd).astype("float32"))
+    got = _scatter_pages(pages, vals, page_ids, slots)
+    want = _scatter_pages_oracle(pages, vals, page_ids, slots)
+    assert got.shape == pages.shape and got.dtype == pages.dtype
+    np.testing.assert_array_equal(
+        np.asarray(got[:, :sink], np.float32),
+        np.asarray(want[:, :sink], np.float32))
+    assert not np.array_equal(np.asarray(got[:, :sink], np.float32),
+                              np.asarray(pages[:, :sink], np.float32))
+    eqns = list(_eqns(jax.make_jaxpr(_scatter_pages)(
+        pages, vals, page_ids, slots).jaxpr))
+    # a wide chunk's index rows are sorted first, a narrow one's are not
+    assert any(e.primitive.name == "sort" for e in eqns) \
+        == (b * qw * nkv >= _SORT_ROWS_FROM) == (qw == 1024)
+    scatters = [e for e in eqns if e.primitive.name.startswith("scatter")]
+    assert len(scatters) == 1
+    assert scatters[0].invars[1].aval.dtype == np.int32
+    assert scatters[0].invars[0].aval.shape == (nkv * (sink + 1) * ps, hd)
+
+
+# ---------------------------------------------------------------------------
 # page pool + scheduler
 # ---------------------------------------------------------------------------
 

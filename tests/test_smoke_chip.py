@@ -37,6 +37,9 @@ def test_chip_smoke_rehearsal_is_green():
     detail = json.loads(report.removeprefix("report: "))
     assert detail["rehearsal"] is True
     assert set(detail["phases"]) == {"train", "serve"}
+    # counted on the CPU too, asserted only where a chip compiles it
+    assert {"pool_copies_q1", "pool_copies_q1_hd128_layer"} \
+        <= set(detail["phases"]["serve"])
 
 
 def test_chip_smoke_without_a_chip_fails_and_prints_no_result():
@@ -44,6 +47,57 @@ def test_chip_smoke_without_a_chip_fails_and_prints_no_result():
     assert proc.returncode != 0
     assert '"ok"' not in proc.stdout
     assert "needs platform 'tpu'" in proc.stderr
+
+
+_AOT_SERVE_LAYER = """
+import json
+import os
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+import jax
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+import chip_smoke
+# a compile for a described device is written to the persistent cache
+# and cannot be read back without the chip
+jax.config.update("jax_enable_compilation_cache", False)
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print("NO_TOPOLOGY " + repr(e))
+    raise SystemExit(0)
+one_chip = SingleDeviceSharding(topo.devices[0])
+out = {}
+for qw in (1, 128):
+    compiled, pool = chip_smoke._compile_serve_layer(
+        qw, sharding=one_chip, **chip_smoke._HD128_LAYER)
+    text = compiled.as_text()
+    out[qw] = {"pool_copies": chip_smoke._pool_copies(text, pool),
+               "kernels": text.count("tpu_custom_call"),
+               "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+               "pool_bytes": pool.size * pool.dtype.itemsize}
+print("RESULT " + json.dumps(out))
+"""
+
+
+def test_serve_layer_compiles_for_v5e_with_no_copy_of_a_page_pool():
+    """The guard that keeps ``copy f32[8,2049,16,128]`` (a fifth of the
+    batch cell's busy time until PR 26) from coming back: one layer of
+    the ragged step — ``_scatter_pages`` on both donated pools, then the
+    Mosaic kernel — compiled ahead of time for a v5e at the batch cell's
+    geometry holds no copy of a pool, and at Q=1 its temporaries are a
+    small fraction of one pool.  In a child, because a Mosaic check
+    failure aborts the process."""
+    proc = _run(["-c", _AOT_SERVE_LAYER], env={"JAX_PLATFORMS": "cpu"})
+    lines = proc.stdout.strip().splitlines()
+    if lines and lines[-1].startswith("NO_TOPOLOGY"):
+        pytest.skip(f"no v5e topology can be described here: {lines[-1]}")
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+    out = json.loads(lines[-1].removeprefix("RESULT "))
+    for qw in ("1", "128"):
+        assert out[qw]["kernels"] >= 1, out
+        assert out[qw]["pool_copies"] == 0, out
+    assert out["1"]["temp_bytes"] < out["1"]["pool_bytes"] // 8, out
 
 
 _REPORT_CACHE_DIR = """
