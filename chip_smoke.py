@@ -408,6 +408,19 @@ def _engine_program_args(engine, qw: int):
 # head of 128 is a whole lane tile, so the compiler keeps such a pool
 # row-major and the k/v write has to leave no copy of it
 _HD128_LAYER = dict(b=8, nh=32, nkv=8, hd=128, pages=2049, ps=16, ppseq=256)
+# the third configuration's two attention geometries (mimo-v2.5-7l-ep32:
+# 64 query heads, keys of 192 and values of 128).  A full layer: 4 kv
+# heads over the shared pool of 4097 pages, 512 a sequence.  A window
+# layer: 8 kv heads, a window of 128 with a sink a head, and a ring of
+# 73 pages a lane (8 x 73 + the sink).  Keys of 192 are a tile and a
+# half, and a pool that wide is re-laid twice a step around the Mosaic
+# call (hd=192 here shows it: 2 copies of the key pool at Q=1 and at
+# Q=1024), so the program pads its key pools to 256
+# (generation._pool_width), which is the geometry guarded here
+_KEY192_FULL_LAYER = dict(b=8, nh=64, nkv=4, hd=256, hdv=128, pages=4097,
+                          ps=16, ppseq=512)
+_KEY192_WINDOW_LAYER = dict(b=8, nh=64, nkv=8, hd=256, hdv=128, pages=585,
+                            ps=16, ppseq=73, window=128, sink=True)
 
 
 def _pool_copies(text: str, pool) -> int:
@@ -421,33 +434,40 @@ def _pool_copies(text: str, pool) -> int:
 
 
 def _compile_serve_layer(qw: int, b, nh, nkv, hd, pages, ps, ppseq,
-                         sharding=None):
+                         sharding=None, hdv=None, window=None, sink=False):
     """One layer's attention of the ragged step (the k/v write into both
     donated pools, then the ragged kernel) compiled at chunk width
     ``qw``, for the attached device or for the described one that
-    ``sharding`` names.  Returns ``(compiled, pool)``, the pool as its
+    ``sharding`` names.  ``hdv``: values narrower than keys; ``window``,
+    ``sink``: a window layer's kernel over a ring of ``ppseq`` pages a
+    lane.  Returns ``(compiled, pool)``, the key pool as its
     abstract shape."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.models.generation import _scatter_pages
     from paddle_tpu.ops.pallas.ragged_paged_attention import _ragged_pallas
+    hdv = hd if hdv is None else hdv
 
-    def layer(pools, q, k, v, page_ids, slots, kv_lens, q_lens, tables):
+    def layer(pools, q, k, v, page_ids, slots, kv_lens, q_lens, tables,
+              sinks):
         kp = _scatter_pages(pools[0], k, page_ids, slots)
         vp = _scatter_pages(pools[1], v, page_ids, slots)
         return _ragged_pallas(q, kp, vp, kv_lens, q_lens, tables,
-                              1.0 / math.sqrt(hd)), (kp, vp)
+                              1.0 / math.sqrt(hd), window,
+                              sinks if sink else None), (kp, vp)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     pool = sds((nkv, pages, ps, hd), jnp.float32)
     compiled = jax.jit(layer, donate_argnums=(0,)).lower(
-        (pool, pool), sds((b, qw, nh, hd), jnp.float32),
+        (pool, sds((nkv, pages, ps, hdv), jnp.float32)),
+        sds((b, qw, nh, hd), jnp.float32),
         sds((b, qw, nkv, hd), jnp.float32),
-        sds((b, qw, nkv, hd), jnp.float32), sds((b, qw), jnp.int32),
+        sds((b, qw, nkv, hdv), jnp.float32), sds((b, qw), jnp.int32),
         sds((b, qw), jnp.int32), sds((b,), jnp.int32),
-        sds((b,), jnp.int32), sds((b, ppseq), jnp.int32)).compile()
+        sds((b,), jnp.int32), sds((b, ppseq), jnp.int32),
+        sds((nh,), jnp.float32)).compile()
     return compiled, pool
 
 
@@ -472,10 +492,27 @@ def _check_pool_copies(engine, rehearse: bool) -> dict:
           f"{list(engine._pools[0][0].shape)}), {n_128} in one layer at "
           f"the batch cell's geometry (pools {list(pool.shape)})",
           flush=True)
+    # the third configuration's two kinds of layer: keys of 192 in pools
+    # padded to 256, values of 128; a ring, a window and sinks in one
+    wide = {}
+    for kind, geo in (("full", _KEY192_FULL_LAYER),
+                      ("window", _KEY192_WINDOW_LAYER)):
+        geo = dict(geo)
+        if rehearse:
+            geo.update(pages=geo["b"] * 3 + 1, ppseq=3)
+        layer, pool = _compile_serve_layer(1, **geo)
+        wide[kind] = _pool_copies(layer.as_text(), pool)
+    print(f"  and in one full and one window layer at keys of 192 "
+          f"(pools 256 wide): {wide}", flush=True)
     if not rehearse:
         _check(n_128 == 0, "the k/v write and the ragged kernel at heads "
                            "of 128 compile with no copy of a page pool")
-    return {"pool_copies_q1": n_own, "pool_copies_q1_hd128_layer": n_128}
+        _check(not any(wide.values()),
+               "a full and a window layer at keys of 192 padded to 256 "
+               "compile with no copy of a key pool")
+    return {"pool_copies_q1": n_own, "pool_copies_q1_hd128_layer": n_128,
+            "pool_copies_q1_key192_full_layer": wide["full"],
+            "pool_copies_q1_key192_window_layer": wide["window"]}
 
 
 def phase_serve(rehearse: bool) -> dict:

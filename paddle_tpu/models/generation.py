@@ -32,7 +32,9 @@ step (``use_cache=False``) — identical tokens, O(n^2) instead of O(n).
 """
 from __future__ import annotations
 
-from typing import Optional
+import math
+from dataclasses import dataclass
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,7 +45,8 @@ from ..flags import get_flag
 from ..random_state import default_generator
 
 __all__ = ["generate", "decode_loop", "build_ragged_decode_step",
-           "build_fused_window_step"]
+           "build_fused_window_step", "AttentionKind", "FeedForwardKind",
+           "LayerDescription", "CacheDescription"]
 
 _GREEDY = ("greedy_search", "greedy")
 
@@ -421,6 +424,122 @@ def _compiled_decode(model, arr, max_new_tokens, decode_strategy,
 
 
 # ---------------------------------------------------------------------------
+# what a model says about its layers, and the page pools that follow
+# ---------------------------------------------------------------------------
+
+@dataclass(frozen=True)
+class AttentionKind:
+    """One layer's attention.  ``window`` None: full causal attention,
+    whose cache holds every token; else the last ``window`` keys are
+    visible and the cache holds a ring of them.  ``value_scale``
+    multiplies the value rows before the weighted sum."""
+    window: Optional[int]
+    kv_heads: int
+    key_dim: int
+    value_dim: int
+    rotary_dim: int = 0
+    rope_theta: float = 10000.0
+    sink: bool = False
+    value_scale: float = 1.0
+
+
+@dataclass(frozen=True)
+class FeedForwardKind:
+    """One layer's feed-forward.  ``held`` None: dense SwiGLU of
+    ``width``; else experts of ``width`` behind a router over
+    ``router_width`` of which each row takes ``top_k``, and of which
+    this chip holds ``held = (first, count)``."""
+    width: int
+    router_width: int = 0
+    top_k: int = 0
+    held: Optional[Tuple[int, int]] = None
+
+
+@dataclass(frozen=True)
+class LayerDescription:
+    attention: AttentionKind
+    feed_forward: FeedForwardKind
+
+
+class CacheDescription:
+    """The page pools a ragged step takes, layer by layer — the one
+    place the serving engine (and anything else that feeds a step)
+    learns their geometry from.  ``build_ragged_decode_step`` hangs it
+    on the step it returns (``step.cache``).
+
+    A full layer's pools are ``[kv_heads, num_pages, page_size, dim]``
+    (keys ``key_dim`` wide, values ``value_dim``), shared through the
+    scheduler's ``PagePool`` and ``tables``; the last page is the sink
+    that padding rows write to.  A window layer's pools do not grow
+    with the sequence: each lane owns a **ring** of ``ring_pages()``
+    pages in ``[kv_heads, max_batch * ring + 1, page_size, dim]`` (the
+    last page again the sink), position ``p`` lives in ring entry
+    ``(p // page_size) % ring``, and a lane's ring page ids ride behind
+    its full-layer pages in ``tables`` (:meth:`tables`)."""
+
+    def __init__(self, layers):
+        # per layer: (kv_heads, key_dim, value_dim, window or None)
+        self.layers = tuple(
+            (int(n), int(dk), int(dv), None if w is None else int(w))
+            for n, dk, dv, w in layers)
+        windows = {w for _, _, _, w in self.layers if w is not None}
+        if len(windows) > 1:
+            raise ValueError(f"window layers of unlike windows {windows} "
+                             "would need rings of unlike sizes")
+        self.window = windows.pop() if windows else None
+        self.n_window = sum(1 for layer in self.layers
+                            if layer[3] is not None)
+
+    def ring_pages(self, page_size: int, max_chunk: int) -> int:
+        """Pages of one lane's ring: the window, the widest chunk a step
+        writes before it attends, and one page of slack for a chunk that
+        starts inside a page.  0 for a model with no window layer."""
+        if self.window is None:
+            return 0
+        return -(-(self.window + int(max_chunk)) // int(page_size)) + 1
+
+    def pool_shapes(self, num_pages: int, page_size: int, max_batch: int,
+                    ring_pages: int = 0):
+        out = []
+        for nkv, dk, dv, window in self.layers:
+            pages = int(num_pages) if window is None \
+                else int(max_batch) * int(ring_pages) + 1
+            out.append(((nkv, pages, int(page_size), dk),
+                        (nkv, pages, int(page_size), dv)))
+        return tuple(out)
+
+    def new_pools(self, num_pages: int, page_size: int, dtype,
+                  max_batch: int, ring_pages: int = 0):
+        """Fresh zeroed pools, one ``(k_pages, v_pages)`` pair a layer."""
+        return tuple(
+            (jnp.zeros(k, dtype), jnp.zeros(v, dtype))
+            for k, v in self.pool_shapes(num_pages, page_size, max_batch,
+                                         ring_pages))
+
+    @staticmethod
+    def tables(full_tables, rings, ring_pages: int):
+        """``tables`` as a step takes them: each row's full-layer page
+        ids, then the page ids of ring ``rings[row]`` (ring ``r`` owns
+        window-pool pages ``r * ring_pages ..``).  With no ring the
+        full-layer tables themselves."""
+        full_tables = np.asarray(full_tables, "int32")
+        if not ring_pages:
+            return full_tables
+        ring = np.asarray(rings, "int32")[:, None] * np.int32(ring_pages) \
+            + np.arange(ring_pages, dtype="int32")[None, :]
+        return np.concatenate([full_tables, ring], axis=1)
+
+    @staticmethod
+    def split_tables(tables, window_pool_pages: int):
+        """:meth:`tables` undone inside a step: ``(full_tables, ring)``
+        from ``tables [B, ppseq + ring_pages]`` and the pages of a window
+        layer's pool (``B * ring_pages + 1``, :meth:`pool_shapes`)."""
+        ring_pages = (int(window_pool_pages) - 1) // tables.shape[0]
+        cut = tables.shape[1] - ring_pages
+        return tables[:, :cut], tables[:, cut:]
+
+
+# ---------------------------------------------------------------------------
 # the ragged batched decode step (continuous-batching serving engine)
 # ---------------------------------------------------------------------------
 
@@ -507,10 +626,19 @@ def build_ragged_decode_step(model):
     the eager ``generate`` output.
 
     Works for any model whose ``build_decode_step`` params carry the
-    GPT (``blocks``) or LLaMA (``layers``) layout."""
+    GPT (``blocks``) or LLaMA (``layers``) layout, and for a model that
+    describes its layers (``config.layer_descriptions()`` and
+    ``described_params()``: attention and feed-forward kinds that differ
+    from layer to layer), whose step also returns a third value, its
+    routing counts (:func:`_build_described_step`).
+
+    The step carries ``step.cache``, the :class:`CacheDescription` of
+    the ``pools`` it takes."""
     from ..ops.pallas import fused_decode as _fd
     from ..ops.pallas.ragged_paged_attention import ragged_paged_attention
 
+    if hasattr(model, "described_params"):
+        return _build_described_step(model)
     params, _ = model.build_decode_step()
     c = model.config
     nh = int(c.num_heads)
@@ -554,6 +682,8 @@ def build_ragged_decode_step(model):
                                 jnp.swapaxes(w, -1, -2))
             return logits, tuple(new_pools)
 
+        step.cache = CacheDescription(
+            [(nh, hd, hd, None)] * len(params["blocks"]))
         return params, step
 
     if "layers" in params:                              # LLaMA family
@@ -603,12 +733,186 @@ def build_ragged_decode_step(model):
                                 jnp.swapaxes(w, -1, -2))
             return logits, tuple(new_pools)
 
+        step.cache = CacheDescription(
+            [(nkv, hd, hd, None)] * len(params["layers"]))
         return params, step
 
     raise TypeError(
         f"{type(model).__name__}.build_decode_step() params carry "
         "neither a GPT ('blocks') nor a LLaMA ('layers') layout — "
         "build_ragged_decode_step has no adapter for it")
+
+
+def _build_described_step(model):
+    """The ragged step of a model that describes its layers: one body
+    written against ``LayerDescription`` — attention full or windowed,
+    key-value heads and key and value widths of the layer's own, a
+    rotation over the first ``rotary_dim`` dimensions from the layer's
+    own base, a sink or none; feed-forward dense or routed experts of
+    which this chip holds some.
+
+    ``step`` takes the arguments of every ragged step.  ``pools`` is
+    what ``step.cache`` describes.  Window layers write and read a ring
+    (``CacheDescription``): their page ids and slots are derived here
+    from ``pos`` and the ring page ids behind the full-layer pages in
+    ``tables``; ``page_ids``/``slots`` serve the full layers alone.
+
+    With an expert layer ``step`` returns ``(logits, pools', counts)``:
+    ``counts i32[3]`` are the rows routed to held experts summed over
+    layers, the fullest held expert's rows (max over layers) and the
+    held experts with at least one row summed over layers."""
+    from ..ops.pallas import fused_decode as _fd
+    from ..ops.pallas.ragged_paged_attention import ragged_paged_attention
+    from ..ops.routed_experts import held_experts_swiglu, \
+        sigmoid_topk_route
+
+    params = model.described_params()
+    c = model.config
+    descs = tuple(c.layer_descriptions())
+    nh = int(c.num_heads)
+    hidden = int(c.hidden_size)
+    eps = float(c.rms_eps)
+    cache = CacheDescription(
+        [(d.attention.kv_heads, _pool_width(d.attention.key_dim),
+          _pool_width(d.attention.value_dim), d.attention.window)
+         for d in descs])
+    window_layer = next((i for i, d in enumerate(descs)
+                         if d.attention.window is not None), None)
+    has_experts = any(d.feed_forward.held is not None for d in descs)
+    i32 = jnp.int32
+
+    def body(p, tok, pos, pools, page_ids, slots, kv_lens, q_lens,
+             tables):
+        b, qw = tok.shape
+        x = jnp.take(p["embed"], tok, axis=0)             # [B, Q, H]
+        valid = jnp.arange(qw, dtype=i32)[None, :] \
+            < q_lens.astype(i32)[:, None]                 # [B, Q]
+        pos = pos.astype(i32)
+        full_tables = tables
+        if window_layer is not None:
+            wpool = pools[window_layer][0]
+            ps = wpool.shape[2]
+            full_tables, ring = cache.split_tables(tables, wpool.shape[1])
+            entry = (pos // i32(ps)) % i32(ring.shape[1])
+            ring_ids = jnp.where(
+                valid, jnp.take_along_axis(ring.astype(i32), entry, axis=1),
+                i32(wpool.shape[1] - 1))                  # padding: sink
+            ring_slots = jnp.where(valid, pos % i32(ps), i32(0))
+        rope = {theta: (jnp.take(cos, pos, axis=0)[:, :, None, :],
+                        jnp.take(sin, pos, axis=0)[:, :, None, :])
+                for theta, (cos, sin) in p["rope"].items()}
+        counts = [i32(0), i32(0), i32(0)]
+        new_pools = []
+        for i, (d, lp) in enumerate(zip(descs, p["layers"])):
+            att, ff = d.attention, d.feed_forward
+            nkv, dk, dv = att.kv_heads, att.key_dim, att.value_dim
+            h2 = _fd.reference_rms_norm(x, lp["ln1_w"], eps) \
+                .reshape(b * qw, hidden)
+            qkv = jnp.matmul(h2, lp["wqkv"])
+            qp = qkv[:, :nh * dk].reshape(b, qw, nh, dk)
+            kp = qkv[:, nh * dk:(nh + nkv) * dk].reshape(b, qw, nkv, dk)
+            vp = qkv[:, (nh + nkv) * dk:].reshape(b, qw, nkv, dv)
+            if att.value_scale != 1.0:
+                vp = vp * att.value_scale
+            rot = att.rotary_dim
+            if rot:
+                cos, sin = rope[_rope_key(att.rope_theta)]
+                qp = jnp.concatenate(
+                    [_fd.reference_rope_rows(qp[..., :rot], cos, sin,
+                                             neox=True), qp[..., rot:]],
+                    axis=-1)
+                kp = jnp.concatenate(
+                    [_fd.reference_rope_rows(kp[..., :rot], cos, sin,
+                                             neox=True), kp[..., rot:]],
+                    axis=-1)
+            windowed = att.window is not None
+            ids, sl, tb = (ring_ids, ring_slots, ring) if windowed \
+                else (page_ids, slots, full_tables)
+            # rows as wide as the pools (_pool_width): the zeros add
+            # nothing to q.k, and the scale stays the head's own
+            qp, kp, vp = (_pad_last(a, pool.shape[-1]) for a, pool in
+                          ((qp, pools[i][0]), (kp, pools[i][0]),
+                           (vp, pools[i][1])))
+            kpg = _scatter_pages(pools[i][0], kp, ids, sl)
+            vpg = _scatter_pages(pools[i][1], vp, ids, sl)
+            new_pools.append((kpg, vpg))
+            # Mosaic takes no "high": the kernel's two dots are float32
+            ctx = ragged_paged_attention(
+                qp, kpg, vpg, kv_lens, q_lens, tb,
+                scale=1.0 / math.sqrt(dk), window=att.window,
+                sinks=lp["sink"] if att.sink else None,
+                precision=jax.lax.Precision.HIGHEST)
+            x = x + jnp.matmul(ctx[..., :dv].reshape(b, qw, nh * dv),
+                               lp["wo"])
+            x2 = x.reshape(b * qw, hidden)
+            if ff.held is None:
+                y = _fd.norm_mlp(x2, kind="rms_norm", norm_w=lp["ln2_w"],
+                                 w_gate=lp["wg"], w1=lp["wu"], w2=lp["wd"],
+                                 eps=eps, act="silu")
+            else:
+                h2 = _fd.reference_rms_norm(x2, lp["ln2_w"], eps)
+                picks, weights = sigmoid_topk_route(
+                    h2, lp["router_w"], lp["router_b"], ff.top_k)
+                y, rows = held_experts_swiglu(
+                    h2, picks, weights, valid.reshape(b * qw), lp["wg"],
+                    lp["wu"], lp["wd"], ff.held[0])
+                counts = [counts[0] + jnp.sum(rows, dtype=i32),
+                          jnp.maximum(counts[1], jnp.max(rows)),
+                          counts[2] + jnp.sum(rows > 0, dtype=i32)]
+            x = x + y.reshape(b, qw, hidden)
+        h = _fd.reference_rms_norm(x, p["norm_w"], eps)
+        logits = jnp.matmul(_last_valid_rows(h, q_lens),
+                            jnp.swapaxes(p["lm_w"], -1, -2))
+        if has_experts:
+            return logits, tuple(new_pools), jnp.stack(counts)
+        return logits, tuple(new_pools)
+
+    def step(p, tok, pos, pools, page_ids, slots, kv_lens, q_lens,
+             tables):
+        # float32 served as float32: at jax's default a float32 product
+        # is ONE bf16 pass on the MXU, which this model's logits check
+        # could not tell from serving in bfloat16 (the program read
+        # 1.3e-2..4.0e-2 of the largest logit over nine seeds, flipped
+        # expert selections included, where the reference in bfloat16
+        # reads 2.7e-2..3.9e-2; at "high", three passes, with the
+        # attention kernel told "highest", it reads 6e-5: PERF.md
+        # section 6, PR 27)
+        with jax.default_matmul_precision(_DESCRIBED_PRECISION):
+            return body(p, tok, pos, pools, page_ids, slots, kv_lens,
+                        q_lens, tables)
+
+    step.cache = cache
+    step.routing_counts = has_experts
+    return params, step
+
+
+# the matmul precision of a described model's ragged step
+_DESCRIBED_PRECISION = "high"
+
+
+def _pool_width(dim: int) -> int:
+    """The last axis of a described model's page pool for rows of
+    ``dim``.  A head wider than the 128-lane tile and not a whole number
+    of tiles (keys of 192) is padded up to one: the compiler's own
+    layout for ``f32[nkv, P, ps, 192]`` is not the row-major one the
+    Mosaic call takes, and every step would re-lay each such pool twice
+    (two whole-pool copies a pool a step at Q=1 and Q=1024, sandbox AOT
+    for a v5e, PR 27; none at 256).  The price is pool memory and key
+    bytes read: a third more at 192.  Narrower heads stay as they are
+    (heads of 96 copy too; ROADMAP S15)."""
+    return dim if dim <= 128 else -(-dim // 128) * 128
+
+
+def _pad_last(a, width: int):
+    if a.shape[-1] == width:
+        return a
+    return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, width - a.shape[-1])])
+
+
+def _rope_key(theta: float) -> str:
+    """The name of a rotary base's tables in a described model's
+    ``params["rope"]``."""
+    return f"{float(theta):g}"
 
 
 def build_fused_window_step(model, max_window: int):
@@ -648,6 +952,13 @@ def build_fused_window_step(model, max_window: int):
     broadcast to every lane."""
     from ..ops.pallas.ragged_paged_attention import append_positions
 
+    if hasattr(model, "described_params"):
+        raise TypeError(
+            f"build_fused_window_step does not take "
+            f"{type(model).__name__}: the fused window derives one "
+            f"append cursor a lane from tables and carries no routing "
+            f"counts; serve a model that describes its layers with "
+            f"FLAGS_serving_fused_steps=1")
     params, step = build_ragged_decode_step(model)
 
     def fused_window(params, tok, pools, kv_lens, live, tables, temps,

@@ -110,7 +110,10 @@ EVENT_SCHEMA: Dict[str, Dict[str, str]] = {
     # step_s + page_occupancy make each record a (features, seconds)
     # training sample for the learned perf model.  The *_s phase fields
     # are the loop thread's perf_counter seconds under the engine:*
-    # profiler annotations of the same names (serving/engine.py)
+    # profiler annotations of the same names (serving/engine.py).  The
+    # routing counts come back behind the sampled tokens in the step's
+    # one host read; the pages read are host arithmetic on the plan's
+    # lengths; all five read 0 for a model with no experts or windows
     "batch_step": {"batch": "int", "prefill_seqs": "int",
                    "decode_seqs": "int", "q_width": "int",
                    "tokens": "int", "queue_depth": "int",
@@ -120,7 +123,10 @@ EVENT_SCHEMA: Dict[str, Dict[str, str]] = {
                    "plan_s": "float", "prepare_s": "float",
                    "dispatch_s": "float", "read_s": "float",
                    "commit_s": "float", "host_gap_s": "float",
-                   "wait_s": "float", "admit_queue_s": "object"},
+                   "wait_s": "float", "admit_queue_s": "object",
+                   "expert_rows": "int", "expert_rows_max": "int",
+                   "experts_hit": "int", "window_pages_read": "int",
+                   "full_pages_read": "int"},
     # learned performance model lifecycle (tuning.learned): a versioned
     # model file was fitted/saved from accumulated telemetry
     "perf_model": {"action": "str", "version": "int", "heads": "object",

@@ -319,19 +319,15 @@ class ServingEngine:
                  health_recovery_steps: int = 64,
                  max_watchdog_relaunches: int = 3):
         import jax
-        import jax.numpy as jnp
         from ..flags import get_flag
         if hasattr(model, "eval"):
             model.eval()
         self.model = model
         self._params, self._step_fn = model.build_ragged_decode_step()
+        # the pools' geometry is the step's to say, layer by layer
+        # (models.generation.CacheDescription)
+        self._cache = self._step_fn.cache
         cfg = model.config
-        nh = int(cfg.num_heads)
-        hidden = int(cfg.hidden_size)
-        hd = hidden // nh
-        nkv = int(getattr(cfg, "num_kv_heads", nh) or nh)
-        n_layers = len(self._params["blocks"] if "blocks" in self._params
-                       else self._params["layers"])
         ps = int(page_size)
         max_pos = int(getattr(cfg, "max_position_embeddings", 1024))
         if max_pages_per_seq is None:
@@ -339,13 +335,37 @@ class ServingEngine:
         if num_pages is None:
             # every slot can hold a max-length sequence, plus the sink
             num_pages = int(max_batch) * int(max_pages_per_seq) + 1
+        # window layers keep a ring a lane, sized for the widest chunk
+        # a step may write before it attends
+        self._ring_pages = self._cache.ring_pages(
+            ps, int(max_prefill_chunk) or max_pos)
+        if self._ring_pages and prefix_caching:
+            raise ValueError(
+                f"{type(model).__name__} has window attention layers, "
+                f"whose cache is a ring a lane that holds only the last "
+                f"{self._cache.window} tokens and a chunk: PrefixCache "
+                f"shares PagePool pages and cannot restore a window's "
+                f"tail yet — pass prefix_caching=False")
+        # the fused window (generation.build_fused_window_step) takes
+        # one append cursor a lane from tables and carries no routing
+        # counts: a model with a ring or an expert layer keeps the
+        # single-step path, and asking for more is refused here, to the
+        # caller, not later inside the serving loop
+        self._fusable = not self._ring_pages and not getattr(
+            self._step_fn, "routing_counts", False)
+        if not self._fusable and int(get_flag("serving_fused_steps")
+                                     or 1) > 1:
+            raise ValueError(
+                f"FLAGS_serving_fused_steps="
+                f"{get_flag('serving_fused_steps')} with "
+                f"{type(model).__name__}: the fused window takes no model "
+                f"with window attention or expert layers — set it to 1")
         self.pool = PagePool(num_pages, ps)
         self.prefix_cache = PrefixCache(self.pool) if prefix_caching \
             else None
         # device-pool geometry, kept so a watchdog relaunch can build
         # FRESH buffers (the wedged dispatch may still write into the
         # old ones — they are abandoned wholesale, never reused)
-        self._nkv, self._hd, self._n_layers = nkv, hd, n_layers
         self._num_pages, self._page_size = int(num_pages), ps
         self._dtype = dtype
         self._prefix_caching = bool(prefix_caching)
@@ -370,14 +390,12 @@ class ServingEngine:
             prefix_cache=self.prefix_cache, max_queue=max_queue,
             max_prefill_chunk=max_prefill_chunk,
             max_seq_len=max_pos, perf_model=perf_model,
-            max_step_cost_s=max_step_cost_s)
+            max_step_cost_s=max_step_cost_s,
+            ring_pages=self._ring_pages)
         self.max_batch = int(max_batch)
         self.default_eos = None if eos_token_id is None \
             else int(eos_token_id)
-        self._pools = tuple(
-            (jnp.zeros((nkv, num_pages, ps, hd), dtype),
-             jnp.zeros((nkv, num_pages, ps, hd), dtype))
-            for _ in range(n_layers))
+        self._pools = self._new_pools()
         self._key = jax.random.PRNGKey(int(seed))
         self._programs: dict = {}
         self.engine_id = str(next(_ENGINE_SEQ))
@@ -431,6 +449,12 @@ class ServingEngine:
         self._n_quarantined = 0
         self._n_cancelled = 0
         self._wedged_threads = 0
+
+    def _new_pools(self):
+        """Zeroed device pools of the step's own geometry."""
+        return self._cache.new_pools(
+            self._num_pages, self._page_size, self._dtype, self.max_batch,
+            self._ring_pages)
 
     # -- lifecycle -------------------------------------------------------
     def start(self) -> "ServingEngine":
@@ -673,7 +697,7 @@ class ServingEngine:
                 # machinery — byte for byte
                 fused_w, fused_max, fused_reason = 1, 0, "single_step"
                 if plan is not None and plan.n_prefill == 0 \
-                        and plan.tok.shape[1] == 1 \
+                        and plan.tok.shape[1] == 1 and self._fusable \
                         and not self.scheduler.bisect_groups:
                     # (a bisection episode pins the single-step path:
                     # probe batches must fail one iteration at a time)
@@ -862,11 +886,29 @@ class ServingEngine:
             self._emit_batch_step(
                 phases, plan, plan.n_prefill, int(qw),
                 plan.fed_prefill + plan.fed_decode, step_timer.seconds,
-                cold_start, 1, "single_step")
+                cold_start, 1, "single_step",
+                routing=toks[self.max_batch:])
+
+    def _pages_read(self, plan):
+        """``(window_pages_read, full_pages_read)``: the pages the
+        attention kernels of this step's window and full layers were
+        asked to visit, summed over lanes and layers, from the plan's
+        lengths alone (host arithmetic, no device work)."""
+        live = plan.q_lens > 0
+        kv = plan.kv_lens[live].astype("int64")
+        ps = self._page_size
+        last = (kv - 1) // ps
+        n_window = self._cache.n_window
+        full = int((last + 1).sum()) * (len(self._cache.layers) - n_window)
+        if not n_window:
+            return 0, full
+        oldest = kv - plan.q_lens[live] - (self._cache.window - 1)
+        first = np.maximum(oldest, 0) // ps
+        return int((last - first + 1).sum()) * n_window, full
 
     def _emit_batch_step(self, phases: _LoopPhases, plan, prefill_seqs,
                          q_width, tokens, step_s, cold_start,
-                         fused_steps, exit_reason) -> None:
+                         fused_steps, exit_reason, routing=()) -> None:
         """The step's ``batch_step`` record (under ``_wake``).  step_s +
         page_occupancy make each record a ready-made (features, seconds)
         sample for the learned perf model (analysis.perf_features /
@@ -877,6 +919,9 @@ class ServingEngine:
             return
         plan_s, prepare_s, dispatch_s, read_s, commit_s, host_gap_s, \
             wait_s, admit_queue_s = phases.take()
+        expert_rows, expert_rows_max, experts_hit = \
+            (int(v) for v in routing) if len(routing) else (0, 0, 0)
+        window_pages, full_pages = self._pages_read(plan)
         _events.emit("batch_step", batch=len(plan.seqs),
                      prefill_seqs=prefill_seqs,
                      decode_seqs=plan.n_decode, q_width=q_width,
@@ -891,7 +936,12 @@ class ServingEngine:
                      plan_s=plan_s, prepare_s=prepare_s,
                      dispatch_s=dispatch_s, read_s=read_s,
                      commit_s=commit_s, host_gap_s=host_gap_s,
-                     wait_s=wait_s, admit_queue_s=admit_queue_s)
+                     wait_s=wait_s, admit_queue_s=admit_queue_s,
+                     expert_rows=expert_rows,
+                     expert_rows_max=expert_rows_max,
+                     experts_hit=experts_hit,
+                     window_pages_read=window_pages,
+                     full_pages_read=full_pages)
 
     def _run_window(self, plan, w, max_window, clamp_reason,
                     epoch: int, phases: _LoopPhases):
@@ -1167,8 +1217,6 @@ class ServingEngine:
             self._relaunch_locked()
 
     def _relaunch_locked(self) -> None:
-        import jax  # noqa: F401 — jnp import hides behind it
-        import jax.numpy as jnp
         self._epoch += 1
         epoch = self._epoch
         self._dispatch_t0 = None
@@ -1194,12 +1242,7 @@ class ServingEngine:
         self.prefix_cache = PrefixCache(self.pool) \
             if self._prefix_caching else None
         self.scheduler.rebind_pool(self.pool, self.prefix_cache)
-        self._pools = tuple(
-            (jnp.zeros((self._nkv, self._num_pages, self._page_size,
-                        self._hd), self._dtype),
-             jnp.zeros((self._nkv, self._num_pages, self._page_size,
-                        self._hd), self._dtype))
-            for _ in range(self._n_layers))
+        self._pools = self._new_pools()
         self._thread = threading.Thread(
             target=self._loop, args=(epoch,), daemon=True,
             name=f"serving-engine-{self.engine_id}-e{epoch}")
@@ -1249,11 +1292,13 @@ class ServingEngine:
         if prog is not None:
             return prog
         step = self._step_fn
+        routed = bool(getattr(step, "routing_counts", False))
 
         def program(params, tok, pos, pools, page_ids, slots, kv_lens,
                     q_lens, tables, temps, rng, poison):
-            logits, pools = step(params, tok, pos, pools, page_ids,
-                                 slots, kv_lens, q_lens, tables)
+            out = step(params, tok, pos, pools, page_ids, slots, kv_lens,
+                       q_lens, tables)
+            logits, pools = out[0], out[1]
             # chaos bias (zeros in production — a no-op add) lets the
             # fault injector NaN one lane's logits without a host hook
             logits = logits + poison[:, None]
@@ -1271,6 +1316,11 @@ class ServingEngine:
             # detector and the lane quarantines with no extra sync
             bad = jnp.isnan(logits).any(axis=-1)
             nxt = jnp.where(bad, jnp.int32(-1), nxt)
+            if routed:
+                # a model with expert layers: its step's routing counts
+                # ride behind the sampled tokens in the one array the
+                # host reads (no other model's program has this branch)
+                nxt = jnp.concatenate([nxt, out[2].astype(jnp.int32)])
             return nxt, pools, rng
 
         # the name the program carries in a profiler trace and in HLO

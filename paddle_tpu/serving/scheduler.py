@@ -227,7 +227,8 @@ class _Sequence:
     committed to pages, and the owned/shared page list."""
 
     __slots__ = ("req", "tokens", "kv_len", "pages", "shared",
-                 "cached_tokens", "cache_inserted", "predicted_cost_s")
+                 "cached_tokens", "cache_inserted", "predicted_cost_s",
+                 "ring")
 
     def __init__(self, req: Request):
         self.req = req
@@ -240,6 +241,9 @@ class _Sequence:
         # learned-model step-cost estimate at admission (None: raw
         # page/token caps decided alone); rides serving_admit events
         self.predicted_cost_s: Optional[float] = None
+        # the ring this sequence's window layers write while it runs
+        # (Scheduler.ring_pages > 0); None while it waits
+        self.ring: Optional[int] = None
 
     @property
     def n_generated(self) -> int:
@@ -297,10 +301,23 @@ class Scheduler:
                  max_pages_per_seq: int, prefix_cache=None,
                  max_queue: int = 1024, max_prefill_chunk: int = 0,
                  max_seq_len: int = 0, perf_model=None,
-                 max_step_cost_s: float = 0.0):
+                 max_step_cost_s: float = 0.0, ring_pages: int = 0):
         self.pool = pool
         self.max_batch = int(max_batch)
         self.ppseq = int(max_pages_per_seq)
+        # window layers (models.generation.CacheDescription): every
+        # running sequence owns one of max_batch rings of ring_pages
+        # pages in the window layers' pools, and a plan's ``tables``
+        # carry its ring's page ids behind its PagePool pages.  Rings
+        # are outside PagePool: they never run out (one a batch slot)
+        # and hold nothing once their sequence leaves, so eviction and
+        # resume re-prefill as for any model.  0: no such layer
+        self.ring_pages = int(ring_pages)
+        if self.ring_pages:
+            # the rings' layout is CacheDescription's alone
+            from ..models.generation import CacheDescription
+            self._with_rings = CacheDescription.tables
+        self._free_rings = list(range(self.max_batch))[::-1]
         self.prefix_cache = prefix_cache
         self.max_queue = int(max_queue)
         # page capacity rounds UP to whole pages; the model's position
@@ -406,6 +423,9 @@ class Scheduler:
         seq.pages = []
         seq.shared = set()
         seq.kv_len = 0
+        if seq.ring is not None:
+            self._free_rings.append(seq.ring)
+            seq.ring = None
 
     # -- predicted-cost admission ----------------------------------------
     def _chunk_len(self, seq: _Sequence) -> int:
@@ -508,6 +528,8 @@ class Scheduler:
             return None
         self.waiting.popleft()
         self.running.append(seq)
+        if self.ring_pages:
+            seq.ring = self._free_rings.pop()
         return seq
 
     def _evict_victim(self, protect) -> Optional[_Sequence]:
@@ -564,6 +586,10 @@ class Scheduler:
         self._prestage = None
         self._staged_pred = None
         self.bisect_groups.clear()
+        # the window layers' pools are fresh too: nobody holds a ring
+        self._free_rings = list(range(self.max_batch))[::-1]
+        for seq in self.waiting:
+            seq.ring = None
 
     # -- quarantine bisection (engine fault containment) -----------------
     def bisect_push_front(self, groups) -> None:
@@ -658,6 +684,7 @@ class Scheduler:
         kv_lens = np.zeros((b,), "int32")
         q_lens = np.zeros((b,), "int32")
         tables = np.zeros((b, self.ppseq), "int32")
+        rings = np.zeros((b,), "int32")
         temps = np.zeros((b,), "float32")
         n_prefill = n_decode = 0
         fed_prefill = fed_decode = 0
@@ -673,6 +700,8 @@ class Scheduler:
             kv_lens[i] = start + n
             q_lens[i] = n
             tables[i, :len(seq.pages)] = seq.pages
+            if self.ring_pages:
+                rings[i] = seq.ring
             temps[i] = seq.req.temperature
             if start < len(seq.req.prompt):     # still eating prompt
                 n_prefill += 1
@@ -680,6 +709,8 @@ class Scheduler:
             else:
                 n_decode += 1
                 fed_decode += n
+        if self.ring_pages:
+            tables = self._with_rings(tables, rings, self.ring_pages)
         plan = StepPlan(seqs=[s for s, _ in active],
                         slots_map={s.req.id: i
                                    for i, (s, _) in enumerate(active)},

@@ -128,6 +128,12 @@ def test_spans_reach_a_bare_jax_profiler_trace(tmp_path):
         try:
             with RecordEvent("bare_span"):
                 engine.generate([4, 3, 2, 1], max_new_tokens=4)
+            # a phase reaches the trace when it CLOSES, and the last
+            # step's engine:commit is still open when its request wakes
+            # this thread (it closes at the loop's next switch): one
+            # more request, whose return proves the loop has moved on,
+            # so that the four steps above are whole in the trace
+            engine.generate([1], max_new_tokens=1)
         finally:
             jax.profiler.stop_trace()
     events = _host_events(str(tmp_path))

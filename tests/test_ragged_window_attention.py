@@ -1,0 +1,192 @@
+"""The ragged kernel's window, sink and value width: the Pallas kernel in
+interpret mode against ``ragged_paged_attention_ref``, and the reference
+against attention written out densely."""
+import math
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.flags import get_flags, set_flags
+from paddle_tpu.ops.pallas import ragged_paged_attention as rpa
+
+WINDOW, PS, CHUNK = 8, 4, 6
+
+
+@pytest.fixture
+def interpret():
+    keep = get_flags(["FLAGS_pallas_interpret",
+                      "FLAGS_use_pallas_ragged_attention"])
+    set_flags({"FLAGS_pallas_interpret": True})
+    yield
+    set_flags(keep)
+
+
+def _case(rs, kv_lens, q_lens, qw, ring, nh=4, nkv=2, hd=24, hdv=16):
+    """Pools filled by writing every position of every lane where the
+    page table (a ring, or a plain table) puts it; a ring keeps the last
+    ``R * PS`` positions only."""
+    b = len(kv_lens)
+    ppseq = (-(-(WINDOW + CHUNK) // PS) + 1) if ring \
+        else -(-max(kv_lens) // PS)
+    tables = np.arange(b * ppseq, dtype="int32").reshape(b, ppseq)
+    k = np.zeros((nkv, b * ppseq + 1, PS, hd), "float32")
+    v = np.zeros((nkv, b * ppseq + 1, PS, hdv), "float32")
+    dense_k = [rs.randn(n, nkv, hd).astype("float32") for n in kv_lens]
+    dense_v = [rs.randn(n, nkv, hdv).astype("float32") for n in kv_lens]
+    for i, n in enumerate(kv_lens):
+        for p in range(n):
+            entry = (p // PS) % ppseq if ring else p // PS
+            k[:, tables[i, entry], p % PS] = dense_k[i][p]
+            v[:, tables[i, entry], p % PS] = dense_v[i][p]
+    q = rs.randn(b, qw, nh, hd).astype("float32")
+    return (jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+            jnp.asarray(kv_lens, jnp.int32), jnp.asarray(q_lens, jnp.int32),
+            jnp.asarray(tables)), dense_k, dense_v
+
+
+def _dense(q, dense_k, dense_v, kv_lens, q_lens, window, sinks):
+    """Attention written out: one query row at a time."""
+    b, qw, nh, hd = q.shape
+    out = np.zeros((b, qw, nh, dense_v[0].shape[-1]), "float32")
+    for i in range(b):
+        rep = nh // dense_k[i].shape[1]
+        for j in range(q_lens[i]):
+            pos = kv_lens[i] - q_lens[i] + j
+            lo = 0 if window is None else max(0, pos - window + 1)
+            for h in range(nh):
+                a = dense_k[i][lo:pos + 1, h // rep] @ q[i, j, h] \
+                    / math.sqrt(hd)
+                m = max(a.max(), sinks[h]) if sinks is not None else a.max()
+                e = np.exp(a - m)
+                den = e.sum() + (np.exp(sinks[h] - m)
+                                 if sinks is not None else 0.0)
+                out[i, j, h] = (e / den) @ dense_v[i][lo:pos + 1, h // rep]
+    return out
+
+
+# contexts below, at and far above the window; a decode step and a chunk
+@pytest.mark.parametrize("context", [5, WINDOW, 3 * WINDOW + 1, 61])
+@pytest.mark.parametrize("q_len", [1, CHUNK])
+@pytest.mark.parametrize("sink", [False, True])
+def test_window_kernel_matches_reference_over_a_ring(interpret, rng,
+                                                     context, q_len, sink):
+    kv_lens = [max(q_len, context), max(q_len, context - 3), context + 2]
+    q_lens = [q_len, q_len, 0]                     # the last lane idles
+    args, dk, dv = _case(rng, kv_lens, q_lens, q_len, ring=True)
+    sinks = jnp.asarray(rng.randn(4), jnp.float32) if sink else None
+    assert rpa.available()
+    got = np.asarray(rpa.ragged_paged_attention(
+        *args, window=WINDOW, sinks=sinks))
+    ref = np.asarray(rpa.ragged_paged_attention_ref(
+        *args, window=WINDOW, sinks=sinks))
+    want = _dense(np.asarray(args[0]), dk, dv, kv_lens, q_lens, WINDOW,
+                  None if sinks is None else np.asarray(sinks))
+    assert got.shape == (3, q_len, 4, 16)            # the values' width
+    for i in range(2):
+        np.testing.assert_allclose(got[i], ref[i], atol=2e-6)
+        np.testing.assert_allclose(ref[i], want[i], atol=2e-6)
+
+
+@pytest.mark.parametrize("window", [None, WINDOW])
+def test_values_narrower_than_keys_and_a_sink_over_a_plain_table(
+        interpret, rng, window):
+    """Full attention with a sink and values of another width, and a
+    window over a table that holds the whole sequence (the ring that
+    never wraps)."""
+    kv_lens, q_lens = [29, 13], [CHUNK, 1]
+    args, dk, dv = _case(rng, kv_lens, q_lens, CHUNK, ring=False)
+    sinks = jnp.asarray(rng.randn(4) * 2.0, jnp.float32)
+    got = np.asarray(rpa.ragged_paged_attention(
+        *args, window=window, sinks=sinks))
+    ref = np.asarray(rpa.ragged_paged_attention_ref(
+        *args, window=window, sinks=sinks))
+    want = _dense(np.asarray(args[0]), dk, dv, kv_lens, q_lens, window,
+                  np.asarray(sinks))
+    for i, n in enumerate(q_lens):
+        np.testing.assert_allclose(got[i, :n], ref[i, :n], atol=2e-6)
+        np.testing.assert_allclose(ref[i, :n], want[i, :n], atol=2e-6)
+
+
+def test_a_window_layer_visits_nine_pages_at_any_context():
+    """The grid's page axis of a window layer: the pages that
+    ``rows + W - 1`` consecutive positions can touch, whatever the
+    context — 9 for a decode step at a window of 128 and pages of 16."""
+    assert rpa._window_pages(1, 128, 16) == 9
+    assert rpa._window_pages(8, 128, 16) == 10
+    assert rpa._window_pages(1, 8, 4) == 3
+    # a query at position 8191 (kv_len 8192, one row): its oldest key is
+    # position 8064, on page 504
+    assert int(rpa._first_page(jnp.int32(8192), jnp.int32(1), jnp.int32(0),
+                               128, 16)) == 504
+    assert int(rpa._first_page(jnp.int32(40), jnp.int32(1), jnp.int32(0),
+                               128, 16)) == 0
+
+
+def _paged(rs, kv_lens, ps, ppseq, nkv, hd, hdv):
+    """Every lane's keys and values laid into pools through a plain
+    table of ``ppseq`` entries a lane; entries past a lane's pages stay
+    0, as the scheduler leaves them."""
+    b = len(kv_lens)
+    tables = np.zeros((b, ppseq), "int32")
+    k = rs.randn(nkv, b * ppseq + 1, ps, hd).astype("float32")
+    v = rs.randn(nkv, b * ppseq + 1, ps, hdv).astype("float32")
+    dense_k, dense_v = [], []
+    for i, n in enumerate(kv_lens):
+        pages = -(-n // ps)
+        tables[i, :pages] = 1 + i * ppseq + np.arange(pages)
+        dense_k.append(rs.randn(n, nkv, hd).astype("float32"))
+        dense_v.append(rs.randn(n, nkv, hdv).astype("float32"))
+        for p in range(n):
+            k[:, tables[i, p // ps], p % ps] = dense_k[i][p]
+            v[:, tables[i, p // ps], p % ps] = dense_v[i][p]
+    return k, v, tables, dense_k, dense_v
+
+
+# the causal walk takes 128 keys a grid step: contexts inside one step,
+# at its edge, one key past it and over several steps; a decode step and
+# a chunk of two query tiles beside a decoding and an idle lane
+@pytest.mark.parametrize("context", [100, 128, 129, 300])
+@pytest.mark.parametrize("q_len", [1, 132])
+def test_causal_kernel_takes_several_pages_a_grid_step(interpret, rng,
+                                                       context, q_len):
+    ps, nh, nkv, hd, hdv = 16, 4, 2, 24, 16
+    kv_lens = [max(q_len, context), 45, 0]
+    q_lens = [q_len, 1, 0]
+    # 21 entries: not a whole number of steps of 8 pages
+    k, v, tables, dk, dv = _paged(rng, kv_lens, ps, 21, nkv, hd, hdv)
+    q = rng.randn(3, q_len, nh, hd).astype("float32")
+    assert q_len == 1 or q_len > rpa._block_q(nh, hd, 4)
+    args = (jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+            jnp.asarray(kv_lens, jnp.int32), jnp.asarray(q_lens, jnp.int32),
+            jnp.asarray(tables))
+    got = np.asarray(rpa.ragged_paged_attention(*args))
+    want = _dense(q, dk, dv, kv_lens, q_lens, None, None)
+    for i, n in enumerate(q_lens):
+        np.testing.assert_allclose(got[i, :n], want[i, :n], atol=3e-6)
+
+
+def test_a_tile_s_walk_ends_at_the_block_of_its_last_row():
+    """``_last_block``: where the index maps stop fetching.  A decode
+    row at position 2,499 ends in block 19 of 128 keys; the first tile
+    of a 1,024-row chunk after 1,024 cached tokens ends in block 8, its
+    last tile in block 15; a tile of padding rows stays on block 0."""
+    def last(kv, q, q0, bq=8, keys=128):
+        return int(rpa._last_block(jnp.int32(kv), jnp.int32(q),
+                                   jnp.int32(q0), bq, keys))
+    assert last(2500, 1, 0) == 19
+    assert last(2048, 1024, 0) == 8
+    assert last(2048, 1024, 1016) == 15
+    assert last(2500, 1, 8) == 0          # rows past q_len
+    assert last(0, 0, 0) == 0             # an idle lane
+    assert last(128, 1, 0) == 0 and last(129, 1, 0) == 1
+
+
+def test_query_tiles_fill_half_of_the_vmem_the_kernel_asks_for():
+    """``_block_q`` at the three serve geometries: 64 heads of keys
+    padded to 256 take tiles of 32 rows, 32 heads of 128 and 16 heads of
+    96 the most a tile may be; a decode step keeps one tile of 8."""
+    assert rpa._VMEM_TILE_BUDGET * 2 == rpa._VMEM_LIMIT == 64 << 20
+    assert rpa._block_q(64, 256, 4) == 32
+    assert rpa._block_q(32, 128, 4) == 128
+    assert rpa._block_q(16, 96, 4) == 128

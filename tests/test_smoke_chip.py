@@ -38,7 +38,9 @@ def test_chip_smoke_rehearsal_is_green():
     assert detail["rehearsal"] is True
     assert set(detail["phases"]) == {"train", "serve"}
     # counted on the CPU too, asserted only where a chip compiles it
-    assert {"pool_copies_q1", "pool_copies_q1_hd128_layer"} \
+    assert {"pool_copies_q1", "pool_copies_q1_hd128_layer",
+            "pool_copies_q1_key192_full_layer",
+            "pool_copies_q1_key192_window_layer"} \
         <= set(detail["phases"]["serve"])
 
 
@@ -98,6 +100,41 @@ def test_serve_layer_compiles_for_v5e_with_no_copy_of_a_page_pool():
         assert out[qw]["kernels"] >= 1, out
         assert out[qw]["pool_copies"] == 0, out
     assert out["1"]["temp_bytes"] < out["1"]["pool_bytes"] // 8, out
+
+
+_AOT_KEY192_LAYERS = _AOT_SERVE_LAYER.replace(
+    """for qw in (1, 128):
+    compiled, pool = chip_smoke._compile_serve_layer(
+        qw, sharding=one_chip, **chip_smoke._HD128_LAYER)
+""", """for kind, geometry, qw in (
+        ("full", chip_smoke._KEY192_FULL_LAYER, 1),
+        ("window", chip_smoke._KEY192_WINDOW_LAYER, 1),
+        ("window_q128", chip_smoke._KEY192_WINDOW_LAYER, 128),
+        ("unpadded", dict(chip_smoke._KEY192_WINDOW_LAYER, hd=192), 1)):
+    compiled, pool = chip_smoke._compile_serve_layer(
+        qw, sharding=one_chip, **geometry)
+    qw = kind
+""")
+
+
+def test_window_and_full_layers_of_wide_keys_compile_with_no_pool_copy():
+    """The third configuration's attention: a full layer (4 kv heads,
+    4097 pages) and a window layer (8 kv heads, a sink a head, a ring of
+    73 pages a lane), keys of 192 in pools padded to 256 and values of
+    128, compile for a v5e with no copy of a key pool; a key pool left
+    192 wide is re-laid twice, which is why ``generation._pool_width``
+    pads it."""
+    assert "_KEY192_FULL_LAYER" in _AOT_KEY192_LAYERS
+    proc = _run(["-c", _AOT_KEY192_LAYERS], env={"JAX_PLATFORMS": "cpu"})
+    lines = proc.stdout.strip().splitlines()
+    if lines and lines[-1].startswith("NO_TOPOLOGY"):
+        pytest.skip(f"no v5e topology can be described here: {lines[-1]}")
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+    out = json.loads(lines[-1].removeprefix("RESULT "))
+    for kind in ("full", "window", "window_q128"):
+        assert out[kind]["kernels"] == 1, out
+        assert out[kind]["pool_copies"] == 0, out
+    assert out["unpadded"]["pool_copies"] == 2, out
 
 
 _REPORT_CACHE_DIR = """
