@@ -386,18 +386,25 @@ def _ragged_parity(cfg, sz: dict, page_size: int) -> None:
                              f"ref: max err {worst:.2e} < 2e-2")
 
 
-def _engine_program_args(engine, qw: int):
+def _engine_program_args(engine, qw: int, sharding=None):
     """Abstract arguments of the engine's jitted ragged program at chunk
-    width ``qw`` (the shapes ``_run_step_traced`` feeds it)."""
+    width ``qw`` (the shapes ``_run_step_traced`` feeds it: the plan's
+    packed token rows), on the attached device or on the described one
+    that ``sharding`` names."""
     import jax
     import jax.numpy as jnp
+    from paddle_tpu.serving.scheduler import step_rows
     b = engine.max_batch
+    rows = step_rows(qw, b)
     ppseq = engine.scheduler.ppseq
-    sds = jax.ShapeDtypeStruct
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
     like = lambda tree: jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
-    return (like(engine._params), sds((b, qw), jnp.int64),
-            sds((b, qw), jnp.int32), like(engine._pools),
-            sds((b, qw), jnp.int32), sds((b, qw), jnp.int32),
+    return (like(engine._params), sds((rows,), jnp.int64),
+            sds((rows,), jnp.int32), like(engine._pools),
+            sds((rows,), jnp.int32), sds((rows,), jnp.int32),
             sds((b,), jnp.int32), sds((b,), jnp.int32),
             sds((b, ppseq), jnp.int32), sds((b,), jnp.float32),
             like(engine._key), sds((b,), jnp.float32))
@@ -469,6 +476,73 @@ def _compile_serve_layer(qw: int, b, nh, nkv, hd, pages, ps, ppseq,
         sds((b,), jnp.int32), sds((b, ppseq), jnp.int32),
         sds((nh,), jnp.float32)).compile()
     return compiled, pool
+
+
+# one layer of the batch cell's model (mistral-7b-8l's widths) behind a
+# small vocabulary, as the engine serves it: 8 lanes, 2049 pages of 16
+_MISTRAL_LAYER_MODEL = dict(vocab_size=2048, hidden_size=4096, num_layers=1,
+                            num_heads=32, num_kv_heads=8,
+                            intermediate_size=14336,
+                            max_position_embeddings=4096, rms_eps=1e-5,
+                            rope_theta=1e6)
+
+
+def _matmul_rows(text: str) -> list:
+    """The row counts (first dimension of the 2-D output) of a compiled
+    program's matrix products: ``convolution`` on a TPU, ``dot`` on the
+    CPU."""
+    import re
+    return sorted({int(m) for m in re.findall(
+        r"= \w+\[(\d+),\d+\]\S* (?:convolution|dot)\(", text)})
+
+
+def _compile_packed_step(qw: int, sharding=None, rehearse: bool = False):
+    """The engine's OWN program at chunk width ``qw`` for one layer at
+    the batch cell's widths (``serve_step_q<qw>``: the plan's packed
+    rows through ``step.packed``, pools donated), compiled for the
+    attached device or for the described one that ``sharding`` names.
+    Returns ``(compiled, key pool, rows)``."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.serving import ServingEngine
+    from paddle_tpu.serving.scheduler import step_rows
+    widths = dict(_MISTRAL_LAYER_MODEL)
+    pages = 2049
+    if rehearse:
+        widths.update(vocab_size=128, hidden_size=64, num_heads=4,
+                      num_kv_heads=2, intermediate_size=128,
+                      max_position_embeddings=256)
+        pages = 33
+    paddle.seed(0)
+    model = LlamaForCausalLM(LlamaConfig(**widths))
+    engine = ServingEngine(model, max_batch=8, page_size=16,
+                           num_pages=pages)
+    compiled = engine._program(qw).lower(
+        *_engine_program_args(engine, qw, sharding)).compile()
+    return compiled, engine._pools[0][0], step_rows(qw, engine.max_batch)
+
+
+def _check_packed_rows(rehearse: bool) -> dict:
+    """The widest prefill program of one batch-cell layer runs its
+    matrix products over the packed rows (``step_rows``: the chunk and a
+    token a lane), none over ``max_batch x q_width``, and copies no
+    pool.  The CPU rehearsal compiles a tiny layer at Q=64 to exercise
+    the code."""
+    qw = 64 if rehearse else 1024
+    compiled, pool, rows = _compile_packed_step(qw, rehearse=rehearse)
+    text = compiled.as_text()
+    products = _matmul_rows(text)
+    copies = _pool_copies(text, pool)
+    print(f"  serve_step_q{qw} of one batch-cell layer: q_width {qw}, rows "
+          f"{rows} (8 lanes x {qw} = {8 * qw} padded), matrix products "
+          f"over {products} rows, {copies} whole-pool copies", flush=True)
+    _check(rows in products and 8 * qw not in products,
+           f"the Q={qw} program multiplies {rows} packed rows and nothing "
+           f"at {8 * qw}")
+    if not rehearse:
+        _check(copies == 0, f"the Q={qw} program copies no page pool")
+    return {"packed_q_width": qw, "packed_rows": rows,
+            "packed_matmul_rows": products, "pool_copies_packed": copies}
 
 
 def _check_pool_copies(engine, rehearse: bool) -> dict:
@@ -630,6 +704,7 @@ def phase_serve(rehearse: bool) -> dict:
         _check_kernels(engine._program(1), _engine_program_args(engine, 1),
                        ("_ragged_kernel",), rehearse, "ragged step (Q=1)")
         pool_copies = _check_pool_copies(engine, rehearse)
+    pool_copies.update(_check_packed_rows(rehearse))
     report = {"phase": "serve", "device": dev, "preset": sz["preset"],
               "requests_per_wave": len(sz["prompts"]) + 2,
               "new_tokens": n_new,

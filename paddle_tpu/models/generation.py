@@ -545,18 +545,22 @@ class CacheDescription:
 
 # XLA's TPU scatter takes index rows as they come at ~73 ns a row and,
 # once they are sorted, at ~0.4 ms flat plus ~9 ns a row; by itself it
-# sorts only from 65,536 rows.  The step's write sorts from this many
-# (Q=128 at eight lanes and eight kv heads), where sorting starts to pay
-# (v5e, f32 rows of 128: PERF.md section 6, PR 26)
+# sorts only from 65,536 rows.  The step's write sorts from this many,
+# where sorting starts to pay (v5e, f32 rows of 128: PERF.md section 6,
+# PR 26).  An index row is a (token row, kv head): a packed Q=1024 step
+# of eight lanes has 1,032 x 8 = 8,256 at eight kv heads and sorts; at
+# four, or at Q=512, it has ~4,100 and pays ~0.3 ms a pool unsorted
+# where the sort would cost 0.4
 _SORT_ROWS_FROM = 8192
 
 
 def _scatter_pages(pages, vals, page_ids, slots):
     """Write one step's new k/v rows into the page pools.  ``pages
-    [nkv, P, ps, hd]``; ``vals [B, Q, nkv, hd]``; ``page_ids/slots
-    [B, Q]`` (padding slots target the engine's sink page, never read
-    back; several may name one row, and any of them wins).  A row whose
-    page or slot lies outside the pool is dropped.
+    [nkv, P, ps, hd]``; ``vals [rows, nkv, hd]``; ``page_ids/slots
+    [rows]``, one a token row of the step (any leading shape of that
+    many elements; padding rows target the engine's sink page, never
+    read back; several may name one row, and any of them wins).  A row
+    whose page or slot lies outside the pool is dropped.
 
     The pool is written as ``[nkv * P * ps, hd]``: one scattered row
     per (token, kv head), ``hd`` the only window axis.  That view is a
@@ -592,14 +596,93 @@ def _scatter_pages(pages, vals, page_ids, slots):
     return flat.reshape(pages.shape)
 
 
-def _last_valid_rows(h, q_lens):
-    """Gather each sequence's LAST valid query row from ``h [B, Q, H]``
-    (row ``q_lens[b] - 1``; padding slots clamp to row 0) — the lm-head
-    matmul then runs on [B, H] instead of every padded token."""
-    b, qw = h.shape[0], h.shape[1]
-    idx = jnp.clip(q_lens.astype(jnp.int32) - jnp.int32(1),
-                   jnp.int32(0), jnp.int32(qw - 1))
-    return h[jnp.arange(b, dtype=jnp.int32), idx]
+class _StepRows:
+    """Where a ragged step's token rows sit.  Every per-token operation
+    of a step (embedding, norms, projections, rotary, the k/v write,
+    the feed-forward) runs over ``n_rows`` flat rows, of which sequence
+    ``b`` owns rows ``offs[b] .. offs[b] + q_lens[b] - 1``; the rest
+    carry no token (token 0 at position 0, written to the sink page).
+    Only the attention kernel wants ``[B, Q]``: :meth:`to_lanes` gathers
+    q rows into its layout and :meth:`from_lanes` its output back.
+
+    ``offs`` is nondecreasing, and two layouts use it: the packed one,
+    ``offs = cumsum(q_lens) - q_lens`` in as many rows as the step's
+    program has, and the ``[B, Q]`` one, ``offs[b] = b * Q`` in ``B * Q``
+    rows."""
+
+    def __init__(self, n_rows: int, offs, q_lens, q_width: int):
+        i32 = jnp.int32
+        self.n, self.q_width = int(n_rows), int(q_width)
+        self.offs = offs.astype(i32)
+        self.q_lens = q_lens.astype(i32)
+        row = jnp.arange(self.n, dtype=i32)
+        # a row's sequence is the last one that starts at or before it
+        # (an empty sequence starts where the next one does and owns no
+        # row: the test of ``valid`` leaves it out)
+        self.lane = jnp.sum(row[:, None] >= self.offs[None, :], axis=1,
+                            dtype=i32) - i32(1)
+        self.at = row - self.offs[self.lane]       # index in its chunk
+        self.valid = self.at < self.q_lens[self.lane]
+
+    def to_lanes(self, x):
+        """``x [rows, ...]`` -> ``[B, Q, ...]``; a slot past ``q_lens``
+        holds some other row, which the kernel never reads into a valid
+        result."""
+        idx = jnp.minimum(
+            self.offs[:, None]
+            + jnp.arange(self.q_width, dtype=jnp.int32)[None, :],
+            jnp.int32(self.n - 1))
+        return x[idx]
+
+    def from_lanes(self, y):
+        """``y [B, Q, ...]`` -> ``[rows, ...]`` (rows that carry no token
+        take a finite row of ``y``)."""
+        return y[self.lane, jnp.minimum(self.at,
+                                        jnp.int32(self.q_width - 1))]
+
+    def of_lanes(self, per_lane):
+        """``per_lane [B, ...]`` -> ``[rows, ...]``: each row its
+        sequence's entry."""
+        return per_lane[self.lane]
+
+    def last_rows(self, h):
+        """Each sequence's LAST valid row of ``h [rows, H]`` (an empty
+        sequence clamps to a row inside ``h``) — the lm-head matmul
+        then runs on [B, H] instead of every token."""
+        idx = jnp.clip(self.offs + self.q_lens - jnp.int32(1),
+                       jnp.int32(0), jnp.int32(self.n - 1))
+        return h[idx]
+
+
+def _two_ways_in(rows_body):
+    """The step object of one family's ``rows_body(p, tok [rows],
+    pos [rows], pools, page_ids [rows], slots [rows], kv_lens, q_lens,
+    tables, rows: _StepRows)``: the documented ``[B, Q]`` call, which
+    runs the body at ``B * Q`` rows with sequence ``b`` starting at row
+    ``b * Q``, and ``step.packed(..., q_width)``, the serving engine's,
+    whose ``tok/pos/page_ids/slots`` are already flat rows with sequence
+    ``b`` behind sequence ``b - 1`` (``offs = cumsum(q_lens) -
+    q_lens``), as ``Scheduler.plan_step`` lays them out, and whose
+    attention width ``q_width`` is static."""
+
+    def step(p, tok, pos, pools, page_ids, slots, kv_lens, q_lens, tables):
+        b, qw = tok.shape
+        offs = jnp.arange(b, dtype=jnp.int32) * jnp.int32(qw)
+        return rows_body(p, tok.reshape(-1), pos.reshape(-1), pools,
+                         page_ids.reshape(-1), slots.reshape(-1), kv_lens,
+                         q_lens, tables,
+                         _StepRows(b * qw, offs, q_lens, qw))
+
+    def packed(p, tok, pos, pools, page_ids, slots, kv_lens, q_lens,
+               tables, q_width: int):
+        ql = q_lens.astype(jnp.int32)
+        offs = jnp.cumsum(ql, dtype=jnp.int32) - ql
+        return rows_body(p, tok, pos, pools, page_ids, slots, kv_lens,
+                         q_lens, tables,
+                         _StepRows(tok.shape[0], offs, q_lens, q_width))
+
+    step.packed = packed
+    return step
 
 
 def build_ragged_decode_step(model):
@@ -620,7 +703,17 @@ def build_ragged_decode_step(model):
     chunk or one decode token, padded to the batch-wide ``Q``); their
     k/v land at ``(page_ids, slots)`` BEFORE the one-launch ragged
     paged attention, so the new tokens attend to themselves causally —
-    the same order as ``attend_cache_append``.  Numerics mirror the
+    the same order as ``attend_cache_append``.
+
+    Inside, a step's tokens are flat rows (:class:`_StepRows`): the
+    matmuls, norms, rotary and k/v write run over the rows, and only
+    the attention kernel sees ``[B, Q]``.  The call above runs the body
+    at ``B * Q`` rows; the serving engine calls the same body through
+    ``step.packed(params, tok [rows], pos [rows], pools, page_ids
+    [rows], slots [rows], kv_lens, q_lens, tables, q_width)`` with the
+    sequences' tokens packed one behind the other, so that a step with
+    one wide chunk beside decoding lanes multiplies about as many rows
+    as it feeds tokens (:func:`_two_ways_in`).  Numerics mirror the
     model's ``build_decode_step`` body exactly (same norm references,
     fp32 attention statistics), so engine output is token-for-token
     the eager ``generate`` output.
@@ -647,41 +740,40 @@ def build_ragged_decode_step(model):
     tied = bool(c.tie_word_embeddings)
 
     if "blocks" in params:                              # GPT family
-        def step(p, tok, pos, pools, page_ids, slots, kv_lens, q_lens,
-                 tables):
-            b, qw = tok.shape
+        def rows_body(p, tok, pos, pools, page_ids, slots, kv_lens,
+                      q_lens, tables, rows):
             x = jnp.take(p["wte"], tok, axis=0) \
-                + jnp.take(p["wpe"], pos, axis=0)        # [B, Q, H]
+                + jnp.take(p["wpe"], pos, axis=0)        # [rows, H]
             new_pools = []
             for i, bp in enumerate(p["blocks"]):
                 h = _fd.reference_layer_norm(x, bp["ln1_w"],
                                              bp["ln1_b"], 1e-5)
-                h2 = h.reshape(b * qw, hidden)
-                qp = (jnp.matmul(h2, bp["wq"]) + bp["bq"]) \
-                    .reshape(b, qw, nh, hd)
-                kp = (jnp.matmul(h2, bp["wk"]) + bp["bk"]) \
-                    .reshape(b, qw, nh, hd)
-                vp = (jnp.matmul(h2, bp["wv"]) + bp["bv"]) \
-                    .reshape(b, qw, nh, hd)
+                qp = (jnp.matmul(h, bp["wq"]) + bp["bq"]) \
+                    .reshape(-1, nh, hd)
+                kp = (jnp.matmul(h, bp["wk"]) + bp["bk"]) \
+                    .reshape(-1, nh, hd)
+                vp = (jnp.matmul(h, bp["wv"]) + bp["bv"]) \
+                    .reshape(-1, nh, hd)
                 kpg = _scatter_pages(pools[i][0], kp, page_ids, slots)
                 vpg = _scatter_pages(pools[i][1], vp, page_ids, slots)
                 new_pools.append((kpg, vpg))
-                ctx = ragged_paged_attention(qp, kpg, vpg, kv_lens,
-                                             q_lens, tables)
-                x = x + (jnp.matmul(ctx.reshape(b, qw, hidden),
-                                    bp["wo"]) + bp["bo"])
+                ctx = rows.from_lanes(ragged_paged_attention(
+                    rows.to_lanes(qp), kpg, vpg, kv_lens, q_lens, tables))
+                x = x + (jnp.matmul(ctx.reshape(-1, hidden), bp["wo"])
+                         + bp["bo"])
                 x = x + _fd.norm_mlp(
-                    x.reshape(b * qw, hidden), kind="layer_norm",
+                    x, kind="layer_norm",
                     norm_w=bp["ln2_w"], norm_b=bp["ln2_b"],
                     w1=bp["w1"], b1=bp["b1"], w2=bp["w2"], b2=bp["b2"],
-                    eps=1e-5, act="gelu_tanh").reshape(b, qw, hidden)
+                    eps=1e-5, act="gelu_tanh")
             h = _fd.reference_layer_norm(x, p["lnf_w"], p["lnf_b"],
                                          1e-5)
             w = p["wte"] if tied else p["lm_w"]
-            logits = jnp.matmul(_last_valid_rows(h, q_lens),
+            logits = jnp.matmul(rows.last_rows(h),
                                 jnp.swapaxes(w, -1, -2))
             return logits, tuple(new_pools)
 
+        step = _two_ways_in(rows_body)
         step.cache = CacheDescription(
             [(nh, hd, hd, None)] * len(params["blocks"]))
         return params, step
@@ -692,21 +784,19 @@ def build_ragged_decode_step(model):
         act = c.hidden_act
         scale = float(c.embed_scale)
 
-        def step(p, tok, pos, pools, page_ids, slots, kv_lens, q_lens,
-                 tables):
-            b, qw = tok.shape
-            x = jnp.take(p["embed"], tok, axis=0)
+        def rows_body(p, tok, pos, pools, page_ids, slots, kv_lens,
+                      q_lens, tables, rows):
+            x = jnp.take(p["embed"], tok, axis=0)        # [rows, H]
             if scale != 1.0:
                 x = x * scale
-            cos = jnp.take(p["cos"], pos, axis=0)[:, :, None, :]
-            sin = jnp.take(p["sin"], pos, axis=0)[:, :, None, :]
+            cos = jnp.take(p["cos"], pos, axis=0)[:, None, :]
+            sin = jnp.take(p["sin"], pos, axis=0)[:, None, :]
             new_pools = []
             for i, lp in enumerate(p["layers"]):
                 h = _fd.reference_rms_norm(x, lp["ln1_w"], eps)
-                h2 = h.reshape(b * qw, hidden)
-                qp = jnp.matmul(h2, lp["wq"]).reshape(b, qw, nh, hd)
-                kp = jnp.matmul(h2, lp["wk"]).reshape(b, qw, nkv, hd)
-                vp = jnp.matmul(h2, lp["wv"]).reshape(b, qw, nkv, hd)
+                qp = jnp.matmul(h, lp["wq"]).reshape(-1, nh, hd)
+                kp = jnp.matmul(h, lp["wk"]).reshape(-1, nkv, hd)
+                vp = jnp.matmul(h, lp["wv"]).reshape(-1, nkv, hd)
                 if lp["bq"] is not None:
                     qp = qp + lp["bq"].reshape(nh, hd)
                 if lp["bk"] is not None:
@@ -718,21 +808,20 @@ def build_ragged_decode_step(model):
                 kpg = _scatter_pages(pools[i][0], kp, page_ids, slots)
                 vpg = _scatter_pages(pools[i][1], vp, page_ids, slots)
                 new_pools.append((kpg, vpg))
-                ctx = ragged_paged_attention(qp, kpg, vpg, kv_lens,
-                                             q_lens, tables)
-                x = x + jnp.matmul(ctx.reshape(b, qw, nh * hd),
-                                   lp["wo"])
+                ctx = rows.from_lanes(ragged_paged_attention(
+                    rows.to_lanes(qp), kpg, vpg, kv_lens, q_lens, tables))
+                x = x + jnp.matmul(ctx.reshape(-1, nh * hd), lp["wo"])
                 x = x + _fd.norm_mlp(
-                    x.reshape(b * qw, hidden), kind="rms_norm",
+                    x, kind="rms_norm",
                     norm_w=lp["ln2_w"], w_gate=lp["wg"], w1=lp["wu"],
-                    w2=lp["wd"], eps=eps,
-                    act=act).reshape(b, qw, hidden)
+                    w2=lp["wd"], eps=eps, act=act)
             h = _fd.reference_rms_norm(x, p["norm_w"], eps)
             w = p["embed"] if tied else p["lm_w"]
-            logits = jnp.matmul(_last_valid_rows(h, q_lens),
+            logits = jnp.matmul(rows.last_rows(h),
                                 jnp.swapaxes(w, -1, -2))
             return logits, tuple(new_pools)
 
+        step = _two_ways_in(rows_body)
         step.cache = CacheDescription(
             [(nkv, hd, hd, None)] * len(params["layers"]))
         return params, step
@@ -751,7 +840,8 @@ def _build_described_step(model):
     own base, a sink or none; feed-forward dense or routed experts of
     which this chip holds some.
 
-    ``step`` takes the arguments of every ragged step.  ``pools`` is
+    ``step`` and ``step.packed`` take the arguments of every ragged
+    step.  ``pools`` is
     what ``step.cache`` describes.  Window layers write and read a ring
     (``CacheDescription``): their page ids and slots are derived here
     from ``pos`` and the ring page ids behind the full-layer pages in
@@ -782,11 +872,9 @@ def _build_described_step(model):
     i32 = jnp.int32
 
     def body(p, tok, pos, pools, page_ids, slots, kv_lens, q_lens,
-             tables):
-        b, qw = tok.shape
-        x = jnp.take(p["embed"], tok, axis=0)             # [B, Q, H]
-        valid = jnp.arange(qw, dtype=i32)[None, :] \
-            < q_lens.astype(i32)[:, None]                 # [B, Q]
+             tables, rows):
+        x = jnp.take(p["embed"], tok, axis=0)             # [rows, H]
+        valid = rows.valid
         pos = pos.astype(i32)
         full_tables = tables
         if window_layer is not None:
@@ -795,23 +883,24 @@ def _build_described_step(model):
             full_tables, ring = cache.split_tables(tables, wpool.shape[1])
             entry = (pos // i32(ps)) % i32(ring.shape[1])
             ring_ids = jnp.where(
-                valid, jnp.take_along_axis(ring.astype(i32), entry, axis=1),
+                valid, jnp.take_along_axis(
+                    rows.of_lanes(ring.astype(i32)), entry[:, None],
+                    axis=1)[:, 0],
                 i32(wpool.shape[1] - 1))                  # padding: sink
             ring_slots = jnp.where(valid, pos % i32(ps), i32(0))
-        rope = {theta: (jnp.take(cos, pos, axis=0)[:, :, None, :],
-                        jnp.take(sin, pos, axis=0)[:, :, None, :])
+        rope = {theta: (jnp.take(cos, pos, axis=0)[:, None, :],
+                        jnp.take(sin, pos, axis=0)[:, None, :])
                 for theta, (cos, sin) in p["rope"].items()}
         counts = [i32(0), i32(0), i32(0)]
         new_pools = []
         for i, (d, lp) in enumerate(zip(descs, p["layers"])):
             att, ff = d.attention, d.feed_forward
             nkv, dk, dv = att.kv_heads, att.key_dim, att.value_dim
-            h2 = _fd.reference_rms_norm(x, lp["ln1_w"], eps) \
-                .reshape(b * qw, hidden)
-            qkv = jnp.matmul(h2, lp["wqkv"])
-            qp = qkv[:, :nh * dk].reshape(b, qw, nh, dk)
-            kp = qkv[:, nh * dk:(nh + nkv) * dk].reshape(b, qw, nkv, dk)
-            vp = qkv[:, (nh + nkv) * dk:].reshape(b, qw, nkv, dv)
+            qkv = jnp.matmul(_fd.reference_rms_norm(x, lp["ln1_w"], eps),
+                             lp["wqkv"])
+            qp = qkv[:, :nh * dk].reshape(-1, nh, dk)
+            kp = qkv[:, nh * dk:(nh + nkv) * dk].reshape(-1, nkv, dk)
+            vp = qkv[:, (nh + nkv) * dk:].reshape(-1, nkv, dv)
             if att.value_scale != 1.0:
                 vp = vp * att.value_scale
             rot = att.rotary_dim
@@ -837,38 +926,36 @@ def _build_described_step(model):
             vpg = _scatter_pages(pools[i][1], vp, ids, sl)
             new_pools.append((kpg, vpg))
             # Mosaic takes no "high": the kernel's two dots are float32
-            ctx = ragged_paged_attention(
-                qp, kpg, vpg, kv_lens, q_lens, tb,
+            ctx = rows.from_lanes(ragged_paged_attention(
+                rows.to_lanes(qp), kpg, vpg, kv_lens, q_lens, tb,
                 scale=1.0 / math.sqrt(dk), window=att.window,
                 sinks=lp["sink"] if att.sink else None,
-                precision=jax.lax.Precision.HIGHEST)
-            x = x + jnp.matmul(ctx[..., :dv].reshape(b, qw, nh * dv),
+                precision=jax.lax.Precision.HIGHEST))
+            x = x + jnp.matmul(ctx[..., :dv].reshape(-1, nh * dv),
                                lp["wo"])
-            x2 = x.reshape(b * qw, hidden)
             if ff.held is None:
-                y = _fd.norm_mlp(x2, kind="rms_norm", norm_w=lp["ln2_w"],
+                y = _fd.norm_mlp(x, kind="rms_norm", norm_w=lp["ln2_w"],
                                  w_gate=lp["wg"], w1=lp["wu"], w2=lp["wd"],
                                  eps=eps, act="silu")
             else:
-                h2 = _fd.reference_rms_norm(x2, lp["ln2_w"], eps)
+                h2 = _fd.reference_rms_norm(x, lp["ln2_w"], eps)
                 picks, weights = sigmoid_topk_route(
                     h2, lp["router_w"], lp["router_b"], ff.top_k)
-                y, rows = held_experts_swiglu(
-                    h2, picks, weights, valid.reshape(b * qw), lp["wg"],
-                    lp["wu"], lp["wd"], ff.held[0])
-                counts = [counts[0] + jnp.sum(rows, dtype=i32),
-                          jnp.maximum(counts[1], jnp.max(rows)),
-                          counts[2] + jnp.sum(rows > 0, dtype=i32)]
-            x = x + y.reshape(b, qw, hidden)
+                y, n_rows = held_experts_swiglu(
+                    h2, picks, weights, valid, lp["wg"], lp["wu"],
+                    lp["wd"], ff.held[0])
+                counts = [counts[0] + jnp.sum(n_rows, dtype=i32),
+                          jnp.maximum(counts[1], jnp.max(n_rows)),
+                          counts[2] + jnp.sum(n_rows > 0, dtype=i32)]
+            x = x + y
         h = _fd.reference_rms_norm(x, p["norm_w"], eps)
-        logits = jnp.matmul(_last_valid_rows(h, q_lens),
+        logits = jnp.matmul(rows.last_rows(h),
                             jnp.swapaxes(p["lm_w"], -1, -2))
         if has_experts:
             return logits, tuple(new_pools), jnp.stack(counts)
         return logits, tuple(new_pools)
 
-    def step(p, tok, pos, pools, page_ids, slots, kv_lens, q_lens,
-             tables):
+    def rows_body(*args):
         # float32 served as float32: at jax's default a float32 product
         # is ONE bf16 pass on the MXU, which this model's logits check
         # could not tell from serving in bfloat16 (the program read
@@ -878,9 +965,9 @@ def _build_described_step(model):
         # attention kernel told "highest", it reads 6e-5: PERF.md
         # section 6, PR 27)
         with jax.default_matmul_precision(_DESCRIBED_PRECISION):
-            return body(p, tok, pos, pools, page_ids, slots, kv_lens,
-                        q_lens, tables)
+            return body(*args)
 
+    step = _two_ways_in(rows_body)
     step.cache = cache
     step.routing_counts = has_experts
     return params, step

@@ -116,7 +116,8 @@ EVENT_SCHEMA: Dict[str, Dict[str, str]] = {
     # lengths; all five read 0 for a model with no experts or windows
     "batch_step": {"batch": "int", "prefill_seqs": "int",
                    "decode_seqs": "int", "q_width": "int",
-                   "tokens": "int", "queue_depth": "int",
+                   "tokens": "int", "rows": "int",
+                   "prefill_waiting": "int", "queue_depth": "int",
                    "step_s": "float", "page_occupancy": "float",
                    "cold_start": "bool", "fused_steps": "int",
                    "exit_reason": "str",

@@ -27,7 +27,11 @@ Programs are cached per query-chunk width ``Q`` (bucketed to powers of
 two), so steady-state decode (``Q == 1``) is exactly one compiled
 program regardless of batch composition, and the page pools ride as
 DONATED jit arguments — XLA reuses their buffers in place across
-iterations on accelerator backends.
+iterations on accelerator backends.  A step's tokens reach its program
+as packed rows (``Scheduler.plan_step`` lays them out, ``step.packed``
+of ``build_ragged_decode_step`` takes them): the program of width ``Q``
+runs ``scheduler.step_rows(Q, max_batch)`` rows — ``Q`` and the lanes,
+not ``max_batch * Q`` — and only its attention kernel sees ``[B, Q]``.
 
 Observability: ``serving_admit`` / ``batch_step`` / ``evict`` events
 (see docs/observability_events.md), queue-depth + batch-occupancy
@@ -160,15 +164,6 @@ _HEALTH_RANK = {"ok": 0, "degraded": 1, "quarantining": 2, "failed": 3}
 _COLD_DISPATCH_GRACE_S = 120.0
 
 _ENGINE_SEQ = itertools.count(1)
-
-
-def _bucket(n: int) -> int:
-    """Next power of two >= n — bounds program-compile count to
-    log2(max prompt length) buckets."""
-    b = 1
-    while b < n:
-        b <<= 1
-    return b
 
 
 def _mark_op_stream(name: str, n: int) -> None:
@@ -697,7 +692,7 @@ class ServingEngine:
                 # machinery — byte for byte
                 fused_w, fused_max, fused_reason = 1, 0, "single_step"
                 if plan is not None and plan.n_prefill == 0 \
-                        and plan.tok.shape[1] == 1 and self._fusable \
+                        and plan.q_width == 1 and self._fusable \
                         and not self.scheduler.bisect_groups:
                     # (a bisection episode pins the single-step path:
                     # probe batches must fail one iteration at a time)
@@ -793,18 +788,12 @@ class ServingEngine:
         # into the fresh epoch's pools (self._pools by then)
         pools_in, key_in = self._pools, self._key  # noqa: PTL902 — THE zombie-containment snapshot: lock-free on purpose, see comment above
         nan_lane = self._maybe_poison(plan)
-        qw = _bucket(plan.tok.shape[1])
+        qw = plan.q_width
         n_progs = len(self._programs)
         prog = self._program(qw)
         cold_start = len(self._programs) > n_progs
         if cold_start:
             self._dispatch_cold = True   # noqa: PTL902 — GIL-atomic bool, sole loop-thread writer; the watchdog tolerates one stale poll of the compile-grace flag
-        pad = qw - plan.tok.shape[1]
-        tok = np.pad(plan.tok, ((0, 0), (0, pad)))
-        pos = np.pad(plan.pos, ((0, 0), (0, pad)))
-        page_ids = np.pad(plan.page_ids, ((0, 0), (0, pad)),
-                          constant_values=self.pool.sink)  # noqa: PTL902 — epoch-snapshot pool handle; sink is immutable per pool
-        slots = np.pad(plan.slots, ((0, 0), (0, pad)))
         # chaos NaN injection rides a logits bias vector: 0 everywhere
         # (jit-compiled no-op add) except the poisoned lane
         poison = np.zeros((self.max_batch,), "float32")
@@ -813,9 +802,9 @@ class ServingEngine:
         phases.switch(_DISPATCH)
         with self._h_step.time() as step_timer:
             nxt, pools, rng = prog(
-                self._params, tok, pos, pools_in, page_ids, slots,
-                plan.kv_lens, plan.q_lens, plan.tables, plan.temps,
-                key_in, poison)
+                self._params, plan.tok, plan.pos, pools_in, plan.page_ids,
+                plan.slots, plan.kv_lens, plan.q_lens, plan.tables,
+                plan.temps, key_in, poison)
             phases.switch(_HOST_READ)
             # THE boundary sync: exactly one device read per window
             # (this path is the degenerate one-iteration window) —
@@ -925,7 +914,8 @@ class ServingEngine:
         _events.emit("batch_step", batch=len(plan.seqs),
                      prefill_seqs=prefill_seqs,
                      decode_seqs=plan.n_decode, q_width=q_width,
-                     tokens=tokens,
+                     tokens=tokens, rows=plan.rows * fused_steps,
+                     prefill_waiting=plan.prefill_waiting,
                      queue_depth=self.scheduler.queue_depth(),
                      step_s=round(step_s, 6),
                      cold_start=cold_start or None,
@@ -989,7 +979,8 @@ class ServingEngine:
         # post-step kv_lens — the compiled loop owns the append cursor
         kv0 = (plan.kv_lens - plan.q_lens).astype("int32")
         live = plan.q_lens > 0
-        tok0 = plan.tok[:, 0].astype("int32")
+        # a decode-only plan has one row a lane, the live lanes first
+        tok0 = plan.tok.astype("int32")
         eos_ids = np.full((b,), -1, "int32")     # -1 never samples
         budgets = np.full((b,), 2 ** 30, "int32")
         for i, seq in enumerate(plan.seqs):
@@ -1296,8 +1287,10 @@ class ServingEngine:
 
         def program(params, tok, pos, pools, page_ids, slots, kv_lens,
                     q_lens, tables, temps, rng, poison):
-            out = step(params, tok, pos, pools, page_ids, slots, kv_lens,
-                       q_lens, tables)
+            # tok, pos, page_ids, slots: [step_rows(qw, max_batch)], the
+            # plan's packed rows
+            out = step.packed(params, tok, pos, pools, page_ids, slots,
+                              kv_lens, q_lens, tables, qw)
             logits, pools = out[0], out[1]
             # chaos bias (zeros in production — a no-op add) lets the
             # fault injector NaN one lane's logits without a host hook
@@ -1369,7 +1362,10 @@ class ServingEngine:
                "prestaged_plans": self.scheduler.prestaged_plans,
                "prestage_commits": self.scheduler.prestage_commits,
                "prestage_discards": self.scheduler.prestage_discards,
-               "free_pages": self.pool.available(),
+               "step_rows": self.scheduler.rows_planned,
+               "step_rows_empty": self.scheduler.rows_empty,
+               "prefill_waits": self.scheduler.prefill_waits,
+               "free_pages": self.pool.available(),  # noqa: PTL902 — advisory snapshot; the handle swaps atomically at relaunch
                "programs": len(self._programs),
                "health": self.health,
                "quarantined": self._n_quarantined,  # noqa: PTL902 — stats() is an advisory lock-free snapshot; counters are GIL-atomic ints

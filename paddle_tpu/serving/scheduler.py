@@ -35,7 +35,40 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-__all__ = ["PagePool", "Request", "Scheduler", "StepPlan"]
+__all__ = ["PagePool", "Request", "Scheduler", "StepPlan", "step_rows"]
+
+
+def _bucket(n: int) -> int:
+    """Next power of two >= n — bounds program-compile count to
+    log2(max prompt length) buckets."""
+    b = 1
+    while b < n:
+        b <<= 1
+    return b
+
+
+# Up to about this many rows a step's time is the read of the weights
+# and rows cost nothing (float32 weights on a v5e: 819 GB/s against one
+# bf16 pass at 197 TFLOP/s is ~480 rows, at "high" ~160).  With fewer, a
+# narrow program would have no room for a second short chunk, and a
+# burst of short prompts would prefill one a step at a decode step's
+# price each
+_MIN_PREFILL_ROWS = 128
+
+
+def step_rows(q_width: int, max_batch: int) -> int:
+    """Token rows of the step program of attention width ``q_width`` (a
+    power of two, :func:`_bucket` of the step's widest chunk): a
+    function of the program's key alone, so that a request sent alone
+    compiles exactly the program a mixed step of the same width runs.
+    A decode-only step is one row a lane.  A wider one has the smallest
+    multiple of 8 rows that holds one chunk of ``q_width`` beside
+    ``max_batch - 1`` single tokens, and never fewer than
+    ``_MIN_PREFILL_ROWS``."""
+    if q_width <= 1:
+        return int(max_batch)
+    return max(-(-(int(q_width) + int(max_batch) - 1) // 8) * 8,
+               _MIN_PREFILL_ROWS)
 
 
 class PagePool:
@@ -251,13 +284,19 @@ class _Sequence:
 
 
 class StepPlan:
-    """One ragged iteration, planned: the active sequences and the
-    padded host arrays the engine feeds the jitted step."""
+    """One ragged iteration, planned: the active sequences and the host
+    arrays the engine feeds the jitted step.  ``tok``, ``pos``,
+    ``page_ids`` and ``slots`` are ``[rows]``: sequence 0's
+    ``q_lens[0]`` tokens, then sequence 1's, ..., then rows that carry
+    no token (token 0, the sink page).  ``q_width`` is the attention
+    width of the step's program, the power-of-two bucket of the widest
+    chunk, and ``rows = step_rows(q_width, max_batch)``.
+    ``prefill_waiting`` counts the chunks the row budget held back."""
 
     __slots__ = ("seqs", "slots_map", "tok", "pos", "page_ids", "slots",
-                 "kv_lens", "q_lens", "tables", "temps",
+                 "kv_lens", "q_lens", "tables", "temps", "q_width", "rows",
                  "n_prefill", "n_decode", "fed_prefill", "fed_decode",
-                 "bisect_group")
+                 "prefill_waiting", "bisect_group")
 
     def __init__(self, **kw):
         for k, v in kw.items():
@@ -339,6 +378,12 @@ class Scheduler:
         self.waiting: deque = deque()
         self.running: List[_Sequence] = []
         self.evictions = 0
+        # the packed layout's own account: rows the planned steps'
+        # programs run, rows among them that carried no token, and
+        # chunks the row budget made wait a step
+        self.rows_planned = 0
+        self.rows_empty = 0
+        self.prefill_waits = 0
         # quarantine bisection (engine fault containment): while
         # non-empty, plan_step restricts each plan to the front
         # group's members (by request id) and pauses admission — the
@@ -607,9 +652,20 @@ class Scheduler:
     # -- the per-iteration plan ------------------------------------------
     def plan_step(self):
         """Admit what fits, grow pages for this iteration's tokens
-        (evicting under pressure), and lay out the padded step arrays.
-        Returns (plan, admitted, evicted) — plan is None when nothing
-        is runnable."""
+        (evicting under pressure), and lay the step's tokens out as
+        packed rows.  Returns (plan, admitted, evicted) — plan is None
+        when nothing is runnable.
+
+        **The row budget.**  A step's program is keyed by the bucket of
+        its widest chunk and runs ``step_rows`` of that bucket.  Lanes
+        that feed one token always go.  Wider chunks are taken in
+        running order: the first always goes; a later one joins only if
+        the step's tokens, with it, still fit the rows of the bucket of
+        the widest chunk taken, and otherwise waits WHOLE for the next
+        step — its lane is held and feeds nothing.  A chunk is never
+        cut to fit: a remainder would have a width that no earlier step
+        of the deployment compiled.  The first wide chunk in running
+        order is the oldest one, so no sequence waits for ever."""
         pre, self._prestage = self._prestage, None
         self._staged_pred = None
         if pre is not None:
@@ -638,17 +694,25 @@ class Scheduler:
 
         # per-sequence chunk of NEW tokens this iteration
         active: List[Tuple[_Sequence, List[int]]] = []
-        for seq in list(self.running):
+        runnable = [seq for seq in self.running
+                    if group is None or seq.req.id in group]
+        # (parked while the bisection probes otherwise)
+        singles = sum(1 for seq in runnable if self._chunk_len(seq) == 1)
+        widest = wide_tokens = waiting = 0
+        for seq in runnable:
             if seq not in self.running:
                 continue       # evicted by an earlier seq's growth
-            if group is not None and seq.req.id not in group:
-                continue       # parked while the bisection probes
             chunk = seq.tokens[seq.kv_len:]
             if self.max_prefill_chunk and \
                     len(chunk) > self.max_prefill_chunk:
                 chunk = chunk[:self.max_prefill_chunk]
             if not chunk:
                 continue
+            if widest and len(chunk) > 1 and \
+                    wide_tokens + len(chunk) + singles > step_rows(
+                        _bucket(max(widest, len(chunk))), self.max_batch):
+                waiting += 1
+                continue       # over the row budget: next step, whole
             while not self._grow(seq, seq.kv_len + len(chunk)):
                 victim = self._evict_victim(
                     {seq} | {s for s, _ in active})
@@ -669,18 +733,21 @@ class Scheduler:
                     evicted.append(seq)
                 continue
             active.append((seq, chunk))
+            if len(chunk) > 1:
+                widest = max(widest, len(chunk))
+                wide_tokens += len(chunk)
 
         if not active:
             return None, admitted, evicted
 
         b = self.max_batch
-        qw = max(len(chunk) for _, chunk in active)
+        qw = _bucket(max(len(chunk) for _, chunk in active))
+        n_rows = step_rows(qw, b)
         ps = self.pool.page_size
-        sink = self.pool.sink
-        tok = np.zeros((b, qw), "int64")
-        pos = np.zeros((b, qw), "int32")
-        page_ids = np.full((b, qw), sink, "int32")
-        slots = np.zeros((b, qw), "int32")
+        tok = np.zeros((n_rows,), "int64")
+        pos = np.zeros((n_rows,), "int32")
+        page_ids = np.full((n_rows,), self.pool.sink, "int32")
+        slots = np.zeros((n_rows,), "int32")
         kv_lens = np.zeros((b,), "int32")
         q_lens = np.zeros((b,), "int32")
         tables = np.zeros((b, self.ppseq), "int32")
@@ -688,18 +755,24 @@ class Scheduler:
         temps = np.zeros((b,), "float32")
         n_prefill = n_decode = 0
         fed_prefill = fed_decode = 0
+        row = 0
         for i, (seq, chunk) in enumerate(active):
             n = len(chunk)
             start = seq.kv_len
-            tok[i, :n] = chunk
-            pos[i, :n] = np.arange(start, start + n, dtype="int32")
-            for j in range(n):
-                p = start + j
-                page_ids[i, j] = seq.pages[p // ps]
-                slots[i, j] = p % ps
+            tables[i, :len(seq.pages)] = seq.pages
+            if n == 1:                  # a decoding lane: no array work
+                tok[row], pos[row] = chunk[0], start
+                page_ids[row] = seq.pages[start // ps]
+                slots[row] = start % ps
+            else:
+                p = np.arange(start, start + n, dtype="int32")
+                tok[row:row + n] = chunk
+                pos[row:row + n] = p
+                page_ids[row:row + n] = tables[i, p // ps]
+                slots[row:row + n] = p % ps
+            row += n
             kv_lens[i] = start + n
             q_lens[i] = n
-            tables[i, :len(seq.pages)] = seq.pages
             if self.ring_pages:
                 rings[i] = seq.ring
             temps[i] = seq.req.temperature
@@ -711,15 +784,19 @@ class Scheduler:
                 fed_decode += n
         if self.ring_pages:
             tables = self._with_rings(tables, rings, self.ring_pages)
+        self.rows_planned += n_rows
+        self.rows_empty += n_rows - row
+        self.prefill_waits += waiting
         plan = StepPlan(seqs=[s for s, _ in active],
                         slots_map={s.req.id: i
                                    for i, (s, _) in enumerate(active)},
                         tok=tok, pos=pos, page_ids=page_ids,
                         slots=slots, kv_lens=kv_lens, q_lens=q_lens,
-                        tables=tables, temps=temps,
+                        tables=tables, temps=temps, q_width=qw,
+                        rows=n_rows,
                         n_prefill=n_prefill, n_decode=n_decode,
                         fed_prefill=fed_prefill, fed_decode=fed_decode,
-                        bisect_group=group)
+                        prefill_waiting=waiting, bisect_group=group)
         return plan, admitted, evicted
 
     def commit(self, plan: StepPlan) -> None:
@@ -772,6 +849,9 @@ class Scheduler:
         FIRST finish, so lanes never diverge mid-window).  Pages the
         clamped window reserved but never wrote are returned to the
         pool."""
+        # plan_step counted the window's first iteration
+        self.rows_planned += (int(steps) - 1) * plan.rows
+        self.rows_empty += (int(steps) - 1) * (plan.rows - len(plan.seqs))
         for seq in plan.seqs:
             if seq.req.done:
                 continue

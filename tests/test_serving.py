@@ -281,16 +281,25 @@ def test_scheduler_admission_completion_and_plan_layout():
     plan, admitted, evicted = sched.plan_step()
     # iteration-level admission: only max_batch sequences run; r3 waits
     assert len(admitted) == 2 and not evicted
-    assert plan.tok.shape == (2, 5)        # widest prompt pads the step
+    # packed rows: r1's five tokens, r2's two behind them, in the rows
+    # of the program of the widest chunk's bucket (8 wide: 8 + 2 lanes
+    # - 1 rounded up to 16, which the floor of 128 passes), not in two
+    # lanes of five
+    assert plan.q_width == 8 and plan.rows == 128
+    assert plan.tok.shape == plan.pos.shape == (128,)
+    assert plan.tok[:7].tolist() == [1, 2, 3, 4, 5, 7, 8]
+    assert plan.pos[:7].tolist() == [0, 1, 2, 3, 4, 0, 1]
     assert plan.q_lens.tolist()[:2] == [5, 2]
     assert plan.kv_lens.tolist()[:2] == [5, 2]
     # page/slot layout: token t of seq 0 -> page[t//4], slot t%4
-    s0 = plan.seqs[0]
-    assert plan.page_ids[0, :5].tolist() == [s0.pages[0]] * 4 \
-        + [s0.pages[1]]
-    assert plan.slots[0, :5].tolist() == [0, 1, 2, 3, 0]
-    # padding of the short row scatters into the sink page
-    assert plan.page_ids[1, 2:].tolist() == [pool.sink] * 3
+    s0, s1 = plan.seqs
+    assert plan.page_ids[:7].tolist() == [s0.pages[0]] * 4 \
+        + [s0.pages[1]] + [s1.pages[0]] * 2
+    assert plan.slots[:7].tolist() == [0, 1, 2, 3, 0, 0, 1]
+    # the rows behind the tokens scatter into the sink page
+    assert plan.page_ids[7:].tolist() == [pool.sink] * 121
+    assert plan.prefill_waiting == 0
+    assert (sched.rows_planned, sched.rows_empty) == (128, 121)
     sched.commit(plan)
     # finishing frees pages IMMEDIATELY and r3 admits next plan
     held = pool.available()
@@ -1023,7 +1032,8 @@ def test_scheduler_window_budget_clamps_pages_and_budget():
         seq.tokens.append(7)
         req._emit(7)                           # one sampled token out
         plan, _, _ = sched.plan_step()         # steady-state decode
-        assert plan.n_prefill == 0 and plan.tok.shape[1] == 1
+        assert plan.n_prefill == 0 and plan.q_width == 1 \
+            and plan.tok.shape == (sched.max_batch,)
         return plan
 
     # page-limited: 3 usable pages, prompt holds 2 -> w clamps to 6
@@ -1229,6 +1239,7 @@ def test_cold_dispatch_failure_fails_engine_not_requests(gpt_model):
         raise RuntimeError("RESOURCE_EXHAUSTED: Ran out of memory in "
                            "memory space vmem")
 
+    refuse.packed = refuse            # the entry the engine calls
     engine._step_fn = refuse
     with engine, pytest.warns(UserWarning, match="loop died"):
         reqs = [engine.submit(p, max_new_tokens=4) for p in prompts]

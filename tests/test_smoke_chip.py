@@ -40,8 +40,13 @@ def test_chip_smoke_rehearsal_is_green():
     # counted on the CPU too, asserted only where a chip compiles it
     assert {"pool_copies_q1", "pool_copies_q1_hd128_layer",
             "pool_copies_q1_key192_full_layer",
-            "pool_copies_q1_key192_window_layer"} \
+            "pool_copies_q1_key192_window_layer", "pool_copies_packed"} \
         <= set(detail["phases"]["serve"])
+    # the packed step's guard: rows beside q_width, no product at 8 x Q
+    serve = detail["phases"]["serve"]
+    assert (serve["packed_q_width"], serve["packed_rows"]) == (64, 128)
+    assert 128 in serve["packed_matmul_rows"] \
+        and 512 not in serve["packed_matmul_rows"]
 
 
 def test_chip_smoke_without_a_chip_fails_and_prints_no_result():
@@ -135,6 +140,48 @@ def test_window_and_full_layers_of_wide_keys_compile_with_no_pool_copy():
         assert out[kind]["kernels"] == 1, out
         assert out[kind]["pool_copies"] == 0, out
     assert out["unpadded"]["pool_copies"] == 2, out
+
+
+_AOT_PACKED_STEP = _AOT_SERVE_LAYER.replace(
+    """out = {}
+for qw in (1, 128):
+    compiled, pool = chip_smoke._compile_serve_layer(
+        qw, sharding=one_chip, **chip_smoke._HD128_LAYER)
+    text = compiled.as_text()
+""", """out = {}
+# the chip's routes (the Mosaic kernel, donated pools) for a program
+# that is compiled here and run nowhere
+jax.default_backend = lambda: "tpu"
+for qw in (1024,):
+    compiled, pool, rows = chip_smoke._compile_packed_step(
+        qw, sharding=one_chip)
+    text = compiled.as_text()
+    out["rows"] = rows
+    out["matmul_rows"] = chip_smoke._matmul_rows(text)
+""")
+
+
+def test_q1024_program_multiplies_packed_rows_and_copies_no_pool():
+    """The guard beside PR 26's: the engine's own Q=1024 program
+    (``serve_step_q1024``) of one Mistral-width layer, compiled ahead of
+    time for a v5e, runs every matrix product over the 1,032 packed rows
+    (a chunk of 1,024 and a token a lane) or over the 8 last rows of the
+    head — none over 8 lanes x 1,024 = 8,192 — and holds no copy of a
+    page pool."""
+    assert "_compile_packed_step" in _AOT_PACKED_STEP
+    proc = _run(["-c", _AOT_PACKED_STEP], env={"JAX_PLATFORMS": "cpu"})
+    lines = proc.stdout.strip().splitlines()
+    if lines and lines[-1].startswith("NO_TOPOLOGY"):
+        pytest.skip(f"no v5e topology can be described here: {lines[-1]}")
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+    out = json.loads(lines[-1].removeprefix("RESULT "))
+    assert out["rows"] == 1032
+    assert set(out["matmul_rows"]) == {8, 1032}, out
+    assert out["1024"]["kernels"] == 1, out
+    assert out["1024"]["pool_copies"] == 0, out
+    # q gathered for the kernel and its output, no [8192, ...] buffers
+    # of the feed-forward's width: well under a pool and a half
+    assert out["1024"]["temp_bytes"] < 3 * out["1024"]["pool_bytes"], out
 
 
 _REPORT_CACHE_DIR = """
