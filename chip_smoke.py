@@ -243,8 +243,9 @@ def _build_model(sz: dict, sequence_parallel: bool = False):
 
 
 def _train_step(model, wrap_optimizer=lambda o: o):
-    """Optimizer, AMP and the jitted step exactly as bench.py builds
-    them (``wrap_optimizer``: fleet's wrapper on the four-chip leg)."""
+    """Optimizer, AMP and the jitted step as ``benchmark/runners/
+    train.py`` builds them (``wrap_optimizer``: fleet's wrapper on the
+    four-chip leg)."""
     import paddle_tpu.optimizer as opt
     from paddle_tpu import amp
     from paddle_tpu.jit import train_step

@@ -198,7 +198,7 @@ def synchronize(device=None):
 
 
 # bf16 peak FLOPs per chip by TPU generation (public spec sheets) —
-# the single source for Engine.cost and bench.py MFU numbers
+# the single source for Engine.cost's MFU numbers
 TPU_PEAK_BF16 = {
     "v2": 46e12, "v3": 123e12, "v4": 275e12,
     "v5lite": 197e12, "v5e": 197e12, "v5p": 459e12, "v6e": 918e12,

@@ -46,7 +46,7 @@ from ..random_state import default_generator
 
 __all__ = ["generate", "decode_loop", "build_ragged_decode_step",
            "build_fused_window_step", "AttentionKind", "FeedForwardKind",
-           "LayerDescription", "CacheDescription"]
+           "LayerDescription", "ModelDescription", "CacheDescription"]
 
 _GREEDY = ("greedy_search", "greedy")
 
@@ -431,34 +431,68 @@ def _compiled_decode(model, arr, max_new_tokens, decode_strategy,
 class AttentionKind:
     """One layer's attention.  ``window`` None: full causal attention,
     whose cache holds every token; else the last ``window`` keys are
-    visible and the cache holds a ring of them.  ``value_scale``
-    multiplies the value rows before the weighted sum."""
+    visible and the cache holds a ring of them.  The first
+    ``rotary_dim`` dimensions of a head are rotated (0: none) from base
+    ``rope_theta``, as pairs ``(i, i + rotary_dim / 2)`` or, with
+    ``rope_interleaved``, ``(2i, 2i + 1)``.  ``value_scale`` multiplies
+    the value rows before the weighted sum."""
     window: Optional[int]
     kv_heads: int
     key_dim: int
     value_dim: int
     rotary_dim: int = 0
     rope_theta: float = 10000.0
+    rope_interleaved: bool = False
     sink: bool = False
     value_scale: float = 1.0
 
 
 @dataclass(frozen=True)
 class FeedForwardKind:
-    """One layer's feed-forward.  ``held`` None: dense SwiGLU of
-    ``width``; else experts of ``width`` behind a router over
-    ``router_width`` of which each row takes ``top_k``, and of which
-    this chip holds ``held = (first, count)``."""
+    """One layer's feed-forward of ``width``: ``gated`` (``act(gate) *
+    up``, then down) or a plain two-matrix MLP with biases, ``act``
+    being "silu" or "gelu_tanh".  ``held`` None: dense; else gated
+    experts behind a router over ``router_width`` of which each row
+    takes ``top_k``, and of which this chip holds ``held = (first,
+    count)``."""
     width: int
     router_width: int = 0
     top_k: int = 0
     held: Optional[Tuple[int, int]] = None
+    act: str = "silu"
+    gated: bool = True
 
 
 @dataclass(frozen=True)
 class LayerDescription:
     attention: AttentionKind
     feed_forward: FeedForwardKind
+
+
+@dataclass(frozen=True)
+class ModelDescription:
+    """What ``build_ragged_decode_step`` writes a model's step from,
+    beside its arrays: every field a fact of the architecture, read
+    from the model's config (``config.description()``).
+
+    ``norm`` is "rms_norm" or "layer_norm" (weights and biases), at
+    ``norm_eps``, for both norms of a layer and the final one.
+    ``learned_positions``: a position table is added to the embedding
+    (the rotation, if any, is the layer's: ``AttentionKind``).
+    ``tied_head``: the head multiplies by the embedding.  ``precision``
+    is the matmul precision a served step runs at: None is jax's
+    default, at which a float32 product is ONE bf16 pass on the MXU; a
+    name ("high": three passes) is what the step's XLA products run at,
+    with the attention kernel's two dots at "highest" (Mosaic takes no
+    "high")."""
+    layers: Tuple[LayerDescription, ...]
+    heads: int
+    norm: str = "rms_norm"
+    norm_eps: float = 1e-5
+    learned_positions: bool = False
+    embed_scale: float = 1.0
+    tied_head: bool = False
+    precision: Optional[str] = None
 
 
 class CacheDescription:
@@ -655,7 +689,7 @@ class _StepRows:
 
 
 def _two_ways_in(rows_body):
-    """The step object of one family's ``rows_body(p, tok [rows],
+    """The step object of a ``rows_body(p, tok [rows],
     pos [rows], pools, page_ids [rows], slots [rows], kv_lens, q_lens,
     tables, rows: _StepRows)``: the documented ``[B, Q]`` call, which
     runs the body at ``B * Q`` rows with sequence ``b`` starting at row
@@ -698,7 +732,8 @@ def build_ragged_decode_step(model):
           -> (last_logits [B, V], pools')
 
     where ``pools`` is a per-layer tuple of ``(k_pages, v_pages)``
-    ``[nkv, P, ps, hd]`` pools shared by every sequence.  Each
+    ``[nkv, P, ps, hd]`` pools shared by every sequence, as
+    ``step.cache`` (a :class:`CacheDescription`) describes them.  Each
     sequence contributes ``q_lens[b]`` new tokens this step (a prefill
     chunk or one decode token, padded to the batch-wide ``Q``); their
     k/v land at ``(page_ids, slots)`` BEFORE the one-launch ragged
@@ -714,154 +749,54 @@ def build_ragged_decode_step(model):
     sequences' tokens packed one behind the other, so that a step with
     one wide chunk beside decoding lanes multiplies about as many rows
     as it feeds tokens (:func:`_two_ways_in`).  Numerics mirror the
-    model's ``build_decode_step`` body exactly (same norm references,
-    fp32 attention statistics), so engine output is token-for-token
-    the eager ``generate`` output.
+    model's own forward (same norm references, fp32 attention
+    statistics), so engine output is token-for-token the eager
+    ``generate`` output.
 
-    Works for any model whose ``build_decode_step`` params carry the
-    GPT (``blocks``) or LLaMA (``layers``) layout, and for a model that
-    describes its layers (``config.layer_descriptions()`` and
-    ``described_params()``: attention and feed-forward kinds that differ
-    from layer to layer), whose step also returns a third value, its
-    routing counts (:func:`_build_described_step`).
+    **What a model provides** (docs/serving_a_new_architecture.md):
 
-    The step carries ``step.cache``, the :class:`CacheDescription` of
-    the ``pools`` it takes."""
-    from ..ops.pallas import fused_decode as _fd
-    from ..ops.pallas.ragged_paged_attention import ragged_paged_attention
+    * ``model.config.description()``, a :class:`ModelDescription`: the
+      norm, where positions come from, the head, the step's precision,
+      and layer by layer the attention (full or windowed, key-value
+      heads and key and value widths of the layer's own, a rotation
+      over the first ``rotary_dim`` dimensions from the layer's own
+      base, a sink or none) and the feed-forward (an MLP, gated, or
+      routed experts of which this chip holds some);
+    * ``model.described_params()``, the tree of arrays the one body
+      below reads — ``embed``, ``positions`` (a learned table),
+      ``rope`` (``{_rope_key(theta): (cos, sin)}``), ``norm_w`` /
+      ``norm_b``, ``lm_w`` (an untied head) and ``layers``, each with
+      ``ln1_w`` / ``ln1_b``, the projection as :func:`_qkv_rows` takes
+      it, ``wo`` / ``bo``, ``sink``, ``ln2_w`` / ``ln2_b`` and
+      ``w1 b1 w2 b2`` (MLP), ``wg wu wd`` (gated; a tuple an expert
+      behind ``router_w`` / ``router_b``).  Whether a projection has a
+      bias is read from the tree: an entry that is absent or None adds
+      nothing.
 
-    if hasattr(model, "described_params"):
-        return _build_described_step(model)
-    params, _ = model.build_decode_step()
-    c = model.config
-    nh = int(c.num_heads)
-    hidden = int(c.hidden_size)
-    hd = hidden // nh
-    tied = bool(c.tie_word_embeddings)
+    Window layers write and read a ring (:class:`CacheDescription`):
+    their page ids and slots are derived here from ``pos`` and the ring
+    page ids behind the full-layer pages in ``tables``;
+    ``page_ids``/``slots`` serve the full layers alone.
 
-    if "blocks" in params:                              # GPT family
-        def rows_body(p, tok, pos, pools, page_ids, slots, kv_lens,
-                      q_lens, tables, rows):
-            x = jnp.take(p["wte"], tok, axis=0) \
-                + jnp.take(p["wpe"], pos, axis=0)        # [rows, H]
-            new_pools = []
-            for i, bp in enumerate(p["blocks"]):
-                h = _fd.reference_layer_norm(x, bp["ln1_w"],
-                                             bp["ln1_b"], 1e-5)
-                qp = (jnp.matmul(h, bp["wq"]) + bp["bq"]) \
-                    .reshape(-1, nh, hd)
-                kp = (jnp.matmul(h, bp["wk"]) + bp["bk"]) \
-                    .reshape(-1, nh, hd)
-                vp = (jnp.matmul(h, bp["wv"]) + bp["bv"]) \
-                    .reshape(-1, nh, hd)
-                kpg = _scatter_pages(pools[i][0], kp, page_ids, slots)
-                vpg = _scatter_pages(pools[i][1], vp, page_ids, slots)
-                new_pools.append((kpg, vpg))
-                ctx = rows.from_lanes(ragged_paged_attention(
-                    rows.to_lanes(qp), kpg, vpg, kv_lens, q_lens, tables))
-                x = x + (jnp.matmul(ctx.reshape(-1, hidden), bp["wo"])
-                         + bp["bo"])
-                x = x + _fd.norm_mlp(
-                    x, kind="layer_norm",
-                    norm_w=bp["ln2_w"], norm_b=bp["ln2_b"],
-                    w1=bp["w1"], b1=bp["b1"], w2=bp["w2"], b2=bp["b2"],
-                    eps=1e-5, act="gelu_tanh")
-            h = _fd.reference_layer_norm(x, p["lnf_w"], p["lnf_b"],
-                                         1e-5)
-            w = p["wte"] if tied else p["lm_w"]
-            logits = jnp.matmul(rows.last_rows(h),
-                                jnp.swapaxes(w, -1, -2))
-            return logits, tuple(new_pools)
-
-        step = _two_ways_in(rows_body)
-        step.cache = CacheDescription(
-            [(nh, hd, hd, None)] * len(params["blocks"]))
-        return params, step
-
-    if "layers" in params:                              # LLaMA family
-        nkv = int(c.num_kv_heads)
-        eps = float(c.rms_eps)
-        act = c.hidden_act
-        scale = float(c.embed_scale)
-
-        def rows_body(p, tok, pos, pools, page_ids, slots, kv_lens,
-                      q_lens, tables, rows):
-            x = jnp.take(p["embed"], tok, axis=0)        # [rows, H]
-            if scale != 1.0:
-                x = x * scale
-            cos = jnp.take(p["cos"], pos, axis=0)[:, None, :]
-            sin = jnp.take(p["sin"], pos, axis=0)[:, None, :]
-            new_pools = []
-            for i, lp in enumerate(p["layers"]):
-                h = _fd.reference_rms_norm(x, lp["ln1_w"], eps)
-                qp = jnp.matmul(h, lp["wq"]).reshape(-1, nh, hd)
-                kp = jnp.matmul(h, lp["wk"]).reshape(-1, nkv, hd)
-                vp = jnp.matmul(h, lp["wv"]).reshape(-1, nkv, hd)
-                if lp["bq"] is not None:
-                    qp = qp + lp["bq"].reshape(nh, hd)
-                if lp["bk"] is not None:
-                    kp = kp + lp["bk"].reshape(nkv, hd)
-                if lp["bv"] is not None:
-                    vp = vp + lp["bv"].reshape(nkv, hd)
-                qp = _fd.reference_rope_rows(qp, cos, sin)
-                kp = _fd.reference_rope_rows(kp, cos, sin)
-                kpg = _scatter_pages(pools[i][0], kp, page_ids, slots)
-                vpg = _scatter_pages(pools[i][1], vp, page_ids, slots)
-                new_pools.append((kpg, vpg))
-                ctx = rows.from_lanes(ragged_paged_attention(
-                    rows.to_lanes(qp), kpg, vpg, kv_lens, q_lens, tables))
-                x = x + jnp.matmul(ctx.reshape(-1, nh * hd), lp["wo"])
-                x = x + _fd.norm_mlp(
-                    x, kind="rms_norm",
-                    norm_w=lp["ln2_w"], w_gate=lp["wg"], w1=lp["wu"],
-                    w2=lp["wd"], eps=eps, act=act)
-            h = _fd.reference_rms_norm(x, p["norm_w"], eps)
-            w = p["embed"] if tied else p["lm_w"]
-            logits = jnp.matmul(rows.last_rows(h),
-                                jnp.swapaxes(w, -1, -2))
-            return logits, tuple(new_pools)
-
-        step = _two_ways_in(rows_body)
-        step.cache = CacheDescription(
-            [(nkv, hd, hd, None)] * len(params["layers"]))
-        return params, step
-
-    raise TypeError(
-        f"{type(model).__name__}.build_decode_step() params carry "
-        "neither a GPT ('blocks') nor a LLaMA ('layers') layout — "
-        "build_ragged_decode_step has no adapter for it")
-
-
-def _build_described_step(model):
-    """The ragged step of a model that describes its layers: one body
-    written against ``LayerDescription`` — attention full or windowed,
-    key-value heads and key and value widths of the layer's own, a
-    rotation over the first ``rotary_dim`` dimensions from the layer's
-    own base, a sink or none; feed-forward dense or routed experts of
-    which this chip holds some.
-
-    ``step`` and ``step.packed`` take the arguments of every ragged
-    step.  ``pools`` is
-    what ``step.cache`` describes.  Window layers write and read a ring
-    (``CacheDescription``): their page ids and slots are derived here
-    from ``pos`` and the ring page ids behind the full-layer pages in
-    ``tables``; ``page_ids``/``slots`` serve the full layers alone.
-
-    With an expert layer ``step`` returns ``(logits, pools', counts)``:
-    ``counts i32[3]`` are the rows routed to held experts summed over
-    layers, the fullest held expert's rows (max over layers) and the
-    held experts with at least one row summed over layers."""
+    With an expert layer (``step.routing_counts``) ``step`` returns
+    ``(logits, pools', counts)``: ``counts i32[3]`` are the rows routed
+    to held experts summed over layers, the fullest held expert's rows
+    (max over layers) and the held experts with at least one row summed
+    over layers."""
     from ..ops.pallas import fused_decode as _fd
     from ..ops.pallas.ragged_paged_attention import ragged_paged_attention
     from ..ops.routed_experts import held_experts_swiglu, \
         sigmoid_topk_route
 
     params = model.described_params()
-    c = model.config
-    descs = tuple(c.layer_descriptions())
-    nh = int(c.num_heads)
-    hidden = int(c.hidden_size)
-    eps = float(c.rms_eps)
+    md = model.config.description()
+    descs = md.layers
+    nh, eps = md.heads, md.norm_eps
+    gated_norm = {"rms_norm": True, "layer_norm": False}[md.norm]
+    if any(d.feed_forward.gated != gated_norm for d in descs):
+        raise NotImplementedError(
+            "fused_decode.norm_mlp pairs an RMS norm with a gated "
+            "feed-forward and a layer norm with an MLP, and no other way")
     cache = CacheDescription(
         [(d.attention.kv_heads, _pool_width(d.attention.key_dim),
           _pool_width(d.attention.value_dim), d.attention.window)
@@ -869,13 +804,32 @@ def _build_described_step(model):
     window_layer = next((i for i, d in enumerate(descs)
                          if d.attention.window is not None), None)
     has_experts = any(d.feed_forward.held is not None for d in descs)
+    kernel_precision = None if md.precision is None \
+        else jax.lax.Precision.HIGHEST
     i32 = jnp.int32
+
+    def norm(x, w, b):
+        if md.norm == "layer_norm":
+            return _fd.reference_layer_norm(x, w, b, eps)
+        return _fd.reference_rms_norm(x, w, eps)
+
+    def rotated(x, att, cos, sin):
+        rot = att.rotary_dim
+        turn = lambda a: _fd.reference_rope_rows(
+            a, cos, sin, neox=not att.rope_interleaved)
+        if rot == x.shape[-1]:
+            return turn(x)
+        return jnp.concatenate([turn(x[..., :rot]), x[..., rot:]], axis=-1)
 
     def body(p, tok, pos, pools, page_ids, slots, kv_lens, q_lens,
              tables, rows):
         x = jnp.take(p["embed"], tok, axis=0)             # [rows, H]
+        if md.embed_scale != 1.0:
+            x = x * md.embed_scale
         valid = rows.valid
         pos = pos.astype(i32)
+        if md.learned_positions:
+            x = x + jnp.take(p["positions"], pos, axis=0)
         full_tables = tables
         if window_layer is not None:
             wpool = pools[window_layer][0]
@@ -890,30 +844,20 @@ def _build_described_step(model):
             ring_slots = jnp.where(valid, pos % i32(ps), i32(0))
         rope = {theta: (jnp.take(cos, pos, axis=0)[:, None, :],
                         jnp.take(sin, pos, axis=0)[:, None, :])
-                for theta, (cos, sin) in p["rope"].items()}
+                for theta, (cos, sin) in p.get("rope", {}).items()}
         counts = [i32(0), i32(0), i32(0)]
         new_pools = []
         for i, (d, lp) in enumerate(zip(descs, p["layers"])):
             att, ff = d.attention, d.feed_forward
-            nkv, dk, dv = att.kv_heads, att.key_dim, att.value_dim
-            qkv = jnp.matmul(_fd.reference_rms_norm(x, lp["ln1_w"], eps),
-                             lp["wqkv"])
-            qp = qkv[:, :nh * dk].reshape(-1, nh, dk)
-            kp = qkv[:, nh * dk:(nh + nkv) * dk].reshape(-1, nkv, dk)
-            vp = qkv[:, (nh + nkv) * dk:].reshape(-1, nkv, dv)
+            dk, dv = att.key_dim, att.value_dim
+            qp, kp, vp = _qkv_rows(
+                lp, norm(x, lp["ln1_w"], lp.get("ln1_b")), nh, att)
             if att.value_scale != 1.0:
                 vp = vp * att.value_scale
-            rot = att.rotary_dim
-            if rot:
+            if att.rotary_dim:
                 cos, sin = rope[_rope_key(att.rope_theta)]
-                qp = jnp.concatenate(
-                    [_fd.reference_rope_rows(qp[..., :rot], cos, sin,
-                                             neox=True), qp[..., rot:]],
-                    axis=-1)
-                kp = jnp.concatenate(
-                    [_fd.reference_rope_rows(kp[..., :rot], cos, sin,
-                                             neox=True), kp[..., rot:]],
-                    axis=-1)
+                qp = rotated(qp, att, cos, sin)
+                kp = rotated(kp, att, cos, sin)
             windowed = att.window is not None
             ids, sl, tb = (ring_ids, ring_slots, ring) if windowed \
                 else (page_ids, slots, full_tables)
@@ -925,20 +869,17 @@ def _build_described_step(model):
             kpg = _scatter_pages(pools[i][0], kp, ids, sl)
             vpg = _scatter_pages(pools[i][1], vp, ids, sl)
             new_pools.append((kpg, vpg))
-            # Mosaic takes no "high": the kernel's two dots are float32
             ctx = rows.from_lanes(ragged_paged_attention(
                 rows.to_lanes(qp), kpg, vpg, kv_lens, q_lens, tb,
                 scale=1.0 / math.sqrt(dk), window=att.window,
                 sinks=lp["sink"] if att.sink else None,
-                precision=jax.lax.Precision.HIGHEST))
-            x = x + jnp.matmul(ctx[..., :dv].reshape(-1, nh * dv),
-                               lp["wo"])
-            if ff.held is None:
-                y = _fd.norm_mlp(x, kind="rms_norm", norm_w=lp["ln2_w"],
-                                 w_gate=lp["wg"], w1=lp["wu"], w2=lp["wd"],
-                                 eps=eps, act="silu")
-            else:
-                h2 = _fd.reference_rms_norm(x, lp["ln2_w"], eps)
+                precision=kernel_precision))
+            out = jnp.matmul(ctx[..., :dv].reshape(-1, nh * dv), lp["wo"])
+            if lp.get("bo") is not None:
+                out = out + lp["bo"]
+            x = x + out
+            if ff.held is not None:
+                h2 = norm(x, lp["ln2_w"], lp.get("ln2_b"))
                 picks, weights = sigmoid_topk_route(
                     h2, lp["router_w"], lp["router_b"], ff.top_k)
                 y, n_rows = held_experts_swiglu(
@@ -947,24 +888,27 @@ def _build_described_step(model):
                 counts = [counts[0] + jnp.sum(n_rows, dtype=i32),
                           jnp.maximum(counts[1], jnp.max(n_rows)),
                           counts[2] + jnp.sum(n_rows > 0, dtype=i32)]
+            elif ff.gated:
+                y = _fd.norm_mlp(x, kind=md.norm, norm_w=lp["ln2_w"],
+                                 w_gate=lp["wg"], w1=lp["wu"], w2=lp["wd"],
+                                 eps=eps, act=ff.act)
+            else:
+                y = _fd.norm_mlp(x, kind=md.norm, norm_w=lp["ln2_w"],
+                                 norm_b=lp["ln2_b"], w1=lp["w1"],
+                                 b1=lp["b1"], w2=lp["w2"], b2=lp["b2"],
+                                 eps=eps, act=ff.act)
             x = x + y
-        h = _fd.reference_rms_norm(x, p["norm_w"], eps)
-        logits = jnp.matmul(rows.last_rows(h),
-                            jnp.swapaxes(p["lm_w"], -1, -2))
+        h = norm(x, p["norm_w"], p.get("norm_b"))
+        w = p["embed"] if md.tied_head else p["lm_w"]
+        logits = jnp.matmul(rows.last_rows(h), jnp.swapaxes(w, -1, -2))
         if has_experts:
             return logits, tuple(new_pools), jnp.stack(counts)
         return logits, tuple(new_pools)
 
     def rows_body(*args):
-        # float32 served as float32: at jax's default a float32 product
-        # is ONE bf16 pass on the MXU, which this model's logits check
-        # could not tell from serving in bfloat16 (the program read
-        # 1.3e-2..4.0e-2 of the largest logit over nine seeds, flipped
-        # expert selections included, where the reference in bfloat16
-        # reads 2.7e-2..3.9e-2; at "high", three passes, with the
-        # attention kernel told "highest", it reads 6e-5: PERF.md
-        # section 6, PR 27)
-        with jax.default_matmul_precision(_DESCRIBED_PRECISION):
+        if md.precision is None:
+            return body(*args)
+        with jax.default_matmul_precision(md.precision):
             return body(*args)
 
     step = _two_ways_in(rows_body)
@@ -973,14 +917,30 @@ def _build_described_step(model):
     return params, step
 
 
-# the matmul precision of a described model's ragged step
-_DESCRIBED_PRECISION = "high"
+def _qkv_rows(lp, h, nh: int, att: AttentionKind):
+    """One layer's query, key and value rows ``[rows, heads, dim]`` from
+    its normed input ``h``, in whichever form the model's tree holds the
+    projection: one ``wqkv`` (queries, then keys, then values, side by
+    side) or ``wq`` / ``wk`` / ``wv``, each with a bias (``bq`` ...) or
+    none — so that each family's program multiplies the matrices it
+    holds, and none is joined or split on the device to fit."""
+    nkv, dk, dv = att.kv_heads, att.key_dim, att.value_dim
+    if "wqkv" in lp:
+        qkv = jnp.matmul(h, lp["wqkv"])
+        q, k, v = (qkv[:, :nh * dk], qkv[:, nh * dk:(nh + nkv) * dk],
+                   qkv[:, (nh + nkv) * dk:])
+    else:
+        q, k, v = (jnp.matmul(h, lp[w]) if lp.get(b) is None
+                   else jnp.matmul(h, lp[w]) + lp[b]
+                   for w, b in (("wq", "bq"), ("wk", "bk"), ("wv", "bv")))
+    return (q.reshape(-1, nh, dk), k.reshape(-1, nkv, dk),
+            v.reshape(-1, nkv, dv))
 
 
 def _pool_width(dim: int) -> int:
-    """The last axis of a described model's page pool for rows of
-    ``dim``.  A head wider than the 128-lane tile and not a whole number
-    of tiles (keys of 192) is padded up to one: the compiler's own
+    """The last axis of a page pool for rows of ``dim``.  A head wider
+    than the 128-lane tile and not a whole number of tiles (keys of
+    192) is padded up to one: the compiler's own
     layout for ``f32[nkv, P, ps, 192]`` is not the row-major one the
     Mosaic call takes, and every step would re-lay each such pool twice
     (two whole-pool copies a pool a step at Q=1 and Q=1024, sandbox AOT
@@ -997,8 +957,7 @@ def _pad_last(a, width: int):
 
 
 def _rope_key(theta: float) -> str:
-    """The name of a rotary base's tables in a described model's
-    ``params["rope"]``."""
+    """The name of a rotary base's tables in ``params["rope"]``."""
     return f"{float(theta):g}"
 
 
@@ -1017,7 +976,7 @@ def build_fused_window_step(model, max_window: int):
     ``kv_lens`` are the PRE-append lengths (tokens already in KV);
     ``tok`` is each live lane's pending last-sampled token.  Every
     iteration re-derives the page-append cursors on device
-    (``append_positions``), runs the family-generic ragged step at
+    (``append_positions``), runs the ragged step at
     Q=1, and samples EXACTLY like the engine's single-step program
     (one ``jax.random.split`` per iteration, argmax/categorical
     blend on temperature) so the RNG stream and the sampled tokens
@@ -1039,14 +998,16 @@ def build_fused_window_step(model, max_window: int):
     broadcast to every lane."""
     from ..ops.pallas.ragged_paged_attention import append_positions
 
-    if hasattr(model, "described_params"):
+    params, step = build_ragged_decode_step(model)
+    if step.cache.window is not None or step.routing_counts:
         raise TypeError(
             f"build_fused_window_step does not take "
             f"{type(model).__name__}: the fused window derives one "
-            f"append cursor a lane from tables and carries no routing "
-            f"counts; serve a model that describes its layers with "
-            f"FLAGS_serving_fused_steps=1")
-    params, step = build_ragged_decode_step(model)
+            f"append cursor a lane from tables, so it can fill no ring "
+            f"of a window layer (step.cache.window = "
+            f"{step.cache.window}), and carries no routing counts "
+            f"(step.routing_counts = {step.routing_counts}); serve such "
+            f"a model with FLAGS_serving_fused_steps=1")
 
     def fused_window(params, tok, pools, kv_lens, live, tables, temps,
                      eos_ids, budgets, key, n_steps):

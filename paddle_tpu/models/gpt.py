@@ -34,6 +34,8 @@ from ..distributed.fleet.utils.sequence_parallel_utils import (
     AllGatherOp, ReduceScatterOp)
 from ..distributed.shard_utils import sharding_constraint
 from ..distributed.fleet.recompute import recompute
+from .generation import (AttentionKind, FeedForwardKind, LayerDescription,
+                         ModelDescription)
 import paddle_tpu as paddle
 
 
@@ -55,6 +57,23 @@ class GPTConfig:
     def __post_init__(self):
         if self.intermediate_size is None:
             self.intermediate_size = 4 * self.hidden_size
+
+    def layer_descriptions(self):
+        """A block as ``models.generation`` serves it: full attention of
+        ``num_heads`` heads and no rotation, then a GELU MLP."""
+        hd = int(self.hidden_size) // int(self.num_heads)
+        block = LayerDescription(
+            AttentionKind(window=None, kv_heads=int(self.num_heads),
+                          key_dim=hd, value_dim=hd),
+            FeedForwardKind(width=int(self.intermediate_size),
+                            act="gelu_tanh", gated=False))
+        return (block,) * int(self.num_layers)
+
+    def description(self) -> ModelDescription:
+        return ModelDescription(
+            self.layer_descriptions(), heads=int(self.num_heads),
+            norm="layer_norm", norm_eps=1e-5, learned_positions=True,
+            tied_head=bool(self.tie_word_embeddings))
 
 
 PRESETS = {
@@ -296,6 +315,18 @@ class GPTForPretraining(nn.Layer):
         ``lax.while_loop``.  Params ride as jit arguments (weight
         updates between calls never retrace)."""
         return _build_gpt_decode_step(self)
+
+    def described_params(self):
+        """The tree the ragged step reads (``models.generation.
+        build_ragged_decode_step``): ``build_decode_step()``'s arrays,
+        the same buffers, under the step's names.  ``blocks`` is what
+        ``benchmark/runners/serve.py`` counts a GPT's layers by: an
+        entry a layer that holds no array (ROADMAP D14)."""
+        p, _ = self.build_decode_step()
+        return {"embed": p["wte"], "positions": p["wpe"],
+                "layers": p["blocks"], "blocks": (None,) * len(p["blocks"]),
+                "norm_w": p["lnf_w"], "norm_b": p["lnf_b"],
+                "lm_w": p["lm_w"]}
 
     def build_ragged_decode_step(self):
         """Batched serving-engine step over paged KV pools (per-

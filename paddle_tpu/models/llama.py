@@ -36,6 +36,8 @@ from ..distributed.fleet.meta_parallel.parallel_layers.mp_layers import (
     VocabParallelEmbedding)
 from ..distributed.shard_utils import sharding_constraint
 from ..distributed.fleet.recompute import recompute
+from .generation import (AttentionKind, FeedForwardKind, LayerDescription,
+                         ModelDescription, _rope_key)
 import paddle_tpu as paddle
 
 __all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM",
@@ -73,6 +75,27 @@ class LlamaConfig:
             # llama rule: 2/3 * 4h rounded up to a multiple of 256
             inter = int(8 * self.hidden_size / 3)
             self.intermediate_size = 256 * ((inter + 255) // 256)
+
+    def layer_descriptions(self):
+        """A layer as ``models.generation`` serves it: full attention
+        with ``num_kv_heads`` key-value heads, the whole head rotated as
+        pairs ``(2i, 2i + 1)``, then a gated feed-forward."""
+        hd = int(self.hidden_size) // int(self.num_heads)
+        layer = LayerDescription(
+            AttentionKind(window=None, kv_heads=int(self.num_kv_heads),
+                          key_dim=hd, value_dim=hd, rotary_dim=hd,
+                          rope_theta=float(self.rope_theta),
+                          rope_interleaved=True),
+            FeedForwardKind(width=int(self.intermediate_size),
+                            act=self.hidden_act))
+        return (layer,) * int(self.num_layers)
+
+    def description(self) -> ModelDescription:
+        return ModelDescription(
+            self.layer_descriptions(), heads=int(self.num_heads),
+            norm_eps=float(self.rms_eps),
+            embed_scale=float(self.embed_scale),
+            tied_head=bool(self.tie_word_embeddings))
 
 
 LLAMA_PRESETS = {
@@ -352,6 +375,17 @@ class LlamaForCausalLM(nn.Layer):
         ``[B, S_total, n_kv, hd]`` caches — rope rows gathered at
         ``pos``, GQA heads expanded inside the fused attention."""
         return _build_llama_decode_step(self)
+
+    def described_params(self):
+        """The tree the ragged step reads (``models.generation.
+        build_ragged_decode_step``): ``build_decode_step()``'s arrays,
+        the same buffers, with the rotation's tables under their base's
+        name."""
+        p, _ = self.build_decode_step()
+        return {"embed": p["embed"], "layers": p["layers"],
+                "rope": {_rope_key(self.config.rope_theta):
+                         (p["cos"], p["sin"])},
+                "norm_w": p["norm_w"], "lm_w": p["lm_w"]}
 
     def build_ragged_decode_step(self):
         """Batched serving-engine step over paged KV pools (per-

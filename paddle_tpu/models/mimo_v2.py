@@ -27,7 +27,7 @@ deployment holds.  With the default ``(0, n_routed_experts)`` it is the
 whole model.
 
 The class carries parameters and the description the serving stack
-asks for — ``config.layer_descriptions()`` and
+asks for — ``config.description()``, ``described_params()`` and
 ``build_ragged_decode_step()`` (``models.generation``) — and no eager
 forward: the engine is its path.  ``benchmark/reference/mimo_v2.py``
 is the plain full-sequence forward it is held to.
@@ -43,7 +43,7 @@ from .. import nn
 from ..framework.param_attr import ParamAttr
 from ..nn.initializer import Constant, Normal
 from .generation import (AttentionKind, FeedForwardKind,
-                         LayerDescription, _rope_key)
+                         LayerDescription, ModelDescription, _rope_key)
 
 __all__ = ["MiMoV2Config", "MiMoV2ForCausalLM"]
 
@@ -147,6 +147,19 @@ class MiMoV2Config:
             out.append(LayerDescription(att, ff))
         return tuple(out)
 
+    def description(self) -> ModelDescription:
+        # float32 served as float32: at jax's default a float32 product
+        # is ONE bf16 pass on the MXU, which this model's logits check
+        # could not tell from serving in bfloat16 (the program read
+        # 1.3e-2..4.0e-2 of the largest logit over nine seeds, flipped
+        # expert selections included, where the reference in bfloat16
+        # reads 2.7e-2..3.9e-2; at "high", three passes, with the
+        # attention kernel told "highest", it reads 6e-5: PERF.md
+        # section 6, PR 27)
+        return ModelDescription(
+            self.layer_descriptions(), heads=int(self.num_heads),
+            norm_eps=float(self.rms_eps), precision="high")
+
 
 class _Block(nn.Layer):
     """One decoder layer's parameters, weights ``[in, out]``."""
@@ -223,7 +236,7 @@ class MiMoV2ForCausalLM(nn.Layer):
             "paddle_tpu.serving.ServingEngine (build_ragged_decode_step)")
 
     def described_params(self):
-        """The tree the described ragged step reads: ``embed``,
+        """The tree the ragged step reads: ``embed``,
         ``norm_w``, ``lm_w``, ``rope`` (``theta -> (cos, sin)``, one
         pair of tables per rotary base, named by ``_rope_key``) and
         ``layers``."""

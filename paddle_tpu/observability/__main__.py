@@ -24,7 +24,7 @@ with ``--perf-model [DIR]``, observed durations checked against the
 learned performance model's predictions (``tuning.learned``; flags
 divergence on shapes no baseline log ever saw and emits
 ``perf_regression`` events) — exit 0 clean, 3 on regression, so CI
-and bench.py can gate on it.
+can gate on it.
 """
 from __future__ import annotations
 

@@ -27,8 +27,7 @@ Three gates:
   not SLOs).
 * :func:`self_check` — one log against itself: the ts-ordered first
   half of each key's samples is the baseline for the second half,
-  catching mid-run degradation (bench.py runs this warn-only on the
-  CPU smoke).
+  catching mid-run degradation.
 * :func:`model_check` — observed durations against the **learned
   performance model's predictions** (``tuning.learned``): a key whose
   median observed/predicted ratio leaves the tolerance band emits a
@@ -36,8 +35,8 @@ Three gates:
   a historical baseline can't give on a shape it never saw.
 
 CLI: ``python -m paddle_tpu.observability watchdog`` — exit 0 clean,
-3 on regression — usable as a CI gate and by bench.py
-(``--perf-model`` switches to the model-divergence mode).
+3 on regression — usable as a CI gate (``--perf-model`` switches to
+the model-divergence mode).
 """
 from __future__ import annotations
 
@@ -55,8 +54,7 @@ DEFAULT_MIN_SECONDS = 1e-4
 # keys that measure BACK-PRESSURE, not work: queue wait and
 # whole-request wall time scale with offered load (later arrivals in a
 # burst legitimately wait longer), so gating on them turns every load
-# test into a "regression".  Promoted here from bench.py's former
-# call-site list; pass exclude=() to check them anyway.
+# test into a "regression".  Pass exclude=() to check them anyway.
 DEFAULT_EXCLUDE = frozenset({"trace_span:queue",
                              "trace_span:serving_request"})
 

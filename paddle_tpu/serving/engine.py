@@ -43,9 +43,9 @@ sibling ``engine:*`` phases (plan, prepare, dispatch, host_read, commit
 and two waits) that show in any live profiler trace and, as seconds, in
 ``batch_step`` (``_LoopPhases``).  While a test or the analyzer
 observes the op-dispatch stream (``core.dispatch.observe_op_stream``)
-each step also emits ``serving_prefill`` / ``serving_decode`` markers
-carrying the REAL fed-token counts, which prove that prefix-cache
-sharing skips prefill work.
+each step also emits a ``serving_prefill`` marker carrying the REAL
+fed-token count, which proves that prefix-cache sharing skips prefill
+work, and a ``serving_host_sync`` marker per host read.
 
 Fault containment: co-batching couples failure domains — one poisoned
 request or one wedged dispatch would otherwise take down every
@@ -346,8 +346,8 @@ class ServingEngine:
         # counts: a model with a ring or an expert layer keeps the
         # single-step path, and asking for more is refused here, to the
         # caller, not later inside the serving loop
-        self._fusable = not self._ring_pages and not getattr(
-            self._step_fn, "routing_counts", False)
+        self._routed = self._step_fn.routing_counts
+        self._fusable = not self._ring_pages and not self._routed
         if not self._fusable and int(get_flag("serving_fused_steps")
                                      or 1) > 1:
             raise ValueError(
@@ -811,13 +811,11 @@ class ServingEngine:
             # admission, eviction and EOS all key off it
             toks = np.asarray(nxt)  # noqa: PTL701 — window boundary
         phases.switch(_COMMIT)
-        # dispatch-stream markers with the REAL fed-token counts (the
-        # prefix-cache FLOPs-skip proof reads these); the host-sync
-        # marker carries the iteration count the read covered, so the
-        # bench's host_syncs_per_100_tokens / steps_per_dispatch and
-        # the one-read-per-window test are measured, not claimed
+        # dispatch-stream markers: the REAL fed-token count (the
+        # prefix-cache FLOPs-skip proof reads it), and the iteration
+        # count the host read covered, so the one-read-per-window test
+        # measures it
         _mark_op_stream("serving_prefill", plan.fed_prefill)
-        _mark_op_stream("serving_decode", plan.fed_decode)
         _mark_op_stream("serving_host_sync", 1)
         with self._wake:
             if epoch != self._epoch:
@@ -1011,7 +1009,6 @@ class ServingEngine:
         phases.switch(_COMMIT)
         steps = int(out[0, max_window + 1])
         fed = len(plan.seqs) * steps
-        _mark_op_stream("serving_decode", fed)
         _mark_op_stream("serving_host_sync", steps)
         with self._wake:
             if epoch != self._epoch:
@@ -1283,7 +1280,7 @@ class ServingEngine:
         if prog is not None:
             return prog
         step = self._step_fn
-        routed = bool(getattr(step, "routing_counts", False))
+        routed = self._routed
 
         def program(params, tok, pos, pools, page_ids, slots, kv_lens,
                     q_lens, tables, temps, rng, poison):

@@ -23,8 +23,8 @@ Pipeline entry points:
   ``FLAGS_program_passes`` ('' = off; '1'/'default' = the default
   pipeline; or an explicit comma-separated pass list).
 * ``capture_decode_program(model, input_ids)`` — the shared harness
-  that captures one KV-cache decode step as a Program (bench.py's
-  op-count-reduction report and the golden tests both use it).
+  that captures one KV-cache decode step as a Program (the golden
+  tests use it).
 """
 from __future__ import annotations
 
@@ -278,7 +278,7 @@ def optimize_ops_for_jit(ops: Sequence, keep_ids: Set[int]) -> List:
 
 
 # ---------------------------------------------------------------------------
-# the shared decode-capture harness (bench.py + golden tests)
+# the decode-capture harness (the golden tests')
 # ---------------------------------------------------------------------------
 
 def capture_decode_program(model, input_ids, feed_name: str = "token"):

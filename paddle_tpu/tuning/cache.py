@@ -23,8 +23,7 @@ signature, backend — whatever the caller folds in).  Failure model:
   degrades to a miss, never an exception.  The next ``store`` rewrites
   the file clean.
 * **observability** — per-kind hit/miss/store/drop counters
-  (``stats()``), surfaced by bench.py and asserted by the warm-start
-  tier-1 tests.  Every counter bump is mirrored into the process
+  (``stats()``), asserted by the warm-start tier-1 tests.  Every counter bump is mirrored into the process
   metrics registry (``paddle_tuning_cache_events_total{kind,event}``,
   readable from any ``GET /metrics`` endpoint or the observability
   CLI) and, when ``FLAGS_observability_dir`` is set, emitted as a

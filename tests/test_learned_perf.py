@@ -526,9 +526,6 @@ def test_load_shaped_kinds_promoted_into_default_exclude():
              "dur_s": 0.01 * (1 + (i // 6) * 50)} for i in range(12)]
     assert watchdog.self_check(recs) == []
     assert watchdog.self_check(recs, exclude=()) != []
-    # bench.py no longer carries its own call-site list
-    with open(os.path.join(_REPO, "bench.py")) as fh:
-        assert "trace_span:serving_request" not in fh.read()
 
 
 def test_report_gains_duration_quantile_columns(tmp_path, flags_guard,

@@ -224,8 +224,25 @@ def test_prefix_caching_with_a_window_model_raises_with_the_reason(model):
         ServingEngine(model, prefix_caching=True)
 
 
+@pytest.mark.parametrize("layers, reason", [
+    (dict(hybrid_layer_pattern=[0, 1], moe_layer_freq=[0, 0]),
+     r"step\.cache\.window = 8.*step\.routing_counts = False"),
+    (dict(hybrid_layer_pattern=[0, 0], moe_layer_freq=[0, 1]),
+     r"step\.cache\.window = None.*step\.routing_counts = True"),
+])
+def test_fused_window_refuses_by_what_it_cannot_carry(layers, reason):
+    """A ring alone and routing counts alone each refuse; a model with
+    neither is taken, whatever its class (the GPT and LLaMA ``fused_*``
+    tests of test_serving.py)."""
+    paddle.seed(11)
+    m = MiMoV2ForCausalLM(_config(**layers))
+    with pytest.raises(TypeError, match=reason):
+        build_fused_window_step(m, 4)
+
+
 def test_fused_window_refuses_a_described_model_by_name(model):
-    with pytest.raises(TypeError, match="MiMoV2ForCausalLM"):
+    with pytest.raises(TypeError, match="MiMoV2ForCausalLM.*a window layer"
+                                        ".*routing counts"):
         build_fused_window_step(model, 4)
     # and the engine says so to its caller, not inside its loop
     keep = get_flags(["FLAGS_serving_fused_steps"])

@@ -158,7 +158,18 @@ for qw in (1024,):
     text = compiled.as_text()
     out["rows"] = rows
     out["matmul_rows"] = chip_smoke._matmul_rows(text)
+    import re
+    out["matmul_shapes"] = sorted(re.findall(
+        r"= (\\w+\\[[\\d,]+\\])\\S* convolution\\(", text))
 """)
+
+# the matrix products of serve_step_q1024 for one Mistral-width layer as
+# the LLaMA closure compiled them at PR 28 (sandbox AOT for a v5e): k and
+# v, gate and up, q, the output projection and down, and the head over
+# the 8 last rows
+_Q1024_PRODUCTS_AT_PR28 = sorted(
+    ["f32[1032,1024]"] * 2 + ["f32[1032,14336]"] * 2
+    + ["f32[1032,4096]"] * 3 + ["f32[8,2048]"])
 
 
 def test_q1024_program_multiplies_packed_rows_and_copies_no_pool():
@@ -167,7 +178,8 @@ def test_q1024_program_multiplies_packed_rows_and_copies_no_pool():
     time for a v5e, runs every matrix product over the 1,032 packed rows
     (a chunk of 1,024 and a token a lane) or over the 8 last rows of the
     head — none over 8 lanes x 1,024 = 8,192 — and holds no copy of a
-    page pool."""
+    page pool.  The one body of the ragged step multiplies what the
+    LLaMA closure multiplied: the same products, shape for shape."""
     assert "_compile_packed_step" in _AOT_PACKED_STEP
     proc = _run(["-c", _AOT_PACKED_STEP], env={"JAX_PLATFORMS": "cpu"})
     lines = proc.stdout.strip().splitlines()
@@ -177,6 +189,7 @@ def test_q1024_program_multiplies_packed_rows_and_copies_no_pool():
     out = json.loads(lines[-1].removeprefix("RESULT "))
     assert out["rows"] == 1032
     assert set(out["matmul_rows"]) == {8, 1032}, out
+    assert out["matmul_shapes"] == _Q1024_PRODUCTS_AT_PR28, out
     assert out["1024"]["kernels"] == 1, out
     assert out["1024"]["pool_copies"] == 0, out
     # q gathered for the kernel and its output, no [8192, ...] buffers
