@@ -389,8 +389,9 @@ def _ragged_parity(cfg, sz: dict, page_size: int) -> None:
 
 def _engine_program_args(engine, qw: int, sharding=None):
     """Abstract arguments of the engine's jitted ragged program at chunk
-    width ``qw`` (the shapes ``_run_step_traced`` feeds it: the plan's
-    packed token rows), on the attached device or on the described one
+    width ``qw`` (the shapes ``_dispatch_step`` feeds it: the plan's
+    packed token rows, the unread step's sampled row and the rows that
+    take a token from it), on the attached device or on the described one
     that ``sharding`` names."""
     import jax
     import jax.numpy as jnp
@@ -408,7 +409,8 @@ def _engine_program_args(engine, qw: int, sharding=None):
             sds((rows,), jnp.int32), sds((rows,), jnp.int32),
             sds((b,), jnp.int32), sds((b,), jnp.int32),
             sds((b, ppseq), jnp.int32), sds((b,), jnp.float32),
-            like(engine._key), sds((b,), jnp.float32))
+            like(engine._key), sds((b,), jnp.float32),
+            like(engine._no_prev), sds((rows,), jnp.int32))
 
 
 # the batch cell's attention geometry (mistral-7b-8l: 8 lanes, 32 heads

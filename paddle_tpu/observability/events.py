@@ -119,8 +119,8 @@ EVENT_SCHEMA: Dict[str, Dict[str, str]] = {
                    "tokens": "int", "rows": "int",
                    "prefill_waiting": "int", "queue_depth": "int",
                    "step_s": "float", "page_occupancy": "float",
-                   "cold_start": "bool", "fused_steps": "int",
-                   "exit_reason": "str",
+                   "cold_start": "bool", "ahead": "bool",
+                   "fused_steps": "int", "exit_reason": "str",
                    "plan_s": "float", "prepare_s": "float",
                    "dispatch_s": "float", "read_s": "float",
                    "commit_s": "float", "host_gap_s": "float",

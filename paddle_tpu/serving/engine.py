@@ -9,7 +9,11 @@ whole mixed prefill+decode batch (``models.generation.
 build_ragged_decode_step`` + the one-launch ragged paged attention
 kernel), samples the next token per sequence ON DEVICE, and reads the
 sampled row back in a single host sync at the window boundary — the
-only device read in the loop (PTL701).
+only device read in the loop (PTL701).  The loop keeps ONE step ahead
+of that read: step N+1 is planned against what step N will commit and
+dispatched before N's row is read (a decoding lane's token reaches it
+on the device), so the host's commit, plan and dispatch run under the
+device's step and not beside it (``_loop_body``).
 
 With ``FLAGS_serving_fused_steps > 1`` the steady-state decode window
 widens: up to N ragged iterations run inside ONE jitted
@@ -196,30 +200,41 @@ class _LoopPhases:
     With the event log off that is all it does: two annotation calls a
     switch, no clock read, nothing allocated.  With it on, ``switch``
     also reads ``perf_counter`` once and sums the closed phase into
-    ``seconds`` (indexed by phase), which ``take`` hands to the
-    ``batch_step`` record with:
+    ``seconds`` (indexed by phase).  A step's record takes them in two
+    halves, because the loop dispatches a step before it reads the one
+    before: ``dispatched`` hands back what it took to get the step out
+    (``plan_s``, ``prepare_s``, ``dispatch_s``) and ``take`` joins that
+    with what it took to get its result in (``read_s``, ``commit_s``),
+    with:
 
-    * ``host_gap_s`` — from the end of the previous step's host read to
-      the end of this step's dispatch call, the waits for work or
-      capacity in between taken out (``wait_s``): the stretch in which
-      this thread, not the device, sets the pace;
-    * ``admit_queue_s`` — the queue wait of each request admitted since
-      the last record.
+    * ``host_gap_s`` — from the end of the last host read (or of the
+      last dispatch call, where no read came since: a second step sent
+      behind the first) to the end of this step's dispatch call, the
+      waits for work or capacity in between taken out (``wait_s``): the
+      host work a step carries.
+      With no step in flight the device waits for all of it; with one
+      in flight, only for what exceeds that step's device time;
+    * ``admit_queue_s`` — the queue wait of each request this step's
+      plan admitted.
     """
 
-    __slots__ = ("_spans", "_cur", "_t_cur", "seconds", "_read_end",
-                 "_waited", "_gap", "admit_queue_s")
+    __slots__ = ("_spans", "_cur", "_t_cur", "seconds", "_gap_from",
+                 "_waited", "_gap", "admit_queue_s", "result_at")
 
     def __init__(self):
         from ..profiler.profiler import RecordEvent
         self._spans = tuple(RecordEvent(n) for n in _PHASE_NAMES)
         self._cur = -1                  # the running phase
         self._t_cur = None              # its start; None: not timed
-        self.seconds = None             # this step's seconds by phase
-        self._read_end = None           # end of the last host read
+        self.seconds = None             # seconds by phase, not yet taken
+        self._gap_from = None           # end of the last host read, or
+        # of a later dispatch call
         self._waited = 0.0              # waits since then
         self._gap = None                # (host_gap_s, wait_s)
         self.admit_queue_s = None
+        # when the last step's result reached the host (time.monotonic;
+        # the engine's own, for step_s: kept with the log off too)
+        self.result_at = 0.0
 
     def switch(self, phase: int) -> None:
         cur = self._cur
@@ -230,7 +245,7 @@ class _LoopPhases:
             if self._t_cur is not None:
                 self._close(cur, now)
             self._t_cur = now
-        elif self._t_cur is not None or self._read_end is not None:
+        elif self._t_cur is not None or self._gap_from is not None:
             self._forget()              # the log was switched off
         self._cur = phase
         if phase >= 0:
@@ -248,15 +263,17 @@ class _LoopPhases:
         if self.seconds is None:
             self.seconds = [0.0] * len(_PHASE_NAMES)
         self.seconds[cur] += dt
-        if cur == _DISPATCH and self._read_end is not None:
-            self._gap = (now - self._read_end - self._waited,
-                         self._waited)
+        if cur == _DISPATCH:
+            if self._gap_from is not None:
+                self._gap = (now - self._gap_from - self._waited,
+                             self._waited)
+            self._gap_from, self._waited = now, 0.0
         elif cur == _HOST_READ:
-            self._read_end, self._waited = now, 0.0
+            self._gap_from, self._waited = now, 0.0
 
     def _forget(self) -> None:
         self.drop()
-        self._read_end = self.admit_queue_s = None
+        self._gap_from = self.admit_queue_s = None
         self._waited = 0.0
 
     def admitted(self, queue_s: float) -> None:
@@ -270,27 +287,65 @@ class _LoopPhases:
         queue waits of what it admitted ride the next record."""
         self._t_cur = self.seconds = self._gap = None
 
-    def take(self):
-        """``(plan_s, prepare_s, dispatch_s, read_s, commit_s,
-        host_gap_s, wait_s, admit_queue_s)`` of the step that is about
-        to be recorded, the running phase counted up to now; all None
-        with the event log off.  What the thread does from here to the
-        next ``switch`` (the record's own write) stays under the running
-        annotation and inside the next ``host_gap_s``, and is summed
-        into no phase."""
-        if self._t_cur is None:
-            return _NO_PHASES
-        self._close(self._cur, time.perf_counter())  # noqa: PTL501 — as in switch()
-        secs, gap, queue = self.seconds, self._gap, self.admit_queue_s
-        self.drop()
-        self.admit_queue_s = None
-        out = [round(secs[i], 6) for i in range(_PLAN, _COMMIT + 1)]
+    def dispatched(self, phase: int):
+        """A step's dispatch call returned and the thread goes on to
+        ``phase``: ``(plan_s, prepare_s, dispatch_s, host_gap_s, wait_s,
+        admit_queue_s)`` of that step, for ``take`` when its result is
+        in; None with the event log off."""
+        self.switch(phase)
+        secs = self.seconds
+        if self._t_cur is None or secs is None:
+            return None
+        gap, queue = self._gap, self.admit_queue_s
+        self._gap = self.admit_queue_s = None
+        out = [round(secs[i], 6) for i in (_PLAN, _PREPARE, _DISPATCH)]
+        secs[_PLAN] = secs[_PREPARE] = secs[_DISPATCH] = 0.0
         if gap is None:
             out += [None, None]
         else:
             out += [round(gap[0], 6), round(gap[1], 6) or None]
         out.append(queue)
         return out
+
+    def take(self, front):
+        """``(plan_s, prepare_s, dispatch_s, read_s, commit_s,
+        host_gap_s, wait_s, admit_queue_s)`` of the step that is about
+        to be recorded: ``front`` is what ``dispatched`` gave at its
+        dispatch, the read and the commit are the thread's since, the
+        running phase counted up to now; all None with the event log
+        off.  What the thread does from here to the next ``switch`` (the
+        record's own write) stays under the running annotation and
+        inside the next ``host_gap_s``, and is summed into no phase."""
+        if self._t_cur is None or front is None:
+            return _NO_PHASES
+        self._close(self._cur, time.perf_counter())  # noqa: PTL501 — as in switch()
+        self._t_cur = None
+        secs = self.seconds
+        back = [round(secs[_HOST_READ], 6), round(secs[_COMMIT], 6)]
+        secs[_HOST_READ] = secs[_COMMIT] = 0.0
+        return front[:3] + back + front[3:]
+
+
+class _Flight:
+    """One step that was dispatched and whose sampled tokens the host
+    has not read: what the loop needs to read it, record it, and undo
+    it if its result never comes."""
+
+    __slots__ = ("plan", "ahead", "cold", "pools_in", "key_in", "span",
+                 "bracket_t0", "call_at", "nxt", "front")
+
+    def __init__(self, plan, ahead: bool):
+        self.plan = plan
+        self.ahead = ahead      # dispatched while the step before it
+                                # was unread
+        self.cold = False       # its program compiled in its dispatch
+        self.pools_in = self.key_in = None  # what its program consumed
+        self.span = _tracing.NOOP_SPAN      # its batch_step trace span
+        self.bracket_t0 = self.call_at = 0.0    # time.monotonic: its
+        # preparation's start (the watchdog's bracket) and its
+        # program's call
+        self.nxt = None         # its sampled row, on the device
+        self.front = None       # _LoopPhases.dispatched()
 
 
 class ServingEngine:
@@ -392,6 +447,11 @@ class ServingEngine:
             else int(eos_token_id)
         self._pools = self._new_pools()
         self._key = jax.random.PRNGKey(int(seed))
+        # what a step's program takes as ``prev`` when no step before
+        # it is unread: the shape of a step's sampled row (the routing
+        # counts behind it where a model routes), on the device once
+        self._no_prev = jax.numpy.zeros(
+            (int(max_batch) + (3 if self._routed else 0),), "int32")
         self._programs: dict = {}
         self.engine_id = str(next(_ENGINE_SEQ))
         eid = self.engine_id
@@ -444,6 +504,10 @@ class ServingEngine:
         self._n_quarantined = 0
         self._n_cancelled = 0
         self._wedged_threads = 0
+        # steps dispatched while the step before them was unread, and
+        # steps dispatched with nothing unread (a fused window is one)
+        self._n_ahead = 0
+        self._n_drained = 0
 
     def _new_pools(self):
         """Zeroed device pools of the step's own geometry."""
@@ -626,108 +690,145 @@ class ServingEngine:
         finally:
             phases.stop()
 
-    def _loop_body(self, epoch: int, phases: _LoopPhases):
+    def _may_run_ahead(self) -> bool:
+        """Whether the loop may plan and dispatch the next step while
+        the last one's tokens are still on the device (under ``_wake``).
+        It may not while a failure is being contained or provoked — a
+        bisection episode probes one plan at a time, a pinned poison
+        fails every plan that holds its request, and a replica that is
+        not ``ok`` is being watched step by step — nor where decode-only
+        plans go to the fused window, which reads its first tokens on
+        the host.  The loop then reads and commits the unread step
+        first and plans as a loop that never ran ahead would."""
+        return self.health == "ok" and not self.scheduler.bisect_groups \
+            and not self._poison and self._fused_max() <= 1
+
+    def _fused_max(self) -> int:
+        """``FLAGS_serving_fused_steps`` where the model's step can run
+        as a fused window, else 1."""
         from ..flags import get_flag
+        return int(get_flag("serving_fused_steps") or 1) \
+            if self._fusable else 1
+
+    def _loop_body(self, epoch: int, phases: _LoopPhases):
+        """The loop keeps ONE step unread behind the step it dispatches:
+
+            dispatch(N) . plan(N+1) . dispatch(N+1) . read(N) . commit(N)
+                        . plan(N+2) . dispatch(N+2) . read(N+1) . ...
+
+        so that when the device ends step N, step N+1 is queued behind
+        it and the host's commit, record, plan and dispatch run under
+        the device's step.  Step N+1 is planned before N's tokens are
+        on the host (``Scheduler.plan_step(unread)``) and takes them on
+        the device (``prev`` / ``take`` of ``_program``).  Where that is
+        unsafe or pointless (``_may_run_ahead``, a plan that would have
+        to evict, nothing runnable) the loop lands the unread step
+        first — reads it, commits it — and plans again."""
+        flight: Optional[_Flight] = None    # dispatched, not read yet
         while True:
             # the wait for _wake counts as planning: it is where the
             # client threads contend with this one
             phases.switch(_PLAN)
+            plan = None
+            fused_w, fused_max, fused_reason = 1, 0, "single_step"
             with self._wake:
                 if not self._running or epoch != self._epoch:
+                    if flight is not None:
+                        # stopped or superseded: whoever finished the
+                        # requests left nothing for this result to reach
+                        flight.span.end(status="cancelled")
                     return
                 self._sweep_deadlines_locked()
-                if not self.scheduler.has_work():
+                if flight is not None and not self._may_run_ahead():
+                    pass                # land it, then plan
+                elif self.scheduler.has_work():
+                    plan, admitted, evicted = self.scheduler.plan_step(
+                        flight.plan if flight is not None else None)
+                    self._note_plan_locked(admitted, evicted, phases)
+                elif flight is None:
                     phases.switch(_IDLE_WAIT)
                     self._wake.wait(0.05)
                     continue
-                plan, admitted, evicted = self.scheduler.plan_step()
-                now = time.monotonic()
-                for seq in admitted:
-                    req = seq.req
-                    queue_s = round(now - req.submitted_at, 6)
-                    phases.admitted(queue_s)
-                    qs, req._queue_span = req._queue_span, None
-                    if qs is not None:
-                        # queue-wait over: prefix-cache hit + resume
-                        # facts land on the closing span
-                        qs.end(cached_tokens=seq.cached_tokens,
-                               resumed=req.evictions > 0)
-                    tr = req.trace
-                    _events.emit(
-                        "serving_admit", request=req.id,
-                        prompt_len=len(req.prompt),
-                        cached_tokens=seq.cached_tokens,
-                        queue_s=queue_s,
-                        resumed=req.evictions > 0,
-                        predicted_cost_s=(
-                            round(seq.predicted_cost_s, 6)
-                            if seq.predicted_cost_s is not None
-                            else None),
-                        trace_id=tr.trace_id if tr else None,
-                        span=tr.span_id if tr else None)
-                for seq in evicted:
-                    self._c_evict.inc()
-                    req = seq.req
-                    tr = req.trace
-                    _events.emit(
-                        "evict", request=req.id,
-                        kv_len=len(seq.tokens),
-                        n_generated=seq.n_generated,
-                        reason="page_exhaustion",
-                        trace_id=tr.trace_id if tr else None,
-                        span=tr.span_id if tr else None)
-                    if tr is not None and req._queue_span is None:
-                        # requeued: a fresh queue-wait span opens under
-                        # the same root until re-admission
-                        req._queue_span = _tracing.start_span(
-                            "queue", parent=tr,
-                            attrs={"resumed": True})
-                self._g_queue.set(self.scheduler.queue_depth())
-                self._g_occ.set(len(self.scheduler.running))
                 # fused-window eligibility: pure steady-state decode
                 # only (no prefill chunk, Q == 1).  window_budget then
                 # clamps N to what the pool can host WITHOUT eviction
                 # and pre-allocates the window's pages; W == 1 keeps
                 # the single-step path — including all of its eviction
                 # machinery — byte for byte
-                fused_w, fused_max, fused_reason = 1, 0, "single_step"
                 if plan is not None and plan.n_prefill == 0 \
-                        and plan.q_width == 1 and self._fusable \
+                        and plan.q_width == 1 \
                         and not self.scheduler.bisect_groups:
                     # (a bisection episode pins the single-step path:
-                    # probe batches must fail one iteration at a time)
-                    fused_max = int(get_flag("serving_fused_steps")
-                                    or 1)
+                    # probe batches must fail one iteration at a time;
+                    # with the flag above 1 no step is ever unread here)
+                    fused_max = self._fused_max()
                     if fused_max > 1:
                         fused_w, fused_reason = \
                             self.scheduler.window_budget(plan,
                                                          fused_max)
-            if plan is None:
+            if plan is None and flight is None:
                 # runnable work exists but no pages/slots right now
                 # (e.g. the queue head cannot fit until a decode
                 # finishes) — yield briefly instead of spinning
                 phases.switch(_NO_CAPACITY_WAIT)
                 time.sleep(0.005)
                 continue
-            phases.switch(_PREPARE)
-            with self._lock:
-                if epoch != self._epoch:
-                    return
-                # watchdog bracket: the dispatch about to start is
-                # bounded by FLAGS_serving_step_timeout_s from here
-                self._dispatch_t0 = time.monotonic()
-                self._dispatch_plan = plan
-                self._dispatch_cold = False
+            landing: Optional[_Flight] = None
+            nxt: Optional[_Flight] = None
             try:
                 if fused_w > 1:
+                    phases.switch(_PREPARE)
+                    if self._open_bracket(plan, epoch) is None:
+                        return
                     self._run_window(plan, fused_w, fused_max,
                                      fused_reason, epoch, phases)
-                else:
-                    self._run_step(plan, epoch, phases)
+                    self._close_bracket(epoch)
+                    continue
+                if plan is not None:
+                    phases.switch(_PREPARE)
+                    nxt = _Flight(plan, ahead=flight is not None)
+                    if not self._dispatch_step(nxt, flight, epoch, phases):
+                        return          # a relaunch superseded us
+                if flight is not None:
+                    landing, flight = flight, None
+                    self._land_step(landing, nxt, epoch, phases)
+                    landing = None
+                flight = nxt
             except Exception as e:  # noqa: BLE001 — containment, not
                 # crash-out: the batch is retried by bisection and
-                # only the isolated offender fails
-                if self._dispatch_cold or self._pools[0][0].is_deleted():  # noqa: PTL902 — loop thread is the sole writer of both
+                # only the isolated offender fails.
+                # Taken as a dispatch that failed until shown otherwise:
+                # ``cold`` says whether its program was still compiling
+                # (the bracket's flag may be an unread step's)
+                failed = plan
+                cold = nxt.cold if nxt is not None else self._dispatch_cold  # noqa: PTL902 — loop thread is the sole writer
+                if landing is None and flight is not None:
+                    # the dispatch BEHIND an unread step failed.  That
+                    # step is sound: land it, then contain this one
+                    landing, flight = flight, None
+                    try:
+                        self._land_step(landing, None, epoch, phases)
+                        landing = None
+                    except Exception as e2:  # noqa: BLE001, PTL401 — not swallowed: it takes the first failure's place below
+                        e = e2
+                if landing is not None:
+                    # a READ failed: the step behind it, if any, took
+                    # its pools and tokens and goes with it.  Nothing of
+                    # either was committed, so the engine's device state
+                    # goes back to what the failed step was given
+                    failed, cold = landing.plan, landing.cold
+                    landing.span.end(status="error")
+                    if nxt is not None:
+                        nxt.span.end(status="error")
+                    with self._lock:
+                        if epoch != self._epoch:
+                            return
+                        self._pools, self._key = \
+                            landing.pools_in, landing.key_in
+                flight = None
+                phases.drop()
+                self._close_bracket(epoch)
+                if cold or self._pools[0][0].is_deleted():  # noqa: PTL902 — loop thread is the sole writer of both
                     # a PROGRAM fault, not a poisoned request: the
                     # dispatch was still tracing/compiling (a Mosaic or
                     # VMEM refusal would repeat for every request that
@@ -735,16 +836,91 @@ class ServingEngine:
                     # the pools were donated and there is nothing left
                     # to retry against.  _loop fails the engine loudly
                     # with the compiler's message on every request
-                    raise
+                    raise e
                 warnings.warn(f"serving step failed: "
                               f"{type(e).__name__}: {e}", stacklevel=1)
-                self._contain_step_failure(plan, e, epoch)
-            finally:
-                phases.drop()
-                with self._lock:
-                    if epoch == self._epoch:
-                        self._dispatch_t0 = None
-                        self._dispatch_plan = None
+                self._contain_step_failure(failed, e, epoch)
+
+    def _note_plan_locked(self, admitted, evicted,
+                          phases: _LoopPhases) -> None:
+        """The events, spans and gauges of what a plan admitted and
+        evicted."""
+        now = time.monotonic()
+        for seq in admitted:
+            req = seq.req
+            queue_s = round(now - req.submitted_at, 6)
+            phases.admitted(queue_s)
+            qs, req._queue_span = req._queue_span, None
+            if qs is not None:
+                # queue-wait over: prefix-cache hit + resume
+                # facts land on the closing span
+                qs.end(cached_tokens=seq.cached_tokens,
+                       resumed=req.evictions > 0)
+            tr = req.trace
+            _events.emit(
+                "serving_admit", request=req.id,
+                prompt_len=len(req.prompt),
+                cached_tokens=seq.cached_tokens,
+                queue_s=queue_s,
+                resumed=req.evictions > 0,
+                predicted_cost_s=(
+                    round(seq.predicted_cost_s, 6)
+                    if seq.predicted_cost_s is not None
+                    else None),
+                trace_id=tr.trace_id if tr else None,
+                span=tr.span_id if tr else None)
+        for seq in evicted:
+            self._c_evict.inc()
+            req = seq.req
+            tr = req.trace
+            _events.emit(
+                "evict", request=req.id,
+                kv_len=len(seq.tokens),
+                n_generated=seq.n_generated,
+                reason="page_exhaustion",
+                trace_id=tr.trace_id if tr else None,
+                span=tr.span_id if tr else None)
+            if tr is not None and req._queue_span is None:
+                # requeued: a fresh queue-wait span opens under
+                # the same root until re-admission
+                req._queue_span = _tracing.start_span(
+                    "queue", parent=tr,
+                    attrs={"resumed": True})
+        self._g_queue.set(self.scheduler.queue_depth())
+        self._g_occ.set(len(self.scheduler.running))
+
+    def _open_bracket(self, plan, epoch: int) -> Optional[float]:
+        """Watchdog bracket: the dispatch about to start is bounded by
+        FLAGS_serving_step_timeout_s from here.  With a step unread the
+        bracket is already open and stays that (older) step's.  Returns
+        the time (``time.monotonic``) from which this dispatch would
+        hold it; None: a relaunch superseded this thread."""
+        now = time.monotonic()
+        with self._lock:
+            if epoch != self._epoch:
+                return None
+            if self._dispatch_t0 is None:
+                self._dispatch_t0 = now
+                self._dispatch_plan = plan
+                self._dispatch_cold = False
+        return now
+
+    def _pass_bracket_locked(self, behind: Optional[_Flight]) -> None:
+        """A step's result is in (or it failed): the bracket passes to
+        the step still unread, from that step's own dispatch on, or
+        closes."""
+        if behind is None:
+            self._dispatch_t0 = self._dispatch_plan = None
+        else:
+            self._dispatch_t0 = behind.bracket_t0
+            self._dispatch_plan = behind.plan
+        # (whatever is still unread has been compiled: its call returned)
+        self._dispatch_cold = False
+
+    def _close_bracket(self, epoch: int) -> None:
+        with self._lock:
+            if epoch == self._epoch:
+                self._pass_bracket_locked(None)
 
     def _maybe_poison(self, plan):
         """Chaos hook (``serving_step@N=exc|nan``): a fired fault pins
@@ -768,48 +944,97 @@ class ServingEngine:
             lane = i                      # kind "nan": poison on device
         return lane
 
-    def _run_step(self, plan, epoch: int, phases: _LoopPhases):
-        # one SHARED step span for the whole ragged iteration, linked
-        # from every member request's trace — each request's timeline
-        # pulls its batch steps in through the links without owning
-        # them.  The span is the ambient context for the block, so the
-        # batch_step event below inherits its trace_id/span.
-        links = [{"trace_id": s.req.trace.trace_id,
-                  "span": s.req.trace.span_id}
-                 for s in plan.seqs if s.req.trace is not None]
-        with _tracing.trace_span("batch_step", links=links or None,
-                                 attrs={"engine": self.engine_id}):
-            self._run_step_traced(plan, epoch, phases)
-
-    def _run_step_traced(self, plan, epoch: int, phases: _LoopPhases):
+    def _dispatch_step(self, step: _Flight, unread: Optional[_Flight],
+                       epoch: int, phases: _LoopPhases) -> bool:
+        """Hand ``step.plan`` to the device and return without reading
+        its result.  ``unread`` is the step dispatched before it whose
+        tokens are still on the device (the plan was made against it):
+        its ``nxt`` is this program's ``prev``.  False: a watchdog
+        relaunch superseded this thread and the result is nobody's."""
+        plan = step.plan
         # snapshot the device state FIRST: if this thread stalls and
         # the watchdog relaunches around it, the zombie must keep
         # writing into the ABANDONED buffers it captured here — never
         # into the fresh epoch's pools (self._pools by then)
         pools_in, key_in = self._pools, self._key  # noqa: PTL902 — THE zombie-containment snapshot: lock-free on purpose, see comment above
-        nan_lane = self._maybe_poison(plan)
-        qw = plan.q_width
-        n_progs = len(self._programs)
-        prog = self._program(qw)
-        cold_start = len(self._programs) > n_progs
-        if cold_start:
-            self._dispatch_cold = True   # noqa: PTL902 — GIL-atomic bool, sole loop-thread writer; the watchdog tolerates one stale poll of the compile-grace flag
-        # chaos NaN injection rides a logits bias vector: 0 everywhere
-        # (jit-compiled no-op add) except the poisoned lane
-        poison = np.zeros((self.max_batch,), "float32")
-        if nan_lane is not None:
-            poison[nan_lane] = np.nan
-        phases.switch(_DISPATCH)
-        with self._h_step.time() as step_timer:
-            nxt, pools, rng = prog(
+        step.pools_in, step.key_in = pools_in, key_in
+        step.bracket_t0 = self._open_bracket(plan, epoch)
+        if step.bracket_t0 is None:
+            return False
+        # one SHARED step span for the whole ragged iteration, linked
+        # from every member request's trace — each request's timeline
+        # pulls its batch steps in through the links without owning
+        # them.  It is open from here to the step's record, which
+        # carries its trace_id/span; steps overlap, so it is no ambient
+        # context
+        links = [{"trace_id": s.req.trace.trace_id,
+                  "span": s.req.trace.span_id}
+                 for s in plan.seqs if s.req.trace is not None]
+        span = step.span = _tracing.start_span(
+            "batch_step", links=links or None,
+            attrs={"engine": self.engine_id})
+        try:
+            nan_lane = self._maybe_poison(plan)
+            n_progs = len(self._programs)
+            prog = self._program(plan.q_width)
+            if len(self._programs) > n_progs:
+                step.cold = self._dispatch_cold = True   # noqa: PTL902 — GIL-atomic bool, sole loop-thread writer; the watchdog tolerates one stale poll of the compile-grace flag
+            # chaos NaN injection rides a logits bias vector: 0
+            # everywhere (jit-compiled no-op add) except the poisoned
+            # lane
+            poison = np.zeros((self.max_batch,), "float32")
+            if nan_lane is not None:
+                poison[nan_lane] = np.nan
+            prev = self._no_prev if unread is None else unread.nxt
+            phases.switch(_DISPATCH)
+            step.call_at = time.monotonic()
+            step.nxt, pools, rng = prog(
                 self._params, plan.tok, plan.pos, pools_in, plan.page_ids,
                 plan.slots, plan.kv_lens, plan.q_lens, plan.tables,
-                plan.temps, key_in, poison)
-            phases.switch(_HOST_READ)
-            # THE boundary sync: exactly one device read per window
-            # (this path is the degenerate one-iteration window) —
-            # admission, eviction and EOS all key off it
-            toks = np.asarray(nxt)  # noqa: PTL701 — window boundary
+                plan.temps, key_in, poison, prev, plan.take)
+        except BaseException:
+            span.end(status="error")
+            raise
+        with self._lock:
+            if epoch != self._epoch:
+                span.end(status="error")
+                return False  # watchdog relaunched mid-dispatch: zombie
+                              # result — the fresh epoch re-runs the work
+            # the next dispatch takes these, whether or not this step's
+            # tokens have been read by then
+            self._pools, self._key = pools, rng
+            if step.cold:
+                # the call returned, so the program is compiled: from
+                # here the step is the device's, and the watchdog's
+                # plain budget counts from now
+                self._dispatch_cold = False
+                self._dispatch_t0 = step.bracket_t0 = time.monotonic()
+            if unread is None:
+                self._n_drained += 1
+            else:
+                self._n_ahead += 1
+        step.front = phases.dispatched(_PLAN if unread is None
+                                       else _HOST_READ)
+        return True
+
+    def _land_step(self, flight: _Flight, behind: Optional[_Flight],
+                   epoch: int, phases: _LoopPhases) -> None:
+        """Read ``flight``'s sampled tokens, commit it, emit its tokens
+        and write its record.  ``behind``: the step dispatched after it
+        and still unread, which inherits the watchdog's bracket."""
+        plan = flight.plan
+        phases.switch(_HOST_READ)
+        # THE boundary sync: exactly one device read per step —
+        # admission, eviction and EOS all key off it
+        toks = np.asarray(flight.nxt)  # noqa: PTL701 — window boundary
+        # what this step cost the loop: from its own dispatch call, or
+        # from its predecessor's result where that came later (the
+        # device was still on the predecessor), to its result.  Steps
+        # that follow each other tile the loop's time
+        now = time.monotonic()
+        step_s = now - max(flight.call_at, phases.result_at)
+        phases.result_at = now
+        self._h_step.observe(step_s)
         phases.switch(_COMMIT)
         # dispatch-stream markers: the REAL fed-token count (the
         # prefix-cache FLOPs-skip proof reads it), and the iteration
@@ -819,9 +1044,10 @@ class ServingEngine:
         _mark_op_stream("serving_host_sync", 1)
         with self._wake:
             if epoch != self._epoch:
+                flight.span.end(status="error")
                 return    # watchdog relaunched mid-dispatch: zombie
                           # result — the fresh epoch re-runs the work
-            self._pools, self._key = pools, rng
+            self._pass_bracket_locked(behind)
             self.scheduler.commit(plan)
             group = plan.bisect_group
             if group is not None:
@@ -839,7 +1065,11 @@ class ServingEngine:
             now = time.monotonic()
             for i, seq in enumerate(plan.seqs):
                 if seq.req.done:
-                    continue        # finished (stop()/error) mid-step
+                    # finished mid-step (stop(), a cancel, an error) —
+                    # or by the step before this one: a lane with an
+                    # eos_token_id was fed on as if its unread token
+                    # were not the end, and this is the row to drop
+                    continue
                 if seq.kv_len < len(seq.tokens):
                     continue        # chunked prefill still in flight
                 req = seq.req
@@ -871,10 +1101,12 @@ class ServingEngine:
                     self._cache_prompt(seq)
             self._g_occ.set(len(self.scheduler.running))
             self._emit_batch_step(
-                phases, plan, plan.n_prefill, int(qw),
-                plan.fed_prefill + plan.fed_decode, step_timer.seconds,
-                cold_start, 1, "single_step",
-                routing=toks[self.max_batch:])
+                phases.take(flight.front), plan, plan.n_prefill,
+                int(plan.q_width), plan.fed_prefill + plan.fed_decode,
+                step_s, flight.cold, 1, "single_step",
+                routing=toks[self.max_batch:], ahead=flight.ahead,
+                span=flight.span)
+        flight.span.end()
 
     def _pages_read(self, plan):
         """``(window_pages_read, full_pages_read)``: the pages the
@@ -893,10 +1125,13 @@ class ServingEngine:
         first = np.maximum(oldest, 0) // ps
         return int((last - first + 1).sum()) * n_window, full
 
-    def _emit_batch_step(self, phases: _LoopPhases, plan, prefill_seqs,
+    def _emit_batch_step(self, phase_seconds, plan, prefill_seqs,
                          q_width, tokens, step_s, cold_start,
-                         fused_steps, exit_reason, routing=()) -> None:
-        """The step's ``batch_step`` record (under ``_wake``).  step_s +
+                         fused_steps, exit_reason, routing=(),
+                         ahead=False, span=None) -> None:
+        """The step's ``batch_step`` record (under ``_wake``;
+        ``phase_seconds`` from ``_LoopPhases.take``; ``span`` the step's
+        own where no ambient one covers it).  step_s +
         page_occupancy make each record a ready-made (features, seconds)
         sample for the learned perf model (analysis.perf_features /
         tuning.learned); cold_start marks the program-cache-miss steps
@@ -905,7 +1140,7 @@ class ServingEngine:
         if not _events.enabled():
             return
         plan_s, prepare_s, dispatch_s, read_s, commit_s, host_gap_s, \
-            wait_s, admit_queue_s = phases.take()
+            wait_s, admit_queue_s = phase_seconds
         expert_rows, expert_rows_max, experts_hit = \
             (int(v) for v in routing) if len(routing) else (0, 0, 0)
         window_pages, full_pages = self._pages_read(plan)
@@ -917,6 +1152,9 @@ class ServingEngine:
                      queue_depth=self.scheduler.queue_depth(),
                      step_s=round(step_s, 6),
                      cold_start=cold_start or None,
+                     ahead=ahead or None,
+                     trace_id=span.trace_id if span is not None else None,
+                     span=span.span_id if span is not None else None,
                      page_occupancy=round(
                          1.0 - self.pool.available()
                          / max(self.pool.num_pages - 1, 1), 4),
@@ -935,7 +1173,7 @@ class ServingEngine:
                     epoch: int, phases: _LoopPhases):
         """Fused serving window: up to ``w`` decode iterations in one
         compiled dispatch (same shared batch_step span contract as
-        ``_run_step``)."""
+        ``_dispatch_step``)."""
         links = [{"trace_id": s.req.trace.trace_id,
                   "span": s.req.trace.span_id}
                  for s in plan.seqs if s.req.trace is not None]
@@ -947,10 +1185,10 @@ class ServingEngine:
 
     def _run_window_traced(self, plan, w, max_window, clamp_reason,
                            epoch: int, phases: _LoopPhases):
-        # snapshot the device state FIRST (see _run_step_traced): a
+        # snapshot the device state FIRST (see _dispatch_step): a
         # zombie thread must only ever write into these captured,
         # abandoned buffers after a watchdog relaunch
-        pools_in, key_in = self._pools, self._key  # noqa: PTL902 — zombie-containment snapshot (window path), same contract as _run_step_traced
+        pools_in, key_in = self._pools, self._key  # noqa: PTL902 — zombie-containment snapshot (window path), same contract as _dispatch_step
         # the fused program has no poison vector input, so "nan"
         # poison degrades to a pre-dispatch raise here — the failure
         # still quarantines through the same bisection (which pins the
@@ -1006,7 +1244,10 @@ class ServingEngine:
             # window — tokens, finished mask and iteration count ride
             # a single int32 array
             out = np.asarray(packed)  # noqa: PTL701 — window boundary
-        phases.switch(_COMMIT)
+        phases.result_at = time.monotonic()
+        # a window is read where it was dispatched: both halves of its
+        # record are taken here, the pre-staged plan in its plan_s
+        front = phases.dispatched(_COMMIT)
         steps = int(out[0, max_window + 1])
         fed = len(plan.seqs) * steps
         _mark_op_stream("serving_host_sync", steps)
@@ -1014,6 +1255,7 @@ class ServingEngine:
             if epoch != self._epoch:
                 return    # zombie window result after a relaunch
             self._pools, self._key = pools, rng
+            self._n_drained += 1
             self._note_clean_step_locked(steps)
             self.scheduler.commit_window(plan, steps)
             self._c_steps.inc(steps)
@@ -1041,8 +1283,9 @@ class ServingEngine:
                     self._h_latency.observe(now - req.submitted_at)
             self._g_occ.set(len(self.scheduler.running))
             self._emit_batch_step(
-                phases, plan, 0, 1, fed, step_timer.seconds, cold_start,
-                steps, "finished" if any_finished else clamp_reason)
+                phases.take(front), plan, 0, 1, fed, step_timer.seconds,
+                cold_start, steps,
+                "finished" if any_finished else clamp_reason)
 
     def _cache_prompt(self, seq):
         """Share the finished prompt's full pages through the prefix
@@ -1283,9 +1526,19 @@ class ServingEngine:
         routed = self._routed
 
         def program(params, tok, pos, pools, page_ids, slots, kv_lens,
-                    q_lens, tables, temps, rng, poison):
-            # tok, pos, page_ids, slots: [step_rows(qw, max_batch)], the
-            # plan's packed rows
+                    q_lens, tables, temps, rng, poison, prev, take):
+            # tok, pos, page_ids, slots, take: [step_rows(qw,
+            # max_batch)], the plan's packed rows.  prev: the sampled
+            # row of the step dispatched before this one, as it left
+            # that program, which the host may not have read yet; a row
+            # with take >= 0 feeds lane take's token of it.  (A lane
+            # that tripped the sentinel there holds -1: fed as token 0,
+            # and the host throws its output away when it reads the -1.)
+            # One program a width serves both forms: with nothing
+            # unread take is -1 throughout and prev a resident zero row
+            tok = jnp.where(take >= 0,
+                            jnp.maximum(prev[jnp.maximum(take, 0)], 0),
+                            tok.astype(jnp.int32))
             out = step.packed(params, tok, pos, pools, page_ids, slots,
                               kv_lens, q_lens, tables, qw)
             logits, pools = out[0], out[1]
@@ -1362,6 +1615,8 @@ class ServingEngine:
                "step_rows": self.scheduler.rows_planned,
                "step_rows_empty": self.scheduler.rows_empty,
                "prefill_waits": self.scheduler.prefill_waits,
+               "steps_ahead": self._n_ahead,  # noqa: PTL902 — advisory snapshot (see below)
+               "steps_drained": self._n_drained,  # noqa: PTL902 — advisory snapshot (see below)
                "free_pages": self.pool.available(),  # noqa: PTL902 — advisory snapshot; the handle swaps atomically at relaunch
                "programs": len(self._programs),
                "health": self.health,

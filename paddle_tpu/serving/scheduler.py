@@ -291,10 +291,14 @@ class StepPlan:
     no token (token 0, the sink page).  ``q_width`` is the attention
     width of the step's program, the power-of-two bucket of the widest
     chunk, and ``rows = step_rows(q_width, max_batch)``.
-    ``prefill_waiting`` counts the chunks the row budget held back."""
+    ``prefill_waiting`` counts the chunks the row budget held back.
+    ``take`` is ``[rows]`` too: for a row whose token the step planned
+    before this one sampled and the host has not read yet, the lane of
+    THAT step it comes from, else -1 (``tok`` holds)."""
 
     __slots__ = ("seqs", "slots_map", "tok", "pos", "page_ids", "slots",
-                 "kv_lens", "q_lens", "tables", "temps", "q_width", "rows",
+                 "take", "kv_lens", "q_lens", "tables", "temps", "q_width",
+                 "rows",
                  "n_prefill", "n_decode", "fed_prefill", "fed_decode",
                  "prefill_waiting", "bisect_group")
 
@@ -473,6 +477,28 @@ class Scheduler:
             seq.ring = None
 
     # -- predicted-cost admission ----------------------------------------
+    def _next_chunk(self, seq: _Sequence, unread: Optional[StepPlan]):
+        """``(start, tokens, lane)``: the tokens ``seq`` feeds next and
+        the position of the first.  With ``unread`` (a step dispatched
+        whose sampled tokens are still on the device) the sequence is
+        seen as it will be once that step commits: its k/v grown by the
+        step's rows, and, where the step sampled for it, one more token
+        whose value the host does not know — fed as a placeholder with
+        ``lane`` its lane in ``unread`` (-1 otherwise), unless the
+        sample is, by count, the last of its budget."""
+        start, tokens = seq.kv_len, seq.tokens
+        if unread is not None:
+            i = unread.slots_map.get(seq.req.id)
+            if i is not None and unread.seqs[i] is seq:
+                start = int(unread.kv_lens[i])
+                if start >= len(tokens):
+                    if seq.n_generated + 1 >= seq.req.max_new_tokens:
+                        return start, [], -1
+                    return start, [0], i
+        end = start + self.max_prefill_chunk if self.max_prefill_chunk \
+            else len(tokens)
+        return start, tokens[start:end], -1
+
     def _chunk_len(self, seq: _Sequence) -> int:
         n = max(len(seq.req.prompt) + len(seq.req.tokens) - seq.kv_len,
                 0)
@@ -481,7 +507,8 @@ class Scheduler:
         return n
 
     def _predicted_admit_cost(self, seq: _Sequence,
-                              projected_decode: bool = False
+                              projected_decode: bool = False,
+                              unread: Optional[StepPlan] = None
                               ) -> Optional[float]:
         """The learned model's batch-step seconds for the NEXT
         iteration with ``seq`` admitted on top of the running batch
@@ -520,7 +547,8 @@ class Scheduler:
             return None
 
     # -- admission / eviction --------------------------------------------
-    def _admit_one(self) -> Optional[_Sequence]:
+    def _admit_one(self, unread: Optional[StepPlan] = None
+                   ) -> Optional[_Sequence]:
         if not self.waiting or len(self.running) >= self.max_batch:
             return None
         seq = self.waiting[0]
@@ -532,7 +560,7 @@ class Scheduler:
                 pred = staged[1]
                 self._staged_pred = None
             else:
-                pred = self._predicted_admit_cost(seq)
+                pred = self._predicted_admit_cost(seq, unread=unread)
             seq.predicted_cost_s = pred
             if pred is not None and pred > self.max_step_cost_s \
                     and self.running:
@@ -650,7 +678,7 @@ class Scheduler:
             self.bisect_groups.popleft()
 
     # -- the per-iteration plan ------------------------------------------
-    def plan_step(self):
+    def plan_step(self, unread: Optional[StepPlan] = None):
         """Admit what fits, grow pages for this iteration's tokens
         (evicting under pressure), and lay the step's tokens out as
         packed rows.  Returns (plan, admitted, evicted) — plan is None
@@ -665,7 +693,27 @@ class Scheduler:
         step — its lane is held and feeds nothing.  A chunk is never
         cut to fit: a remainder would have a width that no earlier step
         of the deployment compiled.  The first wide chunk in running
-        order is the oldest one, so no sequence waits for ever."""
+        order is the oldest one, so no sequence waits for ever.
+
+        **One step ahead.**  ``unread`` is a plan that was dispatched
+        and whose sampled tokens the host has not read: this plan is
+        the one that follows it, made before it commits
+        (:meth:`_next_chunk`).  A lane that sampled there feeds that
+        token from the device (``take``); one whose budget that sample
+        ends feeds nothing and keeps its lane and pages until the
+        commit, so admission sees them one step later than a plan made
+        after the commit would.  A lane with an ``eos_token_id`` is fed
+        as if the unread token were not the end: if it was, this plan's
+        row for it wrote one k/v slot past the sequence's end, in a page
+        the sequence held when the row was planned, and the engine
+        drops the row's token.  (The device runs its programs in
+        dispatch order: where the commit frees that page and the next
+        plan gives it to another sequence, the dropped row's write
+        lands before the new owner's — as it already does when a
+        request is cancelled between a dispatch and its read.)  Such a
+        plan never evicts: where a
+        sequence cannot grow it returns no plan, and the caller commits
+        ``unread`` and plans again."""
         pre, self._prestage = self._prestage, None
         self._staged_pred = None
         if pre is not None:
@@ -687,25 +735,23 @@ class Scheduler:
         evicted: List[_Sequence] = []
         if group is None:
             while True:
-                seq = self._admit_one()
+                seq = self._admit_one(unread)
                 if seq is None:
                     break
                 admitted.append(seq)
 
-        # per-sequence chunk of NEW tokens this iteration
-        active: List[Tuple[_Sequence, List[int]]] = []
-        runnable = [seq for seq in self.running
+        # per-sequence chunk of NEW tokens this iteration: (seq, first
+        # position, tokens, lane of ``unread`` the token is still in)
+        active: List[Tuple[_Sequence, int, List[int], int]] = []
+        runnable = [(seq, *self._next_chunk(seq, unread))
+                    for seq in self.running
                     if group is None or seq.req.id in group]
         # (parked while the bisection probes otherwise)
-        singles = sum(1 for seq in runnable if self._chunk_len(seq) == 1)
+        singles = sum(1 for _, _, chunk, _ in runnable if len(chunk) == 1)
         widest = wide_tokens = waiting = 0
-        for seq in runnable:
+        for seq, start, chunk, lane in runnable:
             if seq not in self.running:
                 continue       # evicted by an earlier seq's growth
-            chunk = seq.tokens[seq.kv_len:]
-            if self.max_prefill_chunk and \
-                    len(chunk) > self.max_prefill_chunk:
-                chunk = chunk[:self.max_prefill_chunk]
             if not chunk:
                 continue
             if widest and len(chunk) > 1 and \
@@ -713,15 +759,19 @@ class Scheduler:
                         _bucket(max(widest, len(chunk))), self.max_batch):
                 waiting += 1
                 continue       # over the row budget: next step, whole
-            while not self._grow(seq, seq.kv_len + len(chunk)):
+            while not self._grow(seq, start + len(chunk)):
+                if unread is not None:
+                    # an eviction would requeue a sequence whose last
+                    # token is still on the device: commit first
+                    return None, admitted, evicted
                 victim = self._evict_victim(
-                    {seq} | {s for s, _ in active})
+                    {seq} | {s[0] for s in active})
                 if victim is None:
                     break
                 evicted.append(victim)
                 if victim in admitted:
                     admitted.remove(victim)
-            if self._pages_needed(seq, seq.kv_len + len(chunk)) > 0:
+            if self._pages_needed(seq, start + len(chunk)) > 0:
                 # could not grow even after evicting everything else;
                 # park this sequence too and try again next iteration
                 if seq in self.running:
@@ -732,7 +782,7 @@ class Scheduler:
                     self.waiting.appendleft(seq)
                     evicted.append(seq)
                 continue
-            active.append((seq, chunk))
+            active.append((seq, start, chunk, lane))
             if len(chunk) > 1:
                 widest = max(widest, len(chunk))
                 wide_tokens += len(chunk)
@@ -741,13 +791,14 @@ class Scheduler:
             return None, admitted, evicted
 
         b = self.max_batch
-        qw = _bucket(max(len(chunk) for _, chunk in active))
+        qw = _bucket(max(len(chunk) for _, _, chunk, _ in active))
         n_rows = step_rows(qw, b)
         ps = self.pool.page_size
         tok = np.zeros((n_rows,), "int64")
         pos = np.zeros((n_rows,), "int32")
         page_ids = np.full((n_rows,), self.pool.sink, "int32")
         slots = np.zeros((n_rows,), "int32")
+        take = np.full((n_rows,), -1, "int32")
         kv_lens = np.zeros((b,), "int32")
         q_lens = np.zeros((b,), "int32")
         tables = np.zeros((b, self.ppseq), "int32")
@@ -756,12 +807,11 @@ class Scheduler:
         n_prefill = n_decode = 0
         fed_prefill = fed_decode = 0
         row = 0
-        for i, (seq, chunk) in enumerate(active):
+        for i, (seq, start, chunk, lane) in enumerate(active):
             n = len(chunk)
-            start = seq.kv_len
             tables[i, :len(seq.pages)] = seq.pages
             if n == 1:                  # a decoding lane: no array work
-                tok[row], pos[row] = chunk[0], start
+                tok[row], pos[row], take[row] = chunk[0], start, lane
                 page_ids[row] = seq.pages[start // ps]
                 slots[row] = start % ps
             else:
@@ -787,11 +837,12 @@ class Scheduler:
         self.rows_planned += n_rows
         self.rows_empty += n_rows - row
         self.prefill_waits += waiting
-        plan = StepPlan(seqs=[s for s, _ in active],
-                        slots_map={s.req.id: i
-                                   for i, (s, _) in enumerate(active)},
+        plan = StepPlan(seqs=[s[0] for s in active],
+                        slots_map={s[0].req.id: i
+                                   for i, s in enumerate(active)},
                         tok=tok, pos=pos, page_ids=page_ids,
-                        slots=slots, kv_lens=kv_lens, q_lens=q_lens,
+                        slots=slots, take=take, kv_lens=kv_lens,
+                        q_lens=q_lens,
                         tables=tables, temps=temps, q_width=qw,
                         rows=n_rows,
                         n_prefill=n_prefill, n_decode=n_decode,

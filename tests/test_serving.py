@@ -1499,7 +1499,9 @@ def test_stop_detects_wedged_loop(gpt_model, chaos):
     — the failed join is detected, the flight recorder dumps, and the
     wedge is surfaced in stop()'s return and stats()."""
     from paddle_tpu.resilience import faults
-    faults.install_schedule("serving_step@2=stall:3")
+    # the loop dispatches step 2 before it reads step 1: the prefill's
+    # token reaches the client just before dispatch 3, the one to stall
+    faults.install_schedule("serving_step@3=stall:3")
     engine = ServingEngine(gpt_model, max_batch=2, page_size=8)
     try:
         engine.start()
@@ -1507,7 +1509,7 @@ def test_stop_detects_wedged_loop(gpt_model, chaos):
         deadline = time.monotonic() + 60
         while not req.tokens and time.monotonic() < deadline:
             time.sleep(0.02)                 # wait for prefill commit
-        assert req.tokens                    # step 2 (the stall) is next
+        assert req.tokens                    # dispatch 3 (the stall) is next
         time.sleep(0.3)                      # let the loop enter it
         st = engine.stop(drain=False, join_timeout=0.3)
     finally:

@@ -576,12 +576,19 @@ def fused_guard():
     set_flags(keep)
 
 
+def waited_ts(steps):
+    """ts of the one step that waited for work (after the pause)."""
+    return next(s["ts"] for s in steps if s.get("wait_s", 0.0) >= 0.15)
+
+
 @pytest.mark.parametrize("fused", [1, 4], ids=["single_step", "fused"])
 def test_batch_step_carries_the_loop_phases(gpt_model, obs_dir,
                                             fused_guard, fused):
-    """Every warm batch_step holds the five phase seconds; host_gap_s
-    runs from the last host read to this dispatch's end without the
-    waits for work; admit_queue_s repeats serving_admit's queue_s."""
+    """Every warm batch_step holds the five phase seconds of ITS OWN
+    plan, dispatch, read and commit, though the loop dispatches a step
+    before it reads the one before; host_gap_s runs from the last host
+    read to this dispatch's end without the waits for work;
+    admit_queue_s repeats serving_admit's queue_s."""
     from paddle_tpu.serving import ServingEngine
     set_flags({"FLAGS_serving_fused_steps": fused})
     rs = np.random.RandomState(9)
@@ -601,9 +608,27 @@ def test_batch_step_carries_the_loop_phases(gpt_model, obs_dir,
         assert any(s["fused_steps"] > 1 for s in warm)
     for s in warm:
         assert all(s[f] >= 0.0 for f in _PHASE_FIELDS), s
-        # step_s brackets the dispatch and the host read, as before
-        assert abs(s["dispatch_s"] + s["read_s"] - s["step_s"]) \
-            <= 0.2 * s["step_s"], s
+    # step_s is what the step cost the loop: from the later of its own
+    # dispatch call and its predecessor's result to its own result
+    for s in warm:
+        if s.get("ahead"):
+            # dispatched behind an unread step: its clock started when
+            # that step's result came in, so it holds its own read and
+            # no other step's device time
+            assert s["step_s"] >= s["read_s"] - 1e-4, s
+        else:
+            # nothing unread before it: the dispatch and the host read
+            # bracket it, as in a loop that never runs ahead
+            assert s["step_s"] >= s["dispatch_s"] - 1e-4, s
+    if fused == 1:
+        assert any(s.get("ahead") for s in warm)
+        # back-to-back steps tile the loop's time: between the first
+        # and the last result of a request's run of steps, the step_s
+        # of the steps in between add up to the wall time, none counted
+        # twice (an ahead step is not the sum of two device steps)
+        run = [s for s in steps[1:] if s["ts"] < waited_ts(steps)]
+        wall = run[-1]["ts"] - run[0]["ts"]
+        assert sum(s["step_s"] for s in run[1:]) <= wall + 0.05, run
     assert "host_gap_s" not in steps[0]
     for s in steps[1:]:
         if s["fused_steps"] == 1 and s["exit_reason"] == "single_step":
@@ -637,14 +662,22 @@ def test_phase_clock_is_idle_with_the_log_off(gpt_model, monkeypatch):
     req = Request([5, 6, 7, 8], max_new_tokens=2)
     engine.scheduler.submit(req)
     phases = engine_mod._LoopPhases()
+    unread = None
     for _ in range(2):
         phases.switch(engine_mod._PLAN)
-        plan, admitted, _ = engine.scheduler.plan_step()
+        plan, admitted, _ = engine.scheduler.plan_step(
+            unread.plan if unread else None)
         for seq in admitted:
             phases.admitted(0.001)
         phases.switch(engine_mod._PREPARE)
-        engine._run_step_traced(plan, engine._epoch, phases)
+        step = engine_mod._Flight(plan, ahead=unread is not None)
+        assert engine._dispatch_step(step, unread, engine._epoch, phases)
+        assert step.front is None
+        if unread is not None:
+            engine._land_step(unread, step, engine._epoch, phases)
+        unread = step
         assert phases.seconds is None and phases.admit_queue_s is None
-        assert phases.take() is engine_mod._NO_PHASES
+        assert phases.take(step.front) is engine_mod._NO_PHASES
+    engine._land_step(unread, None, engine._epoch, phases)
     phases.stop()
     assert len(req.wait(timeout=5)) == 2
