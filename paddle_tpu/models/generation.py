@@ -46,7 +46,8 @@ from ..random_state import default_generator
 
 __all__ = ["generate", "decode_loop", "build_ragged_decode_step",
            "build_fused_window_step", "AttentionKind", "FeedForwardKind",
-           "LayerDescription", "ModelDescription", "CacheDescription"]
+           "LinearAttentionKind", "LayerDescription", "ModelDescription",
+           "CacheDescription", "LaneState"]
 
 _GREEDY = ("greedy_search", "greedy")
 
@@ -435,7 +436,9 @@ class AttentionKind:
     ``rotary_dim`` dimensions of a head are rotated (0: none) from base
     ``rope_theta``, as pairs ``(i, i + rotary_dim / 2)`` or, with
     ``rope_interleaved``, ``(2i, 2i + 1)``.  ``value_scale`` multiplies
-    the value rows before the weighted sum."""
+    the value rows before the weighted sum.  ``gate``: the weighted sum
+    is multiplied, element by element and before the output projection,
+    by ``sigmoid(u wgate)`` of the layer's normed input ``u``."""
     window: Optional[int]
     kv_heads: int
     key_dim: int
@@ -445,6 +448,25 @@ class AttentionKind:
     rope_interleaved: bool = False
     sink: bool = False
     value_scale: float = 1.0
+    gate: bool = False
+
+
+@dataclass(frozen=True)
+class LinearAttentionKind:
+    """One layer's token mixer where it is no softmax attention: gated
+    delta-rule linear attention (``ops/gated_delta.py``) of ``heads``
+    heads with keys of ``key_dim`` and values of ``value_dim``, behind a
+    causal depthwise convolution over the last ``conv_kernel`` positions
+    of the q, k and v projections.  What a sequence carries through such
+    a layer is a state of ``heads x key_dim x value_dim`` and the
+    convolution's last ``conv_kernel - 1`` inputs, whatever its length.
+    ``beta_scale`` multiplies the write strength ``sigmoid(.)`` (2: the
+    state's transition may have negative eigenvalues)."""
+    heads: int
+    key_dim: int
+    value_dim: int
+    conv_kernel: int = 4
+    beta_scale: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -454,19 +476,31 @@ class FeedForwardKind:
     being "silu" or "gelu_tanh".  ``held`` None: dense; else gated
     experts behind a router over ``router_width`` of which each row
     takes ``top_k``, and of which this chip holds ``held = (first,
-    count)``."""
+    count)``; their weighted sum is multiplied by ``routed_scale``, and
+    a shared gated expert of ``shared_width`` (0: none), which every
+    row takes, is added to it unweighted."""
     width: int
     router_width: int = 0
     top_k: int = 0
     held: Optional[Tuple[int, int]] = None
     act: str = "silu"
     gated: bool = True
+    shared_width: int = 0
+    routed_scale: float = 1.0
 
 
 @dataclass(frozen=True)
 class LayerDescription:
-    attention: AttentionKind
+    """A layer mixes tokens by ``attention`` or by ``linear_attention``
+    (the other is None), then runs ``feed_forward``."""
+    attention: Optional[AttentionKind]
     feed_forward: FeedForwardKind
+    linear_attention: Optional[LinearAttentionKind] = None
+
+    def __post_init__(self):
+        if (self.attention is None) == (self.linear_attention is None):
+            raise ValueError("a layer has attention or linear attention, "
+                             "one of the two")
 
 
 @dataclass(frozen=True)
@@ -495,34 +529,60 @@ class ModelDescription:
     precision: Optional[str] = None
 
 
-class CacheDescription:
-    """The page pools a ragged step takes, layer by layer — the one
-    place the serving engine (and anything else that feeds a step)
-    learns their geometry from.  ``build_ragged_decode_step`` hangs it
-    on the step it returns (``step.cache``).
+class LaneState(tuple):
+    """A layer whose cache is a fixed-size state a running sequence: the
+    shapes of its arrays for ONE sequence (a linear-attention layer: the
+    matrix state ``(heads, key_dim, value_dim)`` and the convolution's
+    tail ``(conv_kernel - 1, channels)``).  :class:`CacheDescription`
+    takes it in a layer's place."""
 
-    A full layer's pools are ``[kv_heads, num_pages, page_size, dim]``
-    (keys ``key_dim`` wide, values ``value_dim``), shared through the
-    scheduler's ``PagePool`` and ``tables``; the last page is the sink
-    that padding rows write to.  A window layer's pools do not grow
-    with the sequence: each lane owns a **ring** of ``ring_pages()``
-    pages in ``[kv_heads, max_batch * ring + 1, page_size, dim]`` (the
-    last page again the sink), position ``p`` lives in ring entry
-    ``(p // page_size) % ring``, and a lane's ring page ids ride behind
-    its full-layer pages in ``tables`` (:meth:`tables`)."""
+
+class CacheDescription:
+    """What a ragged step keeps from step to step, layer by layer — the
+    one place the serving engine (and anything else that feeds a step)
+    learns its geometry from.  ``build_ragged_decode_step`` hangs it
+    on the step it returns (``step.cache``).  A layer's cache is of one
+    of three kinds, and ``pools`` holds a tuple of arrays a layer:
+
+    * a **full** layer's pools are ``[kv_heads, num_pages, page_size,
+      dim]`` (keys ``key_dim`` wide, values ``value_dim``), shared
+      through the scheduler's ``PagePool`` and ``tables``; the last page
+      is the sink that padding rows write to;
+    * a **window** layer's pools do not grow with the sequence: each
+      running sequence owns a **ring** of ``ring_pages()`` pages in
+      ``[kv_heads, max_batch * ring + 1, page_size, dim]`` (the last
+      page again the sink), position ``p`` lives in ring entry ``(p //
+      page_size) % ring``;
+    * a **state** layer (:class:`LaneState`) keeps float32 arrays
+      ``[max_batch, *shape]``: a fixed-size state a running sequence,
+      which does not grow with the sequence, is not paged and is never
+      shared.  The step itself zeroes a sequence's state when the
+      sequence's first row of the step is at position 0.
+
+    A running sequence owns one of ``max_batch`` **slots** (the
+    scheduler's): its ring is ring ``slot`` and its state row is row
+    ``slot``.  A step learns the slots from ``tables`` (:meth:`tables`):
+    behind a row's full-layer page ids ride its ring's page ids and,
+    where a layer keeps state, its slot."""
 
     def __init__(self, layers):
-        # per layer: (kv_heads, key_dim, value_dim, window or None)
+        # per layer: (kv_heads, key_dim, value_dim, window or None), or
+        # a LaneState
         self.layers = tuple(
-            (int(n), int(dk), int(dv), None if w is None else int(w))
-            for n, dk, dv, w in layers)
-        windows = {w for _, _, _, w in self.layers if w is not None}
+            layer if isinstance(layer, LaneState) else
+            (int(layer[0]), int(layer[1]), int(layer[2]),
+             None if layer[3] is None else int(layer[3]))
+            for layer in layers)
+        paged = [layer for layer in self.layers
+                 if not isinstance(layer, LaneState)]
+        windows = {w for _, _, _, w in paged if w is not None}
         if len(windows) > 1:
             raise ValueError(f"window layers of unlike windows {windows} "
                              "would need rings of unlike sizes")
         self.window = windows.pop() if windows else None
-        self.n_window = sum(1 for layer in self.layers
-                            if layer[3] is not None)
+        self.n_window = sum(1 for layer in paged if layer[3] is not None)
+        self.n_full = len(paged) - self.n_window
+        self.n_state = len(self.layers) - len(paged)
 
     def ring_pages(self, page_size: int, max_chunk: int) -> int:
         """Pages of one lane's ring: the window, the widest chunk a step
@@ -535,7 +595,12 @@ class CacheDescription:
     def pool_shapes(self, num_pages: int, page_size: int, max_batch: int,
                     ring_pages: int = 0):
         out = []
-        for nkv, dk, dv, window in self.layers:
+        for layer in self.layers:
+            if isinstance(layer, LaneState):
+                out.append(tuple((int(max_batch), *shape)
+                                 for shape in layer))
+                continue
+            nkv, dk, dv, window = layer
             pages = int(num_pages) if window is None \
                 else int(max_batch) * int(ring_pages) + 1
             out.append(((nkv, pages, int(page_size), dk),
@@ -544,29 +609,44 @@ class CacheDescription:
 
     def new_pools(self, num_pages: int, page_size: int, dtype,
                   max_batch: int, ring_pages: int = 0):
-        """Fresh zeroed pools, one ``(k_pages, v_pages)`` pair a layer."""
+        """Fresh zeroed arrays, a tuple a layer: ``(k_pages, v_pages)``
+        in ``dtype``, or a state layer's arrays in float32."""
         return tuple(
-            (jnp.zeros(k, dtype), jnp.zeros(v, dtype))
-            for k, v in self.pool_shapes(num_pages, page_size, max_batch,
-                                         ring_pages))
+            tuple(jnp.zeros(shape, jnp.float32
+                            if isinstance(layer, LaneState) else dtype)
+                  for shape in shapes)
+            for layer, shapes in zip(
+                self.layers, self.pool_shapes(num_pages, page_size,
+                                              max_batch, ring_pages)))
 
-    @staticmethod
-    def tables(full_tables, rings, ring_pages: int):
+    def tables(self, full_tables, slots, ring_pages: int):
         """``tables`` as a step takes them: each row's full-layer page
-        ids, then the page ids of ring ``rings[row]`` (ring ``r`` owns
-        window-pool pages ``r * ring_pages ..``).  With no ring the
+        ids, then the page ids of ring ``slots[row]`` (ring ``r`` owns
+        window-pool pages ``r * ring_pages ..``), then, where a layer
+        keeps state, ``slots[row]`` itself.  With neither the
         full-layer tables themselves."""
-        full_tables = np.asarray(full_tables, "int32")
-        if not ring_pages:
-            return full_tables
-        ring = np.asarray(rings, "int32")[:, None] * np.int32(ring_pages) \
-            + np.arange(ring_pages, dtype="int32")[None, :]
-        return np.concatenate([full_tables, ring], axis=1)
+        out = [np.asarray(full_tables, "int32")]
+        slots = np.asarray(slots, "int32")[:, None]
+        if ring_pages:
+            out.append(slots * np.int32(ring_pages)
+                       + np.arange(ring_pages, dtype="int32")[None, :])
+        if self.n_state:
+            out.append(slots)
+        return out[0] if len(out) == 1 else np.concatenate(out, axis=1)
+
+    def split_slots(self, tables):
+        """:meth:`tables` less its last column inside a step:
+        ``(tables, slots)``; ``slots`` None where no layer keeps
+        state."""
+        if not self.n_state:
+            return tables, None
+        return tables[:, :-1], tables[:, -1].astype(jnp.int32)
 
     @staticmethod
     def split_tables(tables, window_pool_pages: int):
-        """:meth:`tables` undone inside a step: ``(full_tables, ring)``
-        from ``tables [B, ppseq + ring_pages]`` and the pages of a window
+        """The ring page ids undone inside a step: ``(full_tables,
+        ring)`` from ``tables [B, ppseq + ring_pages]`` (the slots
+        already off, :meth:`split_slots`) and the pages of a window
         layer's pool (``B * ring_pages + 1``, :meth:`pool_shapes`)."""
         ring_pages = (int(window_pool_pages) - 1) // tables.shape[0]
         cut = tables.shape[1] - ring_pages
@@ -732,8 +812,9 @@ def build_ragged_decode_step(model):
           -> (last_logits [B, V], pools')
 
     where ``pools`` is a per-layer tuple of ``(k_pages, v_pages)``
-    ``[nkv, P, ps, hd]`` pools shared by every sequence, as
-    ``step.cache`` (a :class:`CacheDescription`) describes them.  Each
+    ``[nkv, P, ps, hd]`` pools shared by every sequence (or of a layer's
+    per-sequence state), as ``step.cache`` (a
+    :class:`CacheDescription`) describes them.  Each
     sequence contributes ``q_lens[b]`` new tokens this step (a prefill
     chunk or one decode token, padded to the batch-wide ``Q``); their
     k/v land at ``(page_ids, slots)`` BEFORE the one-launch ragged
@@ -757,26 +838,37 @@ def build_ragged_decode_step(model):
 
     * ``model.config.description()``, a :class:`ModelDescription`: the
       norm, where positions come from, the head, the step's precision,
-      and layer by layer the attention (full or windowed, key-value
-      heads and key and value widths of the layer's own, a rotation
-      over the first ``rotary_dim`` dimensions from the layer's own
-      base, a sink or none) and the feed-forward (an MLP, gated, or
-      routed experts of which this chip holds some);
+      and layer by layer the token mixer — attention (full or windowed,
+      key-value heads and key and value widths of the layer's own, a
+      rotation over the first ``rotary_dim`` dimensions from the layer's
+      own base, a sink or none, an output gate or none) or gated
+      delta-rule linear attention (:class:`LinearAttentionKind`) — and
+      the feed-forward (an MLP, gated, or routed experts of which this
+      chip holds some, with a shared expert beside them or none);
     * ``model.described_params()``, the tree of arrays the one body
       below reads — ``embed``, ``positions`` (a learned table),
       ``rope`` (``{_rope_key(theta): (cos, sin)}``), ``norm_w`` /
       ``norm_b``, ``lm_w`` (an untied head) and ``layers``, each with
       ``ln1_w`` / ``ln1_b``, the projection as :func:`_qkv_rows` takes
-      it, ``wo`` / ``bo``, ``sink``, ``ln2_w`` / ``ln2_b`` and
-      ``w1 b1 w2 b2`` (MLP), ``wg wu wd`` (gated; a tuple an expert
-      behind ``router_w`` / ``router_b``).  Whether a projection has a
-      bias is read from the tree: an entry that is absent or None adds
+      it, ``wgate`` (an attention layer's output gate), ``wo`` / ``bo``,
+      ``sink``, ``ln2_w`` / ``ln2_b`` and ``w1 b1 w2 b2`` (MLP), ``wg wu
+      wd`` (gated; a tuple an expert behind ``router_w`` / ``router_b``,
+      with ``shared_wg shared_wu shared_wd`` a shared expert); a
+      linear-attention layer has ``wq wk wv``, the convolutions' taps
+      ``conv_q conv_k conv_v [kernel, channels]``, the decay's ``wf_down
+      wf_up dt_bias a_log``, ``wbeta``, the gate's ``wgate_down
+      wgate_up``, ``out_norm_w`` and ``wo``
+      (:func:`_linear_attention_rows`).  Whether a projection has a bias
+      is read from the tree: an entry that is absent or None adds
       nothing.
 
     Window layers write and read a ring (:class:`CacheDescription`):
     their page ids and slots are derived here from ``pos`` and the ring
     page ids behind the full-layer pages in ``tables``;
-    ``page_ids``/``slots`` serve the full layers alone.
+    ``page_ids``/``slots`` serve the full layers alone.  A
+    linear-attention layer's entry of ``pools`` is its state and its
+    convolution's tail, a row a slot, and the last column of ``tables``
+    says which slot a sequence owns.
 
     With an expert layer (``step.routing_counts``) ``step`` returns
     ``(logits, pools', counts)``: ``counts i32[3]`` are the rows routed
@@ -800,9 +892,11 @@ def build_ragged_decode_step(model):
     cache = CacheDescription(
         [(d.attention.kv_heads, _pool_width(d.attention.key_dim),
           _pool_width(d.attention.value_dim), d.attention.window)
+         if d.attention is not None else _lane_state(d.linear_attention)
          for d in descs])
     window_layer = next((i for i, d in enumerate(descs)
-                         if d.attention.window is not None), None)
+                         if d.attention is not None
+                         and d.attention.window is not None), None)
     has_experts = any(d.feed_forward.held is not None for d in descs)
     kernel_precision = None if md.precision is None \
         else jax.lax.Precision.HIGHEST
@@ -830,6 +924,7 @@ def build_ragged_decode_step(model):
         pos = pos.astype(i32)
         if md.learned_positions:
             x = x + jnp.take(p["positions"], pos, axis=0)
+        tables, state_slots = cache.split_slots(tables)
         full_tables = tables
         if window_layer is not None:
             wpool = pools[window_layer][0]
@@ -849,35 +944,47 @@ def build_ragged_decode_step(model):
         new_pools = []
         for i, (d, lp) in enumerate(zip(descs, p["layers"])):
             att, ff = d.attention, d.feed_forward
-            dk, dv = att.key_dim, att.value_dim
-            qp, kp, vp = _qkv_rows(
-                lp, norm(x, lp["ln1_w"], lp.get("ln1_b")), nh, att)
-            if att.value_scale != 1.0:
-                vp = vp * att.value_scale
-            if att.rotary_dim:
-                cos, sin = rope[_rope_key(att.rope_theta)]
-                qp = rotated(qp, att, cos, sin)
-                kp = rotated(kp, att, cos, sin)
-            windowed = att.window is not None
-            ids, sl, tb = (ring_ids, ring_slots, ring) if windowed \
-                else (page_ids, slots, full_tables)
-            # rows as wide as the pools (_pool_width): the zeros add
-            # nothing to q.k, and the scale stays the head's own
-            qp, kp, vp = (_pad_last(a, pool.shape[-1]) for a, pool in
-                          ((qp, pools[i][0]), (kp, pools[i][0]),
-                           (vp, pools[i][1])))
-            kpg = _scatter_pages(pools[i][0], kp, ids, sl)
-            vpg = _scatter_pages(pools[i][1], vp, ids, sl)
-            new_pools.append((kpg, vpg))
-            ctx = rows.from_lanes(ragged_paged_attention(
-                rows.to_lanes(qp), kpg, vpg, kv_lens, q_lens, tb,
-                scale=1.0 / math.sqrt(dk), window=att.window,
-                sinks=lp["sink"] if att.sink else None,
-                precision=kernel_precision))
-            out = jnp.matmul(ctx[..., :dv].reshape(-1, nh * dv), lp["wo"])
-            if lp.get("bo") is not None:
-                out = out + lp["bo"]
-            x = x + out
+            u = norm(x, lp["ln1_w"], lp.get("ln1_b"))
+            if att is None:
+                out, kept = _linear_attention_rows(
+                    lp, u, d.linear_attention, pools[i], rows, state_slots,
+                    pos, eps)
+                new_pools.append(kept)
+                x = x + out
+            else:
+                dk, dv = att.key_dim, att.value_dim
+                qp, kp, vp = _qkv_rows(lp, u, nh, att)
+                if att.value_scale != 1.0:
+                    vp = vp * att.value_scale
+                if att.rotary_dim:
+                    cos, sin = rope[_rope_key(att.rope_theta)]
+                    qp = rotated(qp, att, cos, sin)
+                    kp = rotated(kp, att, cos, sin)
+                windowed = att.window is not None
+                ids, sl, tb = (ring_ids, ring_slots, ring) if windowed \
+                    else (page_ids, slots, full_tables)
+                # rows as wide as the pools (_pool_width): the zeros add
+                # nothing to q.k, and the scale stays the head's own
+                qp, kp, vp = (_pad_last(a, pool.shape[-1]) for a, pool in
+                              ((qp, pools[i][0]), (kp, pools[i][0]),
+                               (vp, pools[i][1])))
+                kpg = _scatter_pages(pools[i][0], kp, ids, sl)
+                vpg = _scatter_pages(pools[i][1], vp, ids, sl)
+                new_pools.append((kpg, vpg))
+                ctx = rows.from_lanes(ragged_paged_attention(
+                    rows.to_lanes(qp), kpg, vpg, kv_lens, q_lens, tb,
+                    scale=1.0 / math.sqrt(dk), window=att.window,
+                    sinks=lp["sink"] if att.sink else None,
+                    precision=kernel_precision))
+                ctx = ctx[..., :dv].reshape(-1, nh * dv)
+                if att.gate:
+                    with jax.named_scope("attention_gate"):
+                        ctx = ctx * jax.nn.sigmoid(
+                            jnp.matmul(u, lp["wgate"]))
+                out = jnp.matmul(ctx, lp["wo"])
+                if lp.get("bo") is not None:
+                    out = out + lp["bo"]
+                x = x + out
             if ff.held is not None:
                 h2 = norm(x, lp["ln2_w"], lp.get("ln2_b"))
                 picks, weights = sigmoid_topk_route(
@@ -885,6 +992,14 @@ def build_ragged_decode_step(model):
                 y, n_rows = held_experts_swiglu(
                     h2, picks, weights, valid, lp["wg"], lp["wu"],
                     lp["wd"], ff.held[0])
+                if ff.routed_scale != 1.0:
+                    y = y * ff.routed_scale
+                if ff.shared_width:
+                    with jax.named_scope("shared_expert"):
+                        y = y + jnp.matmul(
+                            jax.nn.silu(jnp.matmul(h2, lp["shared_wg"]))
+                            * jnp.matmul(h2, lp["shared_wu"]),
+                            lp["shared_wd"])
                 counts = [counts[0] + jnp.sum(n_rows, dtype=i32),
                           jnp.maximum(counts[1], jnp.max(n_rows)),
                           counts[2] + jnp.sum(n_rows > 0, dtype=i32)]
@@ -935,6 +1050,84 @@ def _qkv_rows(lp, h, nh: int, att: AttentionKind):
                    for w, b in (("wq", "bq"), ("wk", "bk"), ("wv", "bv")))
     return (q.reshape(-1, nh, dk), k.reshape(-1, nkv, dk),
             v.reshape(-1, nkv, dv))
+
+
+def _lane_state(kind: LinearAttentionKind) -> LaneState:
+    """What a sequence carries through a linear-attention layer: the
+    matrix state and the last ``conv_kernel - 1`` rows of the q, k and v
+    projections side by side."""
+    return LaneState((
+        (kind.heads, kind.key_dim, kind.value_dim),
+        (kind.conv_kernel - 1,
+         kind.heads * (2 * kind.key_dim + kind.value_dim))))
+
+
+# rows a block of the chunked gated delta rule (ops/gated_delta.py)
+_SCAN_BLOCK = 64
+
+
+def _linear_attention_rows(lp, u, kind: LinearAttentionKind, kept, rows,
+                           slots, pos, eps: float):
+    """One linear-attention layer's mixer over a step's packed rows:
+    ``(out [rows, H], (state', tail'))`` from the layer's normed input
+    ``u`` and what the layer kept (``kept = (state [slots, heads, dk,
+    dv], tail [slots, K - 1, heads (2 dk + dv)])``, both float32).
+
+    A sequence that feeds one row takes the recurrence itself
+    (``linear_attn_step``), one that feeds a chunk the chunked form
+    (``linear_attn_scan``; a decode-only program has no such sequence
+    and holds no scan).  A sequence whose first row of the step is at
+    position 0 starts from zeros; one with no row, and every row that
+    carries no token, moves nothing."""
+    from ..ops import gated_delta as gd
+    from ..ops.pallas.fused_decode import reference_rms_norm
+    nh, dk, dv = kind.heads, kind.key_dim, kind.value_dim
+    state, tail = kept
+    f32 = jnp.float32
+    offs, q_lens, n = rows.offs, rows.q_lens, rows.n
+    reset = (q_lens > 0) & (pos[jnp.minimum(offs, jnp.int32(n - 1))] == 0)
+    with jax.named_scope("linear_attention"):
+        # q, k and v: projection, short convolution, silu
+        mixed, new_tail, col = [], [], 0
+        for name, width in (("q", nh * dk), ("k", nh * dk), ("v", nh * dv)):
+            a, t = gd.short_conv_rows(
+                jnp.matmul(u, lp["w" + name]).astype(f32),
+                lp["conv_" + name].astype(f32),
+                tail[:, :, col:col + width], offs, q_lens, slots, reset,
+                rows.at)
+            mixed.append(jax.nn.silu(a).reshape(n, nh, -1))
+            new_tail.append(t)
+            col += width
+        q, k, v = mixed
+        unit = lambda a: a * jax.lax.rsqrt(
+            jnp.sum(a * a, axis=-1, keepdims=True) + f32(1e-6))
+        q, k = unit(q) * f32(dk ** -0.5), unit(k)
+        g = -jnp.exp(lp["a_log"].astype(f32))[None, :, None] \
+            * jax.nn.softplus(
+                jnp.matmul(jnp.matmul(u, lp["wf_down"]), lp["wf_up"])
+                .astype(f32) + lp["dt_bias"]).reshape(n, nh, dk)
+        beta = f32(kind.beta_scale) * jax.nn.sigmoid(
+            jnp.matmul(u.astype(f32), lp["wbeta"].astype(f32),
+                       precision=jax.lax.Precision.HIGHEST))
+        beta = jnp.where(rows.valid[:, None], beta, 0.0)
+        with jax.named_scope("linear_attn_step"):
+            o_slot, state = gd.gated_delta_step(
+                state, q, k, v, g, beta, offs, q_lens, slots, reset)
+        o = o_slot[slots[rows.lane]]
+        if rows.q_width > 1:
+            with jax.named_scope("linear_attn_scan"):
+                o_chunk, state = gd.gated_delta_chunks(
+                    state, q, k, v, g, beta, offs, q_lens, slots,
+                    rows.lane, rows.at, min(_SCAN_BLOCK, rows.q_width))
+            o = jnp.where((q_lens[rows.lane] > 1)[:, None, None],
+                          o_chunk, o)
+        # a norm over each head's values, then the output gate
+        o = reference_rms_norm(o, lp["out_norm_w"].astype(f32), eps)
+        gate = jnp.matmul(jnp.matmul(u, lp["wgate_down"]),
+                          lp["wgate_up"])
+        o = o.reshape(n, nh * dv).astype(u.dtype) * jax.nn.sigmoid(gate)
+        out = jnp.matmul(o, lp["wo"])
+    return out, (state, jnp.concatenate(new_tail, axis=-1))
 
 
 def _pool_width(dim: int) -> int:
@@ -999,15 +1192,18 @@ def build_fused_window_step(model, max_window: int):
     from ..ops.pallas.ragged_paged_attention import append_positions
 
     params, step = build_ragged_decode_step(model)
-    if step.cache.window is not None or step.routing_counts:
+    if step.cache.window is not None or step.cache.n_state \
+            or step.routing_counts:
         raise TypeError(
             f"build_fused_window_step does not take "
             f"{type(model).__name__}: the fused window derives one "
             f"append cursor a lane from tables, so it can fill no ring "
-            f"of a window layer (step.cache.window = "
-            f"{step.cache.window}), and carries no routing counts "
-            f"(step.routing_counts = {step.routing_counts}); serve such "
-            f"a model with FLAGS_serving_fused_steps=1")
+            f"of a window layer and reach no lane's state "
+            f"(step.cache.window = {step.cache.window}, "
+            f"step.cache.n_state = {step.cache.n_state}), and carries no "
+            f"routing counts (step.routing_counts = "
+            f"{step.routing_counts}); serve such a model with "
+            f"FLAGS_serving_fused_steps=1")
 
     def fused_window(params, tok, pools, kv_lens, live, tables, temps,
                      eos_ids, budgets, key, n_steps):

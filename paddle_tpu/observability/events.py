@@ -127,7 +127,8 @@ EVENT_SCHEMA: Dict[str, Dict[str, str]] = {
                    "wait_s": "float", "admit_queue_s": "object",
                    "expert_rows": "int", "expert_rows_max": "int",
                    "experts_hit": "int", "window_pages_read": "int",
-                   "full_pages_read": "int"},
+                   "full_pages_read": "int", "state_lanes": "int",
+                   "state_resets": "int", "scan_rows": "int"},
     # learned performance model lifecycle (tuning.learned): a versioned
     # model file was fitted/saved from accumulated telemetry
     "perf_model": {"action": "str", "version": "int", "heads": "object",

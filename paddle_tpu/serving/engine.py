@@ -389,27 +389,36 @@ class ServingEngine:
         # a step may write before it attends
         self._ring_pages = self._cache.ring_pages(
             ps, int(max_prefill_chunk) or max_pos)
-        if self._ring_pages and prefix_caching:
+        # layers that keep something a running sequence owns alone, a
+        # ring or a state: the sequence then holds one of max_batch slots
+        self._n_state = self._cache.n_state
+        slotted = bool(self._ring_pages or self._n_state)
+        if slotted and prefix_caching:
             raise ValueError(
-                f"{type(model).__name__} has window attention layers, "
-                f"whose cache is a ring a lane that holds only the last "
-                f"{self._cache.window} tokens and a chunk: PrefixCache "
-                f"shares PagePool pages and cannot restore a window's "
-                f"tail yet — pass prefix_caching=False")
+                f"{type(model).__name__} has layers whose cache is a "
+                f"running sequence's own ({self._cache.n_window} window "
+                f"attention layers, {self._n_state} state layers) and "
+                f"holds no earlier position: a ring keeps the last "
+                f"{self._cache.window} tokens and a chunk, a state the "
+                f"whole prefix folded into one array.  PrefixCache shares "
+                f"PagePool pages and cannot restore a ring's tail or a "
+                f"lane's state at the matched length yet — pass "
+                f"prefix_caching=False")
         # the fused window (generation.build_fused_window_step) takes
         # one append cursor a lane from tables and carries no routing
-        # counts: a model with a ring or an expert layer keeps the
-        # single-step path, and asking for more is refused here, to the
-        # caller, not later inside the serving loop
+        # counts: a model with a ring, a state or an expert layer keeps
+        # the single-step path, and asking for more is refused here, to
+        # the caller, not later inside the serving loop
         self._routed = self._step_fn.routing_counts
-        self._fusable = not self._ring_pages and not self._routed
+        self._fusable = not slotted and not self._routed
         if not self._fusable and int(get_flag("serving_fused_steps")
                                      or 1) > 1:
             raise ValueError(
                 f"FLAGS_serving_fused_steps="
                 f"{get_flag('serving_fused_steps')} with "
                 f"{type(model).__name__}: the fused window takes no model "
-                f"with window attention or expert layers — set it to 1")
+                f"with window attention, state or expert layers — set it "
+                f"to 1")
         self.pool = PagePool(num_pages, ps)
         self.prefix_cache = PrefixCache(self.pool) if prefix_caching \
             else None
@@ -441,7 +450,8 @@ class ServingEngine:
             max_prefill_chunk=max_prefill_chunk,
             max_seq_len=max_pos, perf_model=perf_model,
             max_step_cost_s=max_step_cost_s,
-            ring_pages=self._ring_pages)
+            ring_pages=self._ring_pages,
+            lane_tables=self._cache.tables if slotted else None)
         self.max_batch = int(max_batch)
         self.default_eos = None if eos_token_id is None \
             else int(eos_token_id)
@@ -508,9 +518,11 @@ class ServingEngine:
         # steps dispatched with nothing unread (a fused window is one)
         self._n_ahead = 0
         self._n_drained = 0
+        # what the steps asked of the state layers (_state_counts)
+        self._state_lanes = self._state_resets = self._scan_rows = 0
 
     def _new_pools(self):
-        """Zeroed device pools of the step's own geometry."""
+        """Zeroed device pools and states of the step's own geometry."""
         return self._cache.new_pools(
             self._num_pages, self._page_size, self._dtype, self.max_batch,
             self._ring_pages)
@@ -659,9 +671,14 @@ class ServingEngine:
             self._wake.notify()
         return req
 
-    def generate(self, input_ids, **kw):
-        """Synchronous convenience: submit + wait."""
-        return self.submit(input_ids, **kw).wait()
+    def generate(self, input_ids, timeout: Optional[float] = 600.0, **kw):
+        """Synchronous convenience: submit + wait.  The wait covers a
+        request whose programs are still to compile (a width's first
+        request compiles the prefill program and the decode-only one:
+        over a minute together, cold, for a model of eight layers with
+        six scans each, where ``Request.wait``'s own 60 s cancelled the
+        request and failed the caller)."""
+        return self.submit(input_ids, **kw).wait(timeout)
 
     # -- the iteration loop ----------------------------------------------
     def _loop(self, epoch: int):
@@ -1062,6 +1079,10 @@ class ServingEngine:
             self._c_steps.inc()
             self._c_dispatch.inc()
             self._c_prefill.inc(plan.fed_prefill)
+            state = self._state_counts(plan)
+            self._state_lanes += state[0]
+            self._state_resets += state[1]
+            self._scan_rows += state[2]
             now = time.monotonic()
             for i, seq in enumerate(plan.seqs):
                 if seq.req.done:
@@ -1105,8 +1126,22 @@ class ServingEngine:
                 int(plan.q_width), plan.fed_prefill + plan.fed_decode,
                 step_s, flight.cold, 1, "single_step",
                 routing=toks[self.max_batch:], ahead=flight.ahead,
-                span=flight.span)
+                span=flight.span, state=state)
         flight.span.end()
+
+    def _state_counts(self, plan):
+        """``(state_lanes, state_resets, scan_rows)``: the lanes whose
+        state this step read and wrote, those among them that started at
+        position 0 (the step zeroed their state), and the rows that went
+        through the chunked form (lanes that fed more than one), from
+        the plan's lengths alone; zeros for a model with no state
+        layer."""
+        if not self._n_state:
+            return 0, 0, 0
+        q = plan.q_lens
+        return (int((q > 0).sum()),
+                int(((q > 0) & (plan.kv_lens == q)).sum()),
+                int(q[q > 1].sum()))
 
     def _pages_read(self, plan):
         """``(window_pages_read, full_pages_read)``: the pages the
@@ -1118,7 +1153,7 @@ class ServingEngine:
         ps = self._page_size
         last = (kv - 1) // ps
         n_window = self._cache.n_window
-        full = int((last + 1).sum()) * (len(self._cache.layers) - n_window)
+        full = int((last + 1).sum()) * self._cache.n_full
         if not n_window:
             return 0, full
         oldest = kv - plan.q_lens[live] - (self._cache.window - 1)
@@ -1128,7 +1163,7 @@ class ServingEngine:
     def _emit_batch_step(self, phase_seconds, plan, prefill_seqs,
                          q_width, tokens, step_s, cold_start,
                          fused_steps, exit_reason, routing=(),
-                         ahead=False, span=None) -> None:
+                         ahead=False, span=None, state=(0, 0, 0)) -> None:
         """The step's ``batch_step`` record (under ``_wake``;
         ``phase_seconds`` from ``_LoopPhases.take``; ``span`` the step's
         own where no ambient one covers it).  step_s +
@@ -1167,7 +1202,9 @@ class ServingEngine:
                      expert_rows_max=expert_rows_max,
                      experts_hit=experts_hit,
                      window_pages_read=window_pages,
-                     full_pages_read=full_pages)
+                     full_pages_read=full_pages,
+                     state_lanes=state[0], state_resets=state[1],
+                     scan_rows=state[2])
 
     def _run_window(self, plan, w, max_window, clamp_reason,
                     epoch: int, phases: _LoopPhases):
@@ -1617,6 +1654,9 @@ class ServingEngine:
                "prefill_waits": self.scheduler.prefill_waits,
                "steps_ahead": self._n_ahead,  # noqa: PTL902 — advisory snapshot (see below)
                "steps_drained": self._n_drained,  # noqa: PTL902 — advisory snapshot (see below)
+               "state_lanes": self._state_lanes,  # noqa: PTL902 — advisory snapshot (see below)
+               "state_resets": self._state_resets,  # noqa: PTL902 — advisory snapshot (see below)
+               "scan_rows": self._scan_rows,  # noqa: PTL902 — advisory snapshot (see below)
                "free_pages": self.pool.available(),  # noqa: PTL902 — advisory snapshot; the handle swaps atomically at relaunch
                "programs": len(self._programs),
                "health": self.health,

@@ -261,7 +261,7 @@ class _Sequence:
 
     __slots__ = ("req", "tokens", "kv_len", "pages", "shared",
                  "cached_tokens", "cache_inserted", "predicted_cost_s",
-                 "ring")
+                 "slot")
 
     def __init__(self, req: Request):
         self.req = req
@@ -274,9 +274,11 @@ class _Sequence:
         # learned-model step-cost estimate at admission (None: raw
         # page/token caps decided alone); rides serving_admit events
         self.predicted_cost_s: Optional[float] = None
-        # the ring this sequence's window layers write while it runs
-        # (Scheduler.ring_pages > 0); None while it waits
-        self.ring: Optional[int] = None
+        # the slot this sequence owns while it runs, of max_batch: its
+        # window layers write ring ``slot`` and its state layers row
+        # ``slot`` (Scheduler.lane_tables); None while it waits, and for
+        # a model that keeps neither
+        self.slot: Optional[int] = None
 
     @property
     def n_generated(self) -> int:
@@ -344,23 +346,25 @@ class Scheduler:
                  max_pages_per_seq: int, prefix_cache=None,
                  max_queue: int = 1024, max_prefill_chunk: int = 0,
                  max_seq_len: int = 0, perf_model=None,
-                 max_step_cost_s: float = 0.0, ring_pages: int = 0):
+                 max_step_cost_s: float = 0.0, ring_pages: int = 0,
+                 lane_tables=None):
         self.pool = pool
         self.max_batch = int(max_batch)
         self.ppseq = int(max_pages_per_seq)
-        # window layers (models.generation.CacheDescription): every
-        # running sequence owns one of max_batch rings of ring_pages
-        # pages in the window layers' pools, and a plan's ``tables``
-        # carry its ring's page ids behind its PagePool pages.  Rings
-        # are outside PagePool: they never run out (one a batch slot)
-        # and hold nothing once their sequence leaves, so eviction and
-        # resume re-prefill as for any model.  0: no such layer
+        # window and state layers (models.generation.CacheDescription):
+        # every running sequence owns one of max_batch slots — a ring of
+        # ring_pages pages in the window layers' pools, a row of the
+        # state layers' arrays — and a plan's ``tables`` carry what the
+        # slot holds behind the sequence's PagePool pages, laid out by
+        # ``lane_tables(tables, slots, ring_pages)``, the step's own
+        # ``CacheDescription.tables``.  Slots are outside PagePool: they
+        # never run out (one a batch lane) and what they hold means
+        # nothing once their sequence leaves (the step zeroes a state
+        # when a sequence starts at position 0), so eviction and resume
+        # re-prefill as for any model.  None: no such layer
         self.ring_pages = int(ring_pages)
-        if self.ring_pages:
-            # the rings' layout is CacheDescription's alone
-            from ..models.generation import CacheDescription
-            self._with_rings = CacheDescription.tables
-        self._free_rings = list(range(self.max_batch))[::-1]
+        self.lane_tables = lane_tables
+        self._free_slots = list(range(self.max_batch))[::-1]
         self.prefix_cache = prefix_cache
         self.max_queue = int(max_queue)
         # page capacity rounds UP to whole pages; the model's position
@@ -472,9 +476,9 @@ class Scheduler:
         seq.pages = []
         seq.shared = set()
         seq.kv_len = 0
-        if seq.ring is not None:
-            self._free_rings.append(seq.ring)
-            seq.ring = None
+        if seq.slot is not None:
+            self._free_slots.append(seq.slot)
+            seq.slot = None
 
     # -- predicted-cost admission ----------------------------------------
     def _next_chunk(self, seq: _Sequence, unread: Optional[StepPlan]):
@@ -601,8 +605,8 @@ class Scheduler:
             return None
         self.waiting.popleft()
         self.running.append(seq)
-        if self.ring_pages:
-            seq.ring = self._free_rings.pop()
+        if self.lane_tables is not None:
+            seq.slot = self._free_slots.pop()
         return seq
 
     def _evict_victim(self, protect) -> Optional[_Sequence]:
@@ -659,10 +663,11 @@ class Scheduler:
         self._prestage = None
         self._staged_pred = None
         self.bisect_groups.clear()
-        # the window layers' pools are fresh too: nobody holds a ring
-        self._free_rings = list(range(self.max_batch))[::-1]
+        # the window and state layers' arrays are fresh too: nobody
+        # holds a slot
+        self._free_slots = list(range(self.max_batch))[::-1]
         for seq in self.waiting:
-            seq.ring = None
+            seq.slot = None
 
     # -- quarantine bisection (engine fault containment) -----------------
     def bisect_push_front(self, groups) -> None:
@@ -802,7 +807,7 @@ class Scheduler:
         kv_lens = np.zeros((b,), "int32")
         q_lens = np.zeros((b,), "int32")
         tables = np.zeros((b, self.ppseq), "int32")
-        rings = np.zeros((b,), "int32")
+        lane_slots = np.zeros((b,), "int32")
         temps = np.zeros((b,), "float32")
         n_prefill = n_decode = 0
         fed_prefill = fed_decode = 0
@@ -823,8 +828,8 @@ class Scheduler:
             row += n
             kv_lens[i] = start + n
             q_lens[i] = n
-            if self.ring_pages:
-                rings[i] = seq.ring
+            if self.lane_tables is not None:
+                lane_slots[i] = seq.slot
             temps[i] = seq.req.temperature
             if start < len(seq.req.prompt):     # still eating prompt
                 n_prefill += 1
@@ -832,8 +837,8 @@ class Scheduler:
             else:
                 n_decode += 1
                 fed_decode += n
-        if self.ring_pages:
-            tables = self._with_rings(tables, rings, self.ring_pages)
+        if self.lane_tables is not None:
+            tables = self.lane_tables(tables, lane_slots, self.ring_pages)
         self.rows_planned += n_rows
         self.rows_empty += n_rows - row
         self.prefill_waits += waiting

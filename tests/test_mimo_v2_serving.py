@@ -215,7 +215,7 @@ def test_eviction_and_resume_stay_token_exact_with_a_window_model(model,
     assert engine.scheduler.evictions >= 1
     assert got == want
     assert engine.pool.available() == engine.pool.num_pages - 1
-    assert sorted(engine.scheduler._free_rings) == [0, 1, 2]
+    assert sorted(engine.scheduler._free_slots) == [0, 1, 2]
 
 
 def test_prefix_caching_with_a_window_model_raises_with_the_reason(model):

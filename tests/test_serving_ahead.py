@@ -16,6 +16,8 @@ from paddle_tpu.flags import set_flags
 from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_config
 from paddle_tpu.models.mimo_v2 import MiMoV2Config, MiMoV2ForCausalLM
+from paddle_tpu.models.solar_open2 import (SolarOpen2Config,
+                                           SolarOpen2ForCausalLM)
 from paddle_tpu.serving import Request, ServingEngine
 from paddle_tpu.serving import engine as engine_mod
 
@@ -63,9 +65,31 @@ def mimo():
     return m
 
 
+@pytest.fixture(scope="module")
+def solar():
+    """A gated GQA layer and a linear-attention layer (a state a lane,
+    zeroed by the step when a sequence starts), routed experts beside a
+    shared one, as ``tests/test_solar_open2_serving.py`` builds it."""
+    paddle.seed(11)
+    m = SolarOpen2ForCausalLM(SolarOpen2Config(
+        vocab_size=96, hidden_size=64, num_hidden_layers=2, gqa_layers=[0],
+        num_heads=4, num_kv_heads=2, head_dim=16, linear_num_heads=4,
+        linear_head_dim=16, linear_low_rank=16, moe_intermediate_size=32,
+        n_routed_experts=16, num_experts_per_tok=2, held_experts=(4, 4),
+        max_position_embeddings=128))
+    rs = np.random.RandomState(2)
+    for blk in m.blocks:
+        blk.router_b.set_value(rs.uniform(-.3, .3, blk.router_b.shape)
+                               .astype("float32"))
+    m.seed_decays(rs)
+    m.eval()
+    return m
+
+
 @pytest.fixture
-def family(request, gpt, llama, mimo):
-    return {"gpt": gpt, "llama": llama, "mimo": mimo}[request.param]
+def family(request, gpt, llama, mimo, solar):
+    return {"gpt": gpt, "llama": llama, "mimo": mimo,
+            "solar": solar}[request.param]
 
 
 @pytest.fixture
@@ -130,7 +154,8 @@ def _prompts(model, lengths, seed=5):
 # the same tokens
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("family", ["gpt", "llama", "mimo"], indirect=True)
+@pytest.mark.parametrize("family", ["gpt", "llama", "mimo", "solar"],
+                         indirect=True)
 @pytest.mark.parametrize("temperature", [0.0, 0.8],
                          ids=["greedy", "sampled"])
 def test_tokens_equal_ahead_and_drained(family, temperature):
@@ -216,7 +241,7 @@ def test_a_budget_ends_at_exactly_max_new_tokens(gpt, n_new):
     assert engine.pool.available() == engine.pool.num_pages - 1
 
 
-@pytest.mark.parametrize("family", ["gpt", "mimo"], indirect=True)
+@pytest.mark.parametrize("family", ["gpt", "mimo", "solar"], indirect=True)
 def test_page_pressure_drains_evicts_and_keeps_the_tokens(family):
     """A plan made ahead never evicts: where a sequence cannot grow the
     loop commits the unread step and plans again, and that plan evicts

@@ -197,6 +197,100 @@ def test_q1024_program_multiplies_packed_rows_and_copies_no_pool():
     assert out["1024"]["temp_bytes"] < 3 * out["1024"]["pool_bytes"], out
 
 
+_AOT_STATE_LAYER = """
+import json
+import os
+import re
+import types
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+import jax
+import jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+import chip_smoke
+from paddle_tpu.models.generation import build_ragged_decode_step
+from paddle_tpu.models.solar_open2 import SolarOpen2Config
+from paddle_tpu.serving import ServingEngine
+jax.config.update("jax_enable_compilation_cache", False)
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print("NO_TOPOLOGY " + repr(e))
+    raise SystemExit(0)
+one_chip = SingleDeviceSharding(topo.devices[0])
+# one linear-attention layer at the published widths (64 heads of 128,
+# hidden 4096) with one held expert of 320 behind a small vocabulary;
+# the parameters are shapes, nothing of their size is allocated here
+cfg = SolarOpen2Config(vocab_size=2048, num_hidden_layers=1, gqa_layers=[],
+                       held_experts=(0, 1), max_position_embeddings=4096)
+h, nk, r = 4096, 8192, 128
+layer = {"ln1_w": (h,), "wq": (h, nk), "wk": (h, nk), "wv": (h, nk),
+         "conv_q": (4, nk), "conv_k": (4, nk), "conv_v": (4, nk),
+         "wf_down": (h, r), "wf_up": (r, nk), "dt_bias": (nk,),
+         "a_log": (64,), "wbeta": (h, 64), "wgate_down": (h, r),
+         "wgate_up": (r, nk), "out_norm_w": (128,), "wo": (nk, h),
+         "ln2_w": (h,), "router_w": (h, 320), "router_b": (320,),
+         "wg": ((h, 1280),), "wu": ((h, 1280),), "wd": ((1280, h),),
+         "shared_wg": (h, 1280), "shared_wu": (h, 1280),
+         "shared_wd": (1280, h)}
+shapes = {"embed": (2048, h), "norm_w": (h,), "lm_w": (2048, h),
+          "layers": [layer]}
+is_shape = lambda x: isinstance(x, tuple) and all(
+    isinstance(v, int) for v in x)
+sds = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+    shape, dtype, sharding=one_chip)
+params = jax.tree.map(sds, shapes, is_leaf=is_shape)
+stub = types.SimpleNamespace(config=cfg, described_params=lambda: None)
+engine = ServingEngine(
+    types.SimpleNamespace(
+        config=cfg,
+        build_ragged_decode_step=lambda: build_ragged_decode_step(stub)),
+    max_batch=8, page_size=16, num_pages=9, max_prefill_chunk=128,
+    prefix_caching=False)
+state = engine._pools[0][0]
+# the chip's routes for a program that is compiled here and run nowhere
+jax.default_backend = lambda: "tpu"
+out = {"state_shape": list(state.shape)}
+for qw in (1, 128):
+    args = list(chip_smoke._engine_program_args(engine, qw, one_chip))
+    args[0] = params
+    args[8] = sds((8, engine.scheduler.ppseq + 1), jnp.int32)   # + slot
+    compiled = engine._program(qw).lower(*args).compile()
+    text = compiled.as_text()
+    ma = compiled.memory_analysis()
+    out[qw] = {"state_copies": chip_smoke._pool_copies(text, state),
+               "whiles": len(re.findall(r" while\\(", text)),
+               "temp_bytes": ma.temp_size_in_bytes,
+               "alias_bytes": ma.alias_size_in_bytes,
+               "state_bytes": state.size * 4}
+print("RESULT " + json.dumps(out))
+"""
+
+
+def test_state_layer_compiles_for_v5e_and_updates_the_state_in_place():
+    """The engine's own programs for one linear-attention layer at the
+    published widths (a state of 8 x 64 x 128 x 128 float32, donated),
+    compiled ahead of time for a v5e: neither the decode-only program
+    (the one-token update) nor a prefill program (the chunked scan,
+    whose loop carries the state) holds a layout copy of the state, both
+    hand the donated state back in its own buffer, and the decode-only
+    program's temporaries stay under one state array."""
+    proc = _run(["-c", _AOT_STATE_LAYER], env={"JAX_PLATFORMS": "cpu"})
+    lines = proc.stdout.strip().splitlines()
+    if lines and lines[-1].startswith("NO_TOPOLOGY"):
+        pytest.skip(f"no v5e topology can be described here: {lines[-1]}")
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+    out = json.loads(lines[-1].removeprefix("RESULT "))
+    assert out["state_shape"] == [8, 64, 128, 128]
+    for qw in ("1", "128"):
+        assert out[qw]["state_copies"] == 0, out
+        # the state and the convolution's tail come back in place
+        assert out[qw]["alias_bytes"] >= out[qw]["state_bytes"], out
+    assert out["1"]["whiles"] < out["128"]["whiles"], out   # no scan at Q=1
+    assert out["1"]["temp_bytes"] < out["1"]["state_bytes"], out
+
+
 _REPORT_CACHE_DIR = """
 import jax
 import paddle_tpu
