@@ -112,8 +112,9 @@ EVENT_SCHEMA: Dict[str, Dict[str, str]] = {
     # are the loop thread's perf_counter seconds under the engine:*
     # profiler annotations of the same names (serving/engine.py).  The
     # routing counts come back behind the sampled tokens in the step's
-    # one host read; the pages read are host arithmetic on the plan's
-    # lengths; all five read 0 for a model with no experts or windows
+    # one host read; the pages read and the key blocks walked are host
+    # arithmetic on the plan's lengths; the first five read 0 for a model
+    # with no experts or windows
     "batch_step": {"batch": "int", "prefill_seqs": "int",
                    "decode_seqs": "int", "q_width": "int",
                    "tokens": "int", "rows": "int",
@@ -127,7 +128,8 @@ EVENT_SCHEMA: Dict[str, Dict[str, str]] = {
                    "wait_s": "float", "admit_queue_s": "object",
                    "expert_rows": "int", "expert_rows_max": "int",
                    "experts_hit": "int", "window_pages_read": "int",
-                   "full_pages_read": "int", "state_lanes": "int",
+                   "full_pages_read": "int", "attn_blocks": "int",
+                   "state_lanes": "int",
                    "state_resets": "int", "scan_rows": "int"},
     # learned performance model lifecycle (tuning.learned): a versioned
     # model file was fitted/saved from accumulated telemetry

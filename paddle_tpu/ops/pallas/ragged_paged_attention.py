@@ -9,16 +9,17 @@ decodes exactly one token), and each sequence's KV context is a
 different length scattered across fixed-size cache pages.  The
 reference ecosystem serves this with block_multihead_attention +
 separate prefill/decode kernels; the TPU-native shape is a single
-launch whose grid walks (sequence, page) with the per-sequence
-lengths and page tables riding as scalar-prefetch refs — the index
-maps pick each sequence's OWN pages out of the shared pool, and pages
-past a sequence's length are skipped under ``pl.when``, so the dot-
-product FLOPs of wildly different context lengths cost only their own
-pages.  The grid is static, so a step the lengths rule out still costs
-its turn: the causal walk therefore takes ``_KEYS_PER_STEP`` keys a grid
-step (``128 // page_size`` pages, each its own block of the pool), and
-its index maps stop at the last block a query tile can see, so that the
-steps past it name the block already held and copy nothing.
+launch whose grid walks (sequence, query tile) with the per-sequence
+lengths and page tables riding as scalar-prefetch refs.  The page pools
+stay in HBM, and the walk over a sequence's keys is a loop INSIDE the
+kernel: a tile copies its sequence's OWN pages, named by its row of the
+page table, a block of ``_KEYS_PER_STEP`` keys at a time into one of
+two VMEM buffers (the next block's copies start before the current
+block is waited for), from the sequence's first key to the block that
+holds the tile's last row's own position and no further — so copies and
+dot-product FLOPs of wildly different context lengths cost only their
+own pages.  The grid has no page axis: a tile of padding rows and an
+idle lane cost one grid step that starts no copy and writes zeros.
 
 Layout:
 
@@ -47,18 +48,20 @@ plain causal call exactly the program it was:
   ``p_j = exp(a_j) / (sum_visible exp(a_k) + exp(s_h))``.  In the
   kernel it is the running max's and denominator's initial state;
 * ``window W`` — key ``j`` is visible to the query at position ``i``
-  iff ``i - W < j <= i``.  The grid then walks only the
-  ``_window_pages`` pages a query tile's window can reach, starting at
-  page ``(kv_len - q_len + q0 - W + 1) // ps``, whatever the context's
-  length.  The page table handed with a window is read as a ring of
-  ``R = ppseq`` pages in which position ``p`` lives in entry
+  iff ``i - W < j <= i``.  A tile then copies only the
+  ``_window_pages`` pages its window can reach, starting at page
+  ``(kv_len - q_len + q0 - W + 1) // ps``, whatever the context's
+  length, side by side into one buffer, and attends them in ONE pass
+  (up to ``_MAX_WINDOW_KEYS`` keys; a wider window is walked in blocks
+  of that many).  The page table handed with a window is read as a
+  ring of ``R = ppseq`` pages in which position ``p`` lives in entry
   ``(p // ps) % R`` (the serving engine's window layers keep
   ``R * ps >= W + chunk`` positions a lane and no more; a table that
   holds the whole sequence is the ring that never wraps).
 
-The kernel runs online softmax across a sequence's pages (running
+The kernel runs online softmax across a sequence's blocks (running
 max / denominator / accumulator in VMEM scratch, masked probabilities
-so fully-masked pages contribute nothing), with GQA as a static
+so fully-masked blocks contribute nothing), with GQA as a static
 per-kv-head loop like ``fused_decode.attend_cache_append``.  The jnp
 reference below is the numerics oracle (fp32 logits, ``-1e30`` mask
 constant — the eager sdpa constants) and the route everywhere the
@@ -72,6 +75,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -194,9 +198,9 @@ def ragged_paged_attention_ref(q, k_pages, v_pages, kv_lens, q_lens,
 
 # The kernel asks for 64 of the v5e's 128 MiB of VMEM (Mosaic's default
 # scope is 16) and sizes the q tile to at most half of that, leaving the
-# rest to the k/v page blocks and Mosaic's own temps.  The grid's cost is
-# its steps, lanes x tiles x page steps, whether they compute or skip:
-# a tile four times as tall is a quarter of them.
+# rest to the two key/value buffers and Mosaic's own temps.  A grid step
+# is a tile: a tile four times as tall is a quarter of them, and walks
+# its keys once for four times the rows.
 _VMEM_LIMIT = 64 << 20
 _VMEM_TILE_BUDGET = _VMEM_LIMIT // 2
 _MAX_BLOCK_Q = 128
@@ -209,7 +213,7 @@ def _block_q(nh: int, hd: int, itemsize: int) -> int:
     out blocks double-buffered (4 x itemsize), the fp32 accumulator and
     the fp32 copy of q (8 bytes) over ``hd`` padded to the 128-lane
     tile, plus the lane-padded ``[rows, 1]`` max/denominator scratch
-    and the ``[rows, page]`` logits/probabilities (~6 fp32 lane rows)."""
+    and the ``[rows, keys]`` logits/probabilities (~6 fp32 lane rows)."""
     lanes = -(-hd // 128) * 128
     per_row = nh * (lanes * (4 * itemsize + 8) + 6 * 128 * 4)
     bq = _MAX_BLOCK_Q
@@ -226,50 +230,196 @@ def _window_pages(rows: int, window: int, page_size: int) -> int:
     return (span + page_size - 2) // page_size + 1
 
 
-# Keys a grid step of the causal walk attends.  A step costs its turn
-# whether it computes or skips (on a v5e ~0.26 us with one k and one v
-# block, ~1.2 us with eight of each), and a chunk of 1,024 rows over a
-# table of 512 pages was half a million steps a page at a time; 128 keys
-# are one MXU tile's width and an eighth of those steps at pages of 16.
-_KEYS_PER_STEP = 128
+# Keys a turn of the causal walk copies and attends: one block of
+# ``256 // page_size`` pages, a copy a page a pool into one of two VMEM
+# buffers.  A turn costs its copies' issue and, at "highest", the split
+# of its q rows into bfloat16 parts whatever the block's width, so wider
+# blocks are fewer of both; the last block of a walk is copied and
+# multiplied whole however few of its keys are visible, so wider blocks
+# waste more.  On a v5e (PR 32) 128 / 256 / 512 keys a turn took 70 / 74
+# / 82 us a decode call at 32 heads of 128 over contexts of 128-1,150
+# and 9.2 / 6.6 / 7.1 ms a 1,024-row call at 64 heads of 128 at
+# "highest" over 4,096.
+_KEYS_PER_STEP = 256
+# The most keys a window layer's one pass holds at once; a window that
+# reaches further is walked in blocks of this many, like a causal layer.
+_MAX_WINDOW_KEYS = 512
 
 
-def _last_block(kv_len, q_len, q0, block_q: int, keys: int):
+def _last_block(kv_len, q_len, q0, block_q: int, keys: int, xp=jnp):
     """The last block of ``keys`` positions the query tile starting at
     row ``q0`` can see: the one that holds its last row's own position,
-    inside the context; block 0 for a tile of padding rows."""
-    top = jnp.minimum(kv_len - q_len + q0 + jnp.int32(block_q), kv_len)
-    top = jnp.where(q0 < q_len, jnp.maximum(top - 1, 0), 0)
-    return top // jnp.int32(keys)
+    inside the context; block 0 for a tile of padding rows.  ``xp``:
+    ``jnp`` inside the kernel, ``numpy`` for the host's count."""
+    top = xp.minimum(kv_len - q_len + q0 + xp.int32(block_q), kv_len)
+    top = xp.where(q0 < q_len, xp.maximum(top - 1, 0), 0)
+    return top // xp.int32(keys)
 
 
-def _first_page(kv_len, q_len, q0, window: int, page_size: int):
+def _first_page(kv_len, q_len, q0, window: int, page_size: int, xp=jnp):
     """The page that holds the oldest key the query tile starting at
     row ``q0`` can see."""
-    oldest = kv_len - q_len + q0 - jnp.int32(window - 1)
-    return jnp.maximum(oldest, jnp.int32(0)) // jnp.int32(page_size)
+    oldest = kv_len - q_len + q0 - xp.int32(window - 1)
+    return xp.maximum(oldest, xp.int32(0)) // xp.int32(page_size)
 
 
-def _ragged_kernel(kv_lens_ref, q_lens_ref, tables_ref, q_ref, *rest,
-                   n_kv: int, n_rep: int, block_q: int, page_size: int,
-                   group: int, n_pages: int, scale: float, window,
+def _walk(kv_len, q_len, q0, block_q: int, page_size: int, group: int,
+          window, xp=jnp):
+    """``(first page, blocks)`` of the walk of the query tile starting at
+    row ``q0``: blocks of ``group`` pages from the page of the oldest key
+    the tile can see (page 0 without a window) to the page of its last
+    row's own position, and none for a tile of padding rows or an idle
+    lane."""
+    first = xp.int32(0) if window is None \
+        else _first_page(kv_len, q_len, q0, window, page_size, xp)
+    last = _last_block(kv_len, q_len, q0, block_q, page_size, xp)
+    return first, xp.where(q0 < q_len,
+                           (last - first) // xp.int32(group) + 1, 0)
+
+
+def _tiling(qw: int, nh: int, hd: int, itemsize: int, page_size: int,
+            ppseq: int, window):
+    """``(rows a tile, pages a block)`` of a launch, from its shapes.
+    Mosaic tiles the second-minor dim by 8 sublanes: a decode step's
+    one-row chunk is padded up to a whole tile, and a wide prefill chunk
+    is cut into block_q-row tiles along a grid axis so VMEM holds one
+    tile, not the whole chunk.  A causal block is ``_KEYS_PER_STEP``
+    keys; a window layer's is every page a tile's window can reach (a
+    tile holds at most ``min(bq, qw)`` real rows), so that its walk is
+    one pass, up to ``_MAX_WINDOW_KEYS``."""
+    bq = min(_block_q(nh, hd, itemsize), -(-qw // 8) * 8)
+    if window is None:
+        return bq, max(1, _KEYS_PER_STEP // page_size)
+    reach = min(_window_pages(min(bq, qw), window, page_size), ppseq)
+    return bq, min(reach, max(1, _MAX_WINDOW_KEYS // page_size))
+
+
+def walk_blocks(kv_lens, q_lens, qw: int, nh: int, hd: int, itemsize: int,
+                page_size: int, ppseq: int, window=None) -> int:
+    """The key blocks one launch walks, summed over lanes and tiles, by
+    the kernel's own bounds (``_tiling``, ``_walk``) in numpy on the
+    host: what the serving engine's ``attn_blocks`` counts a layer."""
+    bq, group = _tiling(qw, nh, hd, itemsize, page_size, ppseq, window)
+    q0 = np.arange(0, max(int(qw), 1), bq, dtype=np.int64)[None, :]
+    _, blocks = _walk(np.asarray(kv_lens, np.int64)[:, None],
+                      np.asarray(q_lens, np.int64)[:, None], q0, bq,
+                      page_size, group, window, xp=np)
+    return int(blocks.sum())
+
+
+def _ragged_kernel(kv_lens_ref, q_lens_ref, tables_ref, q_ref, k_hbm, v_hbm,
+                   *rest, n_kv: int, n_rep: int, block_q: int,
+                   page_size: int, group: int, scale: float, window,
                    has_sink: bool, precision=None):
-    # ``group`` consecutive pages a grid step, each a block of its own;
-    # ``n_pages`` grid steps along the page axis
-    k_refs, v_refs = rest[:group], rest[group:2 * group]
+    # the pools stay in HBM; a turn of the walk holds one block of
+    # ``group`` pages in one of the two slots of k_buf / v_buf
     if has_sink:
-        sink_ref, o_ref, acc_ref, m_ref, d_ref = rest[2 * group:]
-    else:
-        o_ref, acc_ref, m_ref, d_ref = rest[2 * group:]
+        sink_ref, *rest = rest
+    o_ref, acc_ref, m_ref, d_ref, k_buf, v_buf, sems = rest
     keys = group * page_size
     b = pl.program_id(0)
     t = pl.program_id(1)
-    p = pl.program_id(2)
     nh = n_kv * n_rep
-    rows = nh * block_q
+    rows = n_rep * block_q               # flat (head, row) of a kv head
+    kv_len = kv_lens_ref[b]
+    q_len = q_lens_ref[b]
+    q0 = jnp.int32(block_q) * t          # first query row of this tile
+    # the walk ends at the tile's last visible key and is empty for a
+    # tile past q_len (pure padding — a decode lane in a prefill-wide
+    # step walks one tile) — so copies and compute scale with the
+    # sequence's OWN lengths, not the padded maxima
+    first, n_blocks = _walk(kv_len, q_len, q0, block_q, page_size, group,
+                            window)
 
-    @pl.when(p == 0)
-    def _init():
+    def start(i, slot):
+        """Start block ``i``'s copies into ``slot``: one a page a pool,
+        every kv head at once.  An entry past the context names whatever
+        page the table holds there; its keys are masked."""
+        def page_copies(j, carry):
+            entry = first + i * jnp.int32(group) + j
+            if window is not None:       # the table is a ring
+                entry = entry % jnp.int32(tables_ref.shape[1])
+            page = tables_ref[b, entry]
+            at = pl.ds(pl.multiple_of(j * jnp.int32(page_size), page_size),
+                       page_size)
+            pltpu.make_async_copy(k_hbm.at[:, page], k_buf.at[slot, :, at],
+                                  sems.at[0, slot]).start()
+            pltpu.make_async_copy(v_hbm.at[:, page], v_buf.at[slot, :, at],
+                                  sems.at[1, slot]).start()
+            return carry
+        jax.lax.fori_loop(jnp.int32(0), jnp.int32(group), page_copies, None)
+
+    def wait(slot):
+        # a copy signals its semaphore by its bytes: one wait a pool for
+        # a whole slot's worth is the wait for every page of the block
+        for pool, buf in enumerate((k_buf, v_buf)):
+            pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                                  sems.at[pool, slot]).wait()
+
+    def turn(i, carry):
+        slot = i % 2
+
+        @pl.when(i + 1 < n_blocks)
+        def _next_block():
+            start(i + 1, 1 - slot)
+
+        wait(slot)
+        # [rows, keys] index planes, the same for every kv head: query
+        # row r of head h sits at flat row h*block_q + r; its absolute
+        # position is kv_len - q_len + q0 + r
+        qi = q0 + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, keys), 0) % jnp.int32(block_q)
+        kvpos = (first + i * jnp.int32(group)) * jnp.int32(page_size) \
+            + jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
+        qpos = kv_len - q_len + qi
+        mask = (kvpos <= qpos) & (kvpos < kv_len)
+        if window is not None:
+            mask = mask & (kvpos > qpos - jnp.int32(window))
+        # every kv head's logits before any statistic is written, every
+        # store after the last load: the heads' chains of product, row
+        # maximum, exponential and product then overlap instead of
+        # queueing behind each other's stores (a decode turn of eight
+        # heads took 2.2 times as long head by head on a v5e)
+        m_prev = m_ref[...]
+        logits = []
+        for g in range(n_kv):                            # static GQA loop
+            # the wrapper hands q heads-major with block_q a multiple of
+            # the 8-sublane tile, so this collapse is layout-trivial
+            qg = q_ref[0, g * n_rep:(g + 1) * n_rep] \
+                .astype(jnp.float32).reshape(rows, -1)
+            s = jax.lax.dot_general(
+                qg, k_buf[slot, g].astype(jnp.float32),  # [keys, hd]
+                (((1,), (1,)), ((), ())), precision=precision,
+                preferred_element_type=jnp.float32) * jnp.float32(scale)
+            logits.append(jnp.where(mask, s, jnp.float32(-1e30)))
+        m_new = jnp.maximum(m_prev, jnp.concatenate(
+            [jnp.max(s, axis=-1, keepdims=True) for s in logits], axis=0))
+        alpha = jnp.exp(m_prev - m_new)
+        # masked probabilities: a fully-masked block must contribute 0,
+        # not exp(-1e30 - (-1e30)) == 1
+        probs = [jnp.where(mask,
+                           jnp.exp(s - m_new[g * rows:(g + 1) * rows]),
+                           jnp.float32(0.0))
+                 for g, s in enumerate(logits)]
+        d_ref[...] = d_ref[...] * alpha + jnp.concatenate(
+            [jnp.sum(p, axis=-1, keepdims=True) for p in probs], axis=0)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.concatenate(
+            [jax.lax.dot_general(
+                p, v_buf[slot, g].astype(jnp.float32),   # [keys, hdv]
+                (((1,), (0,)), ((), ())), precision=precision,
+                preferred_element_type=jnp.float32)
+             for g, p in enumerate(probs)], axis=0)
+        m_ref[...] = m_new
+        return carry
+
+    @pl.when(n_blocks == 0)
+    def _padding_tile():
+        # no copy, no turn: a zero-context row is exactly zero
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_blocks > 0)
+    def _live_tile():
+        start(jnp.int32(0), 0)
         if has_sink:
             # the sink is a key with no value: the running max starts at
             # its logit and the denominator at exp(0)
@@ -279,69 +429,7 @@ def _ragged_kernel(kv_lens_ref, q_lens_ref, tables_ref, q_ref, *rest,
             m_ref[...] = jnp.full_like(m_ref, jnp.float32(-1e30))
             d_ref[...] = jnp.zeros_like(d_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    kv_len = kv_lens_ref[b]
-    q_len = q_lens_ref[b]
-    q0 = jnp.int32(block_q) * t          # first query row of this tile
-    if window is None:
-        page = jnp.int32(group) * p
-    else:
-        page = _first_page(kv_len, q_len, q0, window, page_size) + p
-    page0 = jnp.int32(page_size) * page  # first kv position of the step
-
-    # skip the dot products of (a) pages at or past ceil(kv_len / ps)
-    # (their table entries fetch page 0, fully masked), (b) query tiles
-    # past q_len (pure padding — a decode lane in a prefill-wide step
-    # computes one tile) and (c) pages wholly above the tile's last
-    # causal position — so compute scales with the sequence's OWN
-    # lengths, not the padded maxima
-    @pl.when((page0 < kv_len) & (q0 < q_len)
-             & (page0 < kv_len - q_len + q0 + jnp.int32(block_q)))
-    def _compute():
-        # [rows, ps] index planes: query row i of head h sits at flat
-        # row h*block_q + i; its absolute position is kv_len - q_len +
-        # q0 + i
-        qi = q0 + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, keys), 0) % jnp.int32(block_q)
-        kvpos = page0 + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, keys), 1)
-        qpos = kv_len - q_len + qi
-        mask = (kvpos <= qpos) & (kvpos < kv_len)
-        if window is not None:
-            mask = mask & (kvpos > qpos - jnp.int32(window))
-        # the wrapper hands q heads-major with block_q a multiple of
-        # the 8-sublane tile, so this collapse is layout-trivial
-        qf = q_ref[0].astype(jnp.float32).reshape(rows, -1)
-        for g in range(n_kv):                            # static GQA loop
-            sl = slice(g * n_rep * block_q, (g + 1) * n_rep * block_q)
-            kg, vg = (jnp.concatenate([r[g, 0] for r in refs], axis=0)
-                      .astype(jnp.float32)
-                      for refs in (k_refs, v_refs))      # [keys, hd]
-            s = jax.lax.dot_general(qf[sl], kg,
-                                    (((1,), (1,)), ((), ())),
-                                    precision=precision,
-                                    preferred_element_type=jnp.float32) \
-                * jnp.float32(scale)
-            s = jnp.where(mask[sl], s, jnp.float32(-1e30))
-            m_prev = m_ref[sl]                           # [rows_g, 1]
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            # masked probabilities: a fully-masked page must
-            # contribute 0, not exp(-1e30 - (-1e30)) == 1
-            prob = jnp.where(mask[sl], jnp.exp(s - m_new),
-                             jnp.float32(0.0))
-            d_ref[sl] = d_ref[sl] * alpha \
-                + jnp.sum(prob, axis=-1, keepdims=True)
-            acc_ref[sl] = acc_ref[sl] * alpha \
-                + jax.lax.dot_general(prob, vg,
-                                      (((1,), (0,)), ((), ())),
-                                      precision=precision,
-                                      preferred_element_type=jnp.float32)
-            m_ref[sl] = m_new
-
-    @pl.when(p == n_pages - 1)
-    def _finalize():
+        jax.lax.fori_loop(jnp.int32(0), n_blocks, turn, None)
         d = d_ref[...]
         out = jnp.where(d > jnp.float32(0.0), acc_ref[...] / d,
                         jnp.float32(0.0))
@@ -352,9 +440,8 @@ def _ragged_pallas(q, k_pages, v_pages, kv_lens, q_lens, page_tables,
                    scale, window=None, sinks=None, precision=None):
     """The kernel's launch, as a jitted function of its own: a step
     calls it once a layer, and the layers of one geometry then share
-    one trace and one lowering to Mosaic (a lowering costs ~0.3 s of
-    set-up with the causal walk's sixteen page blocks, a program at a
-    time, cached or not)."""
+    one trace and one lowering to Mosaic, a program at a time, cached
+    or not."""
     return _ragged_call(q, k_pages, v_pages, kv_lens, q_lens, page_tables,
                         sinks, scale=float(scale),
                         window=None if window is None else int(window),
@@ -365,73 +452,59 @@ def _ragged_pallas(q, k_pages, v_pages, kv_lens, q_lens, page_tables,
                                              "interpret"))
 def _ragged_call(q, k_pages, v_pages, kv_lens, q_lens, page_tables, sinks,
                  *, scale, window, precision, interpret):
+    hdv = v_pages.shape[-1]
+    # a copy out of a pool moves whole 128-lane tiles (Mosaic refuses a
+    # slice 96 wide): heads of another width are padded for the call,
+    # which copies such a pool as the compiler's own re-lay of it did
+    # (ROADMAP S15); the zeros add nothing to q.k
+    q, k_pages, v_pages = (
+        a if a.shape[-1] % 128 == 0 else jnp.pad(
+            a, ((0, 0),) * 3 + ((0, -a.shape[-1] % 128),))
+        for a in (q, k_pages, v_pages))
     b, qw, nh, hd = q.shape
     nkv, _, ps, _ = k_pages.shape
-    hdv = v_pages.shape[-1]
+    hdp = v_pages.shape[-1]              # the values' width as copied
     ppseq = page_tables.shape[1]
-    # Mosaic tiles the second-minor dim by 8 sublanes: a decode step's
-    # one-row chunk is padded up to a whole tile, and a wide prefill
-    # chunk is cut into block_q-row tiles along a grid axis so VMEM
-    # holds one tile, not the whole chunk
-    bq = min(_block_q(nh, hd, q.dtype.itemsize), -(-qw // 8) * 8)
+    bq, group = _tiling(qw, nh, hd, q.dtype.itemsize, ps, ppseq, window)
     qp = -(-qw // bq) * bq
-    # heads-major [B, nh, Q, hd]: the kernel collapses (nh, block_q)
+    # heads-major [B, nh, Q, hd]: the kernel collapses (n_rep, block_q)
     # into flat rows without an in-kernel transpose
     qt = jnp.swapaxes(q, 1, 2)
     if qp != qw:
         qt = jnp.pad(qt, ((0, 0), (0, 0), (0, qp - qw), (0, 0)))
-    if window is None:
-        group = max(1, _KEYS_PER_STEP // ps)
-        n_pages = -(-ppseq // group)
-        if n_pages * group != ppseq:
-            # whole steps: the added entries lie past every context
-            page_tables = jnp.pad(
-                page_tables, ((0, 0), (0, n_pages * group - ppseq)),
-                mode="edge")
+    if window is None and ppseq % group:
+        # whole blocks: the added entries lie past every context
+        page_tables = jnp.pad(
+            page_tables, ((0, 0), (0, group - ppseq % group)), mode="edge")
 
-        def kv_map(j):
-            def index(i, t, p, kl, ql, tb):
-                # past the tile's last block the step names the block it
-                # holds, so a step that computes nothing copies nothing
-                last = _last_block(kl[i], ql[i], jnp.int32(bq) * t, bq,
-                                   group * ps)
-                return (0, tb[i, jnp.minimum(p, last) * jnp.int32(group)
-                              + jnp.int32(j)], 0, 0)
-            return index
-    else:
-        # only the pages the tile's window reaches, one a step; a tile
-        # holds at most min(bq, qw) real rows
-        group = 1
-        n_pages = min(_window_pages(min(bq, qw), window, ps), ppseq)
+    def q_map(i, t, kl, ql, tb):
+        # a tile of padding rows names the lane's last real tile, which
+        # is held already: it copies no q
+        return (i, 0, jnp.minimum(
+            t, jnp.maximum(ql[i] - 1, 0) // jnp.int32(bq)), 0)
 
-        def kv_map(j):
-            def index(i, t, p, kl, ql, tb):
-                entry = _first_page(kl[i], ql[i], jnp.int32(bq) * t,
-                                    window, ps) + p
-                return (0, tb[i, entry % jnp.int32(ppseq)], 0, 0)
-            return index
-
-    def q_map(i, t, p, kl, ql, tb):
-        return (i, 0, t, 0)
-
-    in_specs = [pl.BlockSpec((1, nh, bq, hd), q_map)] \
-        + [pl.BlockSpec((nkv, 1, ps, hd), kv_map(j)) for j in range(group)] \
-        + [pl.BlockSpec((nkv, 1, ps, hdv), kv_map(j)) for j in range(group)]
-    operands = [qt] + [k_pages] * group + [v_pages] * group
+    in_specs = [pl.BlockSpec((1, nh, bq, hd), q_map),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    operands = [qt, k_pages, v_pages]
     if sinks is not None:
         # one logit per flat (head, row) of the tile
         in_specs.append(pl.BlockSpec(
-            (nh * bq, 1), lambda i, t, p, kl, ql, tb: (0, 0)))
+            (nh * bq, 1), lambda i, t, kl, ql, tb: (0, 0)))
         operands.append(jnp.repeat(sinks.astype(jnp.float32), bq)[:, None])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, qp // bq, n_pages),
+        grid=(b, qp // bq),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, nh, bq, hdv), q_map),
+        out_specs=pl.BlockSpec((1, nh, bq, hdp),
+                               lambda i, t, kl, ql, tb: (i, 0, t, 0)),
         scratch_shapes=[
-            pltpu.VMEM((nh * bq, hdv), jnp.float32),  # acc
+            pltpu.VMEM((nh * bq, hdp), jnp.float32),  # acc
             pltpu.VMEM((nh * bq, 1), jnp.float32),    # running max
             pltpu.VMEM((nh * bq, 1), jnp.float32),    # denominator
+            pltpu.VMEM((2, nkv, group * ps, hd), k_pages.dtype),
+            pltpu.VMEM((2, nkv, group * ps, hdp), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),          # (k | v, slot)
         ],
     )
     name = "ragged_paged_attn" if window is None \
@@ -440,19 +513,18 @@ def _ragged_call(q, k_pages, v_pages, kv_lens, q_lens, page_tables, sinks,
         out = pl.pallas_call(
             functools.partial(_ragged_kernel, n_kv=nkv,
                               n_rep=nh // nkv, block_q=bq,
-                              page_size=ps, group=group,
-                              n_pages=n_pages, scale=scale,
+                              page_size=ps, group=group, scale=scale,
                               window=window,
                               has_sink=sinks is not None,
                               precision=precision),
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, nh, qp, hdv), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((b, nh, qp, hdp), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_VMEM_LIMIT),
             interpret=interpret,
         )(kv_lens.astype(jnp.int32), q_lens.astype(jnp.int32),
           page_tables.astype(jnp.int32), *operands)
-    return jnp.swapaxes(out[:, :, :qw], 1, 2)
+    return jnp.swapaxes(out[:, :, :qw, :hdv], 1, 2)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, kv_lens, q_lens,

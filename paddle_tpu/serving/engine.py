@@ -85,6 +85,7 @@ extra host read).
 """
 from __future__ import annotations
 
+import collections
 import hashlib
 import itertools
 import threading
@@ -427,6 +428,15 @@ class ServingEngine:
         # old ones — they are abandoned wholesale, never reused)
         self._num_pages, self._page_size = int(num_pages), ps
         self._dtype = dtype
+        # the attention launches of a step, one entry a geometry:
+        # ((key width, window or None), layers) — what _attn_blocks counts
+        from ..models.generation import LaneState
+        self._attn_launches = tuple(collections.Counter(
+            (layer[1], layer[3]) for layer in self._cache.layers
+            if not isinstance(layer, LaneState)).items())
+        self._heads = int(cfg.description().heads) \
+            if self._attn_launches else 0
+        self._itemsize = jax.numpy.dtype(dtype).itemsize
         self._prefix_caching = bool(prefix_caching)
         # predicted-cost admission (FLAGS_serving_predicted_admission,
         # seconds): the scheduler admits prefills against the learned
@@ -520,6 +530,8 @@ class ServingEngine:
         self._n_drained = 0
         # what the steps asked of the state layers (_state_counts)
         self._state_lanes = self._state_resets = self._scan_rows = 0
+        # key blocks the steps' attention kernels walked (_attn_blocks)
+        self._n_attn_blocks = 0
 
     def _new_pools(self):
         """Zeroed device pools and states of the step's own geometry."""
@@ -1083,6 +1095,8 @@ class ServingEngine:
             self._state_lanes += state[0]
             self._state_resets += state[1]
             self._scan_rows += state[2]
+            blocks = self._attn_blocks(plan)
+            self._n_attn_blocks += blocks
             now = time.monotonic()
             for i, seq in enumerate(plan.seqs):
                 if seq.req.done:
@@ -1126,7 +1140,7 @@ class ServingEngine:
                 int(plan.q_width), plan.fed_prefill + plan.fed_decode,
                 step_s, flight.cold, 1, "single_step",
                 routing=toks[self.max_batch:], ahead=flight.ahead,
-                span=flight.span, state=state)
+                span=flight.span, state=state, attn_blocks=blocks)
         flight.span.end()
 
     def _state_counts(self, plan):
@@ -1160,10 +1174,27 @@ class ServingEngine:
         first = np.maximum(oldest, 0) // ps
         return int((last - first + 1).sum()) * n_window, full
 
+    def _attn_blocks(self, plan, steps: int = 1) -> int:
+        """The key blocks this step's attention kernels walk, summed
+        over lanes, query tiles and layers, by the kernel's own bounds
+        (``ragged_paged_attention.walk_blocks``) on the plan's lengths:
+        host arithmetic, no device work.  Kernel seconds over it is the
+        cost of a block.  ``steps``: a fused window's iterations, each a
+        token further."""
+        from ..ops.pallas.ragged_paged_attention import walk_blocks
+        return sum(
+            layers * walk_blocks(
+                plan.kv_lens + j, plan.q_lens, int(plan.q_width),
+                self._heads, width, self._itemsize, self._page_size,
+                self._ring_pages, window)
+            for (width, window), layers in self._attn_launches
+            for j in range(steps))
+
     def _emit_batch_step(self, phase_seconds, plan, prefill_seqs,
                          q_width, tokens, step_s, cold_start,
                          fused_steps, exit_reason, routing=(),
-                         ahead=False, span=None, state=(0, 0, 0)) -> None:
+                         ahead=False, span=None, state=(0, 0, 0),
+                         attn_blocks=0) -> None:
         """The step's ``batch_step`` record (under ``_wake``;
         ``phase_seconds`` from ``_LoopPhases.take``; ``span`` the step's
         own where no ambient one covers it).  step_s +
@@ -1203,6 +1234,7 @@ class ServingEngine:
                      experts_hit=experts_hit,
                      window_pages_read=window_pages,
                      full_pages_read=full_pages,
+                     attn_blocks=attn_blocks,
                      state_lanes=state[0], state_resets=state[1],
                      scan_rows=state[2])
 
@@ -1319,10 +1351,13 @@ class ServingEngine:
                     self.scheduler.finish(seq)
                     self._h_latency.observe(now - req.submitted_at)
             self._g_occ.set(len(self.scheduler.running))
+            blocks = self._attn_blocks(plan, steps)
+            self._n_attn_blocks += blocks
             self._emit_batch_step(
                 phases.take(front), plan, 0, 1, fed, step_timer.seconds,
                 cold_start, steps,
-                "finished" if any_finished else clamp_reason)
+                "finished" if any_finished else clamp_reason,
+                attn_blocks=blocks)
 
     def _cache_prompt(self, seq):
         """Share the finished prompt's full pages through the prefix
@@ -1657,6 +1692,7 @@ class ServingEngine:
                "state_lanes": self._state_lanes,  # noqa: PTL902 — advisory snapshot (see below)
                "state_resets": self._state_resets,  # noqa: PTL902 — advisory snapshot (see below)
                "scan_rows": self._scan_rows,  # noqa: PTL902 — advisory snapshot (see below)
+               "attn_blocks": self._n_attn_blocks,  # noqa: PTL902 — advisory snapshot (see below)
                "free_pages": self.pool.available(),  # noqa: PTL902 — advisory snapshot; the handle swaps atomically at relaunch
                "programs": len(self._programs),
                "health": self.health,

@@ -1,8 +1,10 @@
 """The ragged kernel's window, sink and value width: the Pallas kernel in
 interpret mode against ``ragged_paged_attention_ref``, and the reference
 against attention written out densely."""
+import functools
 import math
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -109,7 +111,7 @@ def test_values_narrower_than_keys_and_a_sink_over_a_plain_table(
 
 
 def test_a_window_layer_visits_nine_pages_at_any_context():
-    """The grid's page axis of a window layer: the pages that
+    """The one pass of a window layer: the pages that
     ``rows + W - 1`` consecutive positions can touch, whatever the
     context — 9 for a decode step at a window of 128 and pages of 16."""
     assert rpa._window_pages(1, 128, 16) == 9
@@ -143,31 +145,118 @@ def _paged(rs, kv_lens, ps, ppseq, nkv, hd, hdv):
     return k, v, tables, dense_k, dense_v
 
 
-# the causal walk takes 128 keys a grid step: contexts inside one step,
-# at its edge, one key past it and over several steps; a decode step and
-# a chunk of two query tiles beside a decoding and an idle lane
-@pytest.mark.parametrize("context", [100, 128, 129, 300])
-@pytest.mark.parametrize("q_len", [1, 132])
-def test_causal_kernel_takes_several_pages_a_grid_step(interpret, rng,
-                                                       context, q_len):
+def _kernel_against_dense(rng, kv_lens, q_lens, qw, tables=None, ppseq=21,
+                          window=None):
     ps, nh, nkv, hd, hdv = 16, 4, 2, 24, 16
-    kv_lens = [max(q_len, context), 45, 0]
-    q_lens = [q_len, 1, 0]
-    # 21 entries: not a whole number of steps of 8 pages
-    k, v, tables, dk, dv = _paged(rng, kv_lens, ps, 21, nkv, hd, hdv)
-    q = rng.randn(3, q_len, nh, hd).astype("float32")
-    assert q_len == 1 or q_len > rpa._block_q(nh, hd, 4)
-    args = (jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
-            jnp.asarray(kv_lens, jnp.int32), jnp.asarray(q_lens, jnp.int32),
-            jnp.asarray(tables))
-    got = np.asarray(rpa.ragged_paged_attention(*args))
+    k, v, own, dk, dv = _paged(rng, kv_lens, ps, ppseq, nkv, hd, hdv)
+    q = rng.randn(len(kv_lens), qw, nh, hd).astype("float32")
+    got = np.asarray(rpa.ragged_paged_attention(
+        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+        jnp.asarray(kv_lens, jnp.int32), jnp.asarray(q_lens, jnp.int32),
+        jnp.asarray(own if tables is None else tables), window=window))
+    want = _dense(q, dk, dv, kv_lens, q_lens, window, None)
+    for i, n in enumerate(q_lens):
+        np.testing.assert_allclose(got[i, :n], want[i, :n], atol=3e-6)
+    return got
+
+
+# the causal walk takes a block of _KEYS_PER_STEP keys a turn: contexts
+# inside one block of 128 or 256, at its edge, one key past it and over
+# several; a decode step and a chunk of two query tiles beside a
+# decoding and an idle lane; 21 entries, not a whole number of blocks
+@pytest.mark.parametrize("context", [100, 128, 129, 256, 257, 300])
+@pytest.mark.parametrize("q_len", [1, 132])
+def test_causal_kernel_takes_several_pages_a_turn(interpret, rng,
+                                                  context, q_len):
+    assert q_len == 1 or q_len > rpa._block_q(4, 24, 4)
+    _kernel_against_dense(rng, [max(q_len, context), 45, 0],
+                          [q_len, 1, 0], q_len)
+
+
+@pytest.mark.parametrize("q_len", [1, 40, 200])
+def test_a_table_s_last_block_is_partial(interpret, rng, q_len):
+    """21 entries of 16 keys, every one in use: the walk's last block
+    holds the table's last pages and the entries the launch added to
+    make whole blocks, which lie past the context."""
+    assert (21 * 16) % rpa._KEYS_PER_STEP
+    _kernel_against_dense(rng, [336, 330], [q_len, 1], q_len)
+
+
+@pytest.mark.parametrize("blocks", [1, 2])
+def test_a_context_that_ends_on_a_block_s_edge_beside_an_idle_lane(
+        interpret, rng, blocks):
+    """The last key is the last of its block: the walk takes ``blocks``
+    turns and starts no copy of a block after it, and the idle lane
+    after it starts none at all and reads exactly zero."""
+    edge = blocks * rpa._KEYS_PER_STEP
+    assert int(rpa._last_block(jnp.int32(edge), jnp.int32(1), jnp.int32(0),
+                               8, rpa._KEYS_PER_STEP)) == blocks - 1
+    got = _kernel_against_dense(rng, [edge, 0, edge + 1], [1, 0, 1], 1,
+                                ppseq=40)
+    assert np.all(got[1] == 0.0)
+
+
+def test_two_lanes_whose_tables_name_the_same_pages(interpret, rng):
+    """A prefix shared through the prefix cache: two lanes read the same
+    first pages, each its own after them."""
+    ps, kv_lens = 16, [200, 150]
+    k, v, tables, dk, dv = _paged(rng, kv_lens, ps, 21, 2, 24, 16)
+    shared = 6                                     # pages of 16 keys
+    tables[1, :shared] = tables[0, :shared]
+    dk[1][:shared * ps], dv[1][:shared * ps] = \
+        dk[0][:shared * ps], dv[0][:shared * ps]
+    q = rng.randn(2, 3, 4, 24).astype("float32")
+    q_lens = [3, 1]
+    got = np.asarray(rpa.ragged_paged_attention(
+        jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+        jnp.asarray(kv_lens, jnp.int32), jnp.asarray(q_lens, jnp.int32),
+        jnp.asarray(tables)))
     want = _dense(q, dk, dv, kv_lens, q_lens, None, None)
     for i, n in enumerate(q_lens):
         np.testing.assert_allclose(got[i, :n], want[i, :n], atol=3e-6)
 
 
+def test_a_window_wider_than_one_pass_is_walked_in_blocks(interpret, rng):
+    """A window that reaches more keys than one pass holds: blocks of
+    ``_MAX_WINDOW_KEYS`` from the page of the oldest visible key."""
+    window = rpa._MAX_WINDOW_KEYS + 100
+    bq, group = rpa._tiling(1, 4, 24, 4, 16, 60, window)
+    assert group * 16 == rpa._MAX_WINDOW_KEYS
+    assert rpa.walk_blocks([900, 300, 0], [1, 1, 0], 1, 4, 24, 4, 16, 60,
+                           window) == 2 + 1
+    _kernel_against_dense(rng, [900, 300, 0], [1, 1, 0], 1, ppseq=60,
+                          window=window)
+
+
+@pytest.mark.parametrize("window", [None, 128])
+@pytest.mark.parametrize("qw,kv_lens,q_lens", [
+    (1, [2500, 129, 128, 1, 0], [1, 1, 1, 1, 0]),
+    (1024, [2048, 700, 0, 4096], [1024, 1, 0, 1]),
+    (300, [300, 77, 310], [300, 1, 130])])
+def test_the_host_s_count_of_blocks_is_the_kernel_s_own_bounds(
+        window, qw, kv_lens, q_lens):
+    """``walk_blocks`` (numpy, what ``attn_blocks`` sums) against the
+    bounds as the kernel takes them: ``_walk`` a lane and a tile in
+    jnp, at the longgen cell's heads."""
+    nh, hd, ps, ppseq = 64, 256, 16, 73 if window else 512
+    bq, group = rpa._tiling(qw, nh, hd, 4, ps, ppseq, window)
+    want = 0
+    for kv, q in zip(kv_lens, q_lens):
+        for q0 in range(0, -(-qw // bq) * bq, bq):
+            first, blocks = rpa._walk(jnp.int32(kv), jnp.int32(q),
+                                      jnp.int32(q0), bq, ps, group, window)
+            want += int(blocks)
+            if int(blocks) and window is None:
+                assert int(blocks) == int(rpa._last_block(
+                    jnp.int32(kv), jnp.int32(q), jnp.int32(q0), bq,
+                    group * ps)) + 1
+    assert want > 0
+    assert rpa.walk_blocks(kv_lens, q_lens, qw, nh, hd, 4, ps, ppseq,
+                           window) == want
+
+
 def test_a_tile_s_walk_ends_at_the_block_of_its_last_row():
-    """``_last_block``: where the index maps stop fetching.  A decode
+    """``_last_block``: where a tile's walk ends.  A decode
     row at position 2,499 ends in block 19 of 128 keys; the first tile
     of a 1,024-row chunk after 1,024 cached tokens ends in block 8, its
     last tile in block 15; a tile of padding rows stays on block 0."""
@@ -190,3 +279,46 @@ def test_query_tiles_fill_half_of_the_vmem_the_kernel_asks_for():
     assert rpa._block_q(64, 256, 4) == 32
     assert rpa._block_q(32, 128, 4) == 128
     assert rpa._block_q(16, 96, 4) == 128
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", None)
+            if inner is not None:
+                yield from _pallas_calls(getattr(inner, "jaxpr", inner))
+
+
+# the serve cells' launches: heads, kv heads, key and value widths, pages,
+# table entries, window, tile rows at Q=1024
+@pytest.mark.parametrize("nh,nkv,hd,hdv,pages,ppseq,window,bq", [
+    (32, 8, 128, 128, 2049, 256, None, 128),
+    (64, 4, 256, 128, 4097, 512, None, 32),
+    (64, 8, 256, 128, 585, 73, 128, 32),
+    (64, 8, 128, 128, 4097, 512, None, 64)],
+    ids=["batch", "longgen-full", "longgen-window", "longdoc"])
+@pytest.mark.parametrize("qw", [1, 1024])
+def test_the_grid_is_lanes_by_query_tiles_and_the_pools_stay_in_hbm(
+        nh, nkv, hd, hdv, pages, ppseq, window, bq, qw):
+    """No page axis: one grid step a (lane, query tile), whatever the
+    table's length, and one launch that takes each pool whole, in any
+    memory space, beside q and the three prefetched arrays."""
+    f32, i32 = jnp.float32, jnp.int32
+    sds = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(functools.partial(
+        rpa._ragged_call, scale=0.1, window=window, precision=None,
+        interpret=False))(
+        sds((8, qw, nh, hd), f32), sds((nkv, pages, 16, hd), f32),
+        sds((nkv, pages, 16, hdv), f32), sds((8,), i32), sds((8,), i32),
+        sds((8, ppseq), i32), None)
+    [call] = _pallas_calls(jaxpr.jaxpr)
+    grid = call.params["grid_mapping"]
+    assert grid.grid == (8, 1 if qw == 1 else 1024 // bq)
+    assert len(call.invars) == 6
+    blocks = [str(b.transformed_block_aval) for b in grid.block_mappings]
+    assert blocks[1] == f"Ref<any>{{float32[{nkv},{pages},16,{hd}]}}"
+    assert blocks[2] == f"Ref<any>{{float32[{nkv},{pages},16,{hdv}]}}"
+    assert call.outvars[0].aval.shape == (8, nh, 8 if qw == 1 else 1024,
+                                          hdv)

@@ -1020,6 +1020,49 @@ def test_batch_step_events_carry_fused_fields(gpt_model, fused_flags,
     assert batch_step_features(legacy)["fused_steps"] == 1.0
 
 
+@pytest.mark.parametrize("fused", [1, 4], ids=["single-step", "fused"])
+def test_attn_blocks_of_a_plan_are_the_blocks_the_kernel_walks(
+        gpt_model, flags_guard, tmp_path, fused):
+    """``batch_step.attn_blocks`` is ``walk_blocks`` of the plan's
+    lengths a layer (the kernel's own bounds), ``engine.stats()`` sums
+    it, and a fused window counts every iteration."""
+    import types
+    from paddle_tpu.observability import events as obs_events
+    from paddle_tpu.ops.pallas import ragged_paged_attention as rpa
+    rs = np.random.RandomState(7)
+    keep = get_flags(["FLAGS_serving_fused_steps"])
+    set_flags({"FLAGS_observability_dir": str(tmp_path),
+               "FLAGS_serving_fused_steps": fused})
+    try:
+        engine = ServingEngine(gpt_model, max_batch=3, page_size=8,
+                               max_prefill_chunk=16)
+        with engine:
+            reqs = [engine.submit(rs.randint(0, 128, (n,)).tolist(),
+                                  max_new_tokens=6) for n in (40, 9, 21)]
+            for r in reqs:
+                r.wait(timeout=120)
+    finally:
+        set_flags({"FLAGS_observability_dir": "", **keep})
+    steps = [e for e in obs_events.read_events(str(tmp_path))
+             if e["kind"] == "batch_step"]
+    assert steps and all(e["attn_blocks"] >= e["batch"] * 2 for e in steps)
+    assert any(e["fused_steps"] > 1 for e in steps) == (fused > 1)
+    assert sum(e["attn_blocks"] for e in steps) \
+        == engine.stats()["attn_blocks"]
+    # a plan by hand: a chunk of 16 beside two decoding lanes and an idle
+    # one, two layers of 4 heads of 16
+    plan = types.SimpleNamespace(
+        kv_lens=np.array([40, 9, 100, 0]), q_lens=np.array([16, 1, 1, 0]),
+        q_width=16)
+    a_layer = rpa.walk_blocks(plan.kv_lens, plan.q_lens, 16, 4, 16, 4, 8, 0)
+    assert a_layer == 3      # every tile of 16 rows inside one block
+    assert engine._attn_blocks(plan) == 2 * a_layer
+    plan.q_lens, plan.q_width = np.array([1, 1, 1, 0]), 1
+    steps_3 = sum(rpa.walk_blocks(plan.kv_lens + j, plan.q_lens, 1, 4, 16,
+                                  4, 8, 0) for j in range(3))
+    assert engine._attn_blocks(plan, 3) == 2 * steps_3
+
+
 def test_scheduler_window_budget_clamps_pages_and_budget():
     """window_budget: the width obeys the tightest of the remaining
     token budget and the page pool, pre-allocates the window's pages

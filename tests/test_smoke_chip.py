@@ -161,6 +161,12 @@ for qw in (1024,):
     import re
     out["matmul_shapes"] = sorted(re.findall(
         r"= (\\w+\\[[\\d,]+\\])\\S* convolution\\(", text))
+    [call] = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    out["attn_operands"] = re.search(
+        r"custom-call\\((.*?)\\), custom_call_target", call)[1].count("%")
+    out["attn_pool_operands"] = call.split("backend_config")[0].split(
+        "operand_layout_constraints=")[1].count(
+        "f32[" + ",".join(str(n) for n in pool.shape) + "]")
 """)
 
 # the matrix products of serve_step_q1024 for one Mistral-width layer as
@@ -192,6 +198,11 @@ def test_q1024_program_multiplies_packed_rows_and_copies_no_pool():
     assert out["matmul_shapes"] == _Q1024_PRODUCTS_AT_PR28, out
     assert out["1024"]["kernels"] == 1, out
     assert out["1024"]["pool_copies"] == 0, out
+    # the attention call walks the pools itself: the key pool and the
+    # value pool once each beside q and the three prefetched arrays,
+    # where a block a page of the causal walk was sixteen pool operands
+    assert out["attn_pool_operands"] == 2, out
+    assert out["attn_operands"] == 6, out
     # q gathered for the kernel and its output, no [8192, ...] buffers
     # of the feed-forward's width: well under a pool and a half
     assert out["1024"]["temp_bytes"] < 3 * out["1024"]["pool_bytes"], out
