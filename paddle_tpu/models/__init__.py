@@ -14,6 +14,7 @@ from .ernie_moe import (ErnieMoEConfig, ErnieMoEModel,
                         ERNIE_MOE_PRESETS)
 from .mimo_v2 import MiMoV2Config, MiMoV2ForCausalLM
 from .solar_open2 import SolarOpen2Config, SolarOpen2ForCausalLM
+from .glm5 import Glm5Config, Glm5ForCausalLM
 from .t5 import T5Config, T5ForConditionalGeneration
 from .bart import BartConfig, BartForConditionalGeneration
 from .convert import (bert_from_hf, llama_from_hf, gpt2_from_hf,

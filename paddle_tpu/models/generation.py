@@ -46,8 +46,9 @@ from ..random_state import default_generator
 
 __all__ = ["generate", "decode_loop", "build_ragged_decode_step",
            "build_fused_window_step", "AttentionKind", "FeedForwardKind",
-           "LinearAttentionKind", "LayerDescription", "ModelDescription",
-           "CacheDescription", "LaneState"]
+           "LinearAttentionKind", "LatentAttentionKind", "IndexKind",
+           "LayerDescription", "ModelDescription", "CacheDescription",
+           "LaneState", "LatentPages"]
 
 _GREEDY = ("greedy_search", "greedy")
 
@@ -470,6 +471,63 @@ class LinearAttentionKind:
 
 
 @dataclass(frozen=True)
+class IndexKind:
+    """The index of a latent-attention layer: which keys a query row
+    attends.  ``heads`` index queries of ``dim`` a row, from the row's
+    normed query latent, and ONE index key of ``dim`` a token, a layer
+    norm (weight and bias, at ``norm_eps``) of a projection of the
+    layer's normed input; the first ``rotary_dim`` dimensions of both
+    are rotated.  A row's score for a visible key is ``sum_h w_h
+    relu(q_h . k)`` with ``w`` a projection of the row's input times
+    ``heads ** -0.5 * dim ** -0.5``, and the row attends the ``top_k``
+    keys of the largest scores (every visible key while there are no
+    more than that).  The index keys are cached beside the latent."""
+    heads: int
+    dim: int
+    top_k: int
+    rotary_dim: int
+    rope_interleaved: bool = False
+    norm_eps: float = 1e-6
+
+
+@dataclass(frozen=True)
+class LatentAttentionKind:
+    """One layer's token mixer where keys and values are a LATENT
+    (multi-head latent attention, in its absorbed form): a token's cache
+    row is its normed latent of ``kv_rank`` and ONE rotated key of
+    ``rope_dim`` shared by all heads, nothing a head.  Queries come
+    through a normed latent of ``q_rank``: ``heads`` of ``nope_dim +
+    rope_dim``, the last ``rope_dim`` rotated from base ``rope_theta``
+    (pairs ``(2i, 2i + 1)`` with ``rope_interleaved``).  A head's
+    ``nope_dim`` query is carried into the latent by its ``w_uk``
+    (``[nope_dim, kv_rank]``), logits are ``(q~ . c + q_r . k_r) /
+    sqrt(nope_dim + rope_dim)``, and the weighted sum of latents leaves
+    through the head's ``w_uv`` (``[kv_rank, value_dim]``).  With an
+    ``index`` a row attends only the keys its index picks
+    (:class:`IndexKind`, ``ops/latent_select.py``)."""
+    heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    value_dim: int
+    rope_theta: float = 10000.0
+    rope_interleaved: bool = False
+    index: Optional[IndexKind] = None
+
+    def __post_init__(self):
+        ix = self.index
+        if ix is not None and (
+                ix.rotary_dim != self.rope_dim
+                or ix.rope_interleaved != self.rope_interleaved):
+            raise ValueError(
+                "an index rotates by its layer's own tables: rotary_dim "
+                f"{ix.rotary_dim} and rope_interleaved "
+                f"{ix.rope_interleaved} differ from the layer's "
+                f"{self.rope_dim} and {self.rope_interleaved}")
+
+
+@dataclass(frozen=True)
 class FeedForwardKind:
     """One layer's feed-forward of ``width``: ``gated`` (``act(gate) *
     up``, then down) or a plain two-matrix MLP with biases, ``act``
@@ -491,16 +549,20 @@ class FeedForwardKind:
 
 @dataclass(frozen=True)
 class LayerDescription:
-    """A layer mixes tokens by ``attention`` or by ``linear_attention``
-    (the other is None), then runs ``feed_forward``."""
+    """A layer mixes tokens by ``attention``, by ``linear_attention`` or
+    by ``latent_attention`` (the other two are None), then runs
+    ``feed_forward``."""
     attention: Optional[AttentionKind]
     feed_forward: FeedForwardKind
     linear_attention: Optional[LinearAttentionKind] = None
+    latent_attention: Optional[LatentAttentionKind] = None
 
     def __post_init__(self):
-        if (self.attention is None) == (self.linear_attention is None):
-            raise ValueError("a layer has attention or linear attention, "
-                             "one of the two")
+        mixers = (self.attention, self.linear_attention,
+                  self.latent_attention)
+        if sum(m is not None for m in mixers) != 1:
+            raise ValueError("a layer has attention, linear attention or "
+                             "latent attention, one of the three")
 
 
 @dataclass(frozen=True)
@@ -537,12 +599,22 @@ class LaneState(tuple):
     takes it in a layer's place."""
 
 
+class LatentPages(tuple):
+    """A latent-attention layer's cache: the widths of its paged rows, a
+    row a token — ``(latent, index)`` with ``latent`` the pool width of
+    the normed latent and the shared rotated key side by side
+    (:func:`_pool_width`) and ``index`` that of the index key, or
+    ``(latent,)`` for a layer with no index.  No width depends on the
+    number of heads.  :class:`CacheDescription` takes it in a layer's
+    place."""
+
+
 class CacheDescription:
     """What a ragged step keeps from step to step, layer by layer — the
     one place the serving engine (and anything else that feeds a step)
     learns its geometry from.  ``build_ragged_decode_step`` hangs it
     on the step it returns (``step.cache``).  A layer's cache is of one
-    of three kinds, and ``pools`` holds a tuple of arrays a layer:
+    of four kinds, and ``pools`` holds a tuple of arrays a layer:
 
     * a **full** layer's pools are ``[kv_heads, num_pages, page_size,
       dim]`` (keys ``key_dim`` wide, values ``value_dim``), shared
@@ -557,7 +629,12 @@ class CacheDescription:
       ``[max_batch, *shape]``: a fixed-size state a running sequence,
       which does not grow with the sequence, is not paged and is never
       shared.  The step itself zeroes a sequence's state when the
-      sequence's first row of the step is at position 0.
+      sequence's first row of the step is at position 0;
+    * a **latent** layer (:class:`LatentPages`) keeps one pool a width,
+      ``[1, num_pages, page_size, width]``: a token's latent row and,
+      beside it, its index key.  They are paged by token exactly as a
+      full layer's pools are, through the same ``PagePool``, page ids
+      and ``tables``, and hold no axis of heads.
 
     A running sequence owns one of ``max_batch`` **slots** (the
     scheduler's): its ring is ring ``slot`` and its state row is row
@@ -566,15 +643,15 @@ class CacheDescription:
     where a layer keeps state, its slot."""
 
     def __init__(self, layers):
-        # per layer: (kv_heads, key_dim, value_dim, window or None), or
-        # a LaneState
+        # per layer: (kv_heads, key_dim, value_dim, window or None), a
+        # LaneState or a LatentPages
         self.layers = tuple(
-            layer if isinstance(layer, LaneState) else
+            layer if isinstance(layer, (LaneState, LatentPages)) else
             (int(layer[0]), int(layer[1]), int(layer[2]),
              None if layer[3] is None else int(layer[3]))
             for layer in layers)
         paged = [layer for layer in self.layers
-                 if not isinstance(layer, LaneState)]
+                 if not isinstance(layer, (LaneState, LatentPages))]
         windows = {w for _, _, _, w in paged if w is not None}
         if len(windows) > 1:
             raise ValueError(f"window layers of unlike windows {windows} "
@@ -582,7 +659,9 @@ class CacheDescription:
         self.window = windows.pop() if windows else None
         self.n_window = sum(1 for layer in paged if layer[3] is not None)
         self.n_full = len(paged) - self.n_window
-        self.n_state = len(self.layers) - len(paged)
+        self.n_state = sum(isinstance(layer, LaneState)
+                           for layer in self.layers)
+        self.n_latent = len(self.layers) - len(paged) - self.n_state
 
     def ring_pages(self, page_size: int, max_chunk: int) -> int:
         """Pages of one lane's ring: the window, the widest chunk a step
@@ -600,6 +679,10 @@ class CacheDescription:
                 out.append(tuple((int(max_batch), *shape)
                                  for shape in layer))
                 continue
+            if isinstance(layer, LatentPages):
+                out.append(tuple((1, int(num_pages), int(page_size), width)
+                                 for width in layer))
+                continue
             nkv, dk, dv, window = layer
             pages = int(num_pages) if window is None \
                 else int(max_batch) * int(ring_pages) + 1
@@ -610,7 +693,8 @@ class CacheDescription:
     def new_pools(self, num_pages: int, page_size: int, dtype,
                   max_batch: int, ring_pages: int = 0):
         """Fresh zeroed arrays, a tuple a layer: ``(k_pages, v_pages)``
-        in ``dtype``, or a state layer's arrays in float32."""
+        or a latent layer's pools in ``dtype``, or a state layer's
+        arrays in float32."""
         return tuple(
             tuple(jnp.zeros(shape, jnp.float32
                             if isinstance(layer, LaneState) else dtype)
@@ -858,7 +942,10 @@ def build_ragged_decode_step(model):
       ``conv_q conv_k conv_v [kernel, channels]``, the decay's ``wf_down
       wf_up dt_bias a_log``, ``wbeta``, the gate's ``wgate_down
       wgate_up``, ``out_norm_w`` and ``wo``
-      (:func:`_linear_attention_rows`).  Whether a projection has a bias
+      (:func:`_linear_attention_rows`); a latent-attention layer has
+      ``wq_a q_norm_w wq_b wkv_a kv_norm_w w_uk w_uv wo`` and, with an
+      index, ``wi_q wi_k wi_k_norm_w wi_k_norm_b wi_w``
+      (:func:`_latent_attention_rows`).  Whether a projection has a bias
       is read from the tree: an entry that is absent or None adds
       nothing.
 
@@ -868,7 +955,10 @@ def build_ragged_decode_step(model):
     ``page_ids``/``slots`` serve the full layers alone.  A
     linear-attention layer's entry of ``pools`` is its state and its
     convolution's tail, a row a slot, and the last column of ``tables``
-    says which slot a sequence owns.
+    says which slot a sequence owns.  A latent-attention layer's entry is
+    its latent pool and its index-key pool (:class:`LatentPages`),
+    written at ``(page_ids, slots)`` and read through the full layers'
+    page ids in ``tables``.
 
     With an expert layer (``step.routing_counts``) ``step`` returns
     ``(logits, pools', counts)``: ``counts i32[3]`` are the rows routed
@@ -892,7 +982,10 @@ def build_ragged_decode_step(model):
     cache = CacheDescription(
         [(d.attention.kv_heads, _pool_width(d.attention.key_dim),
           _pool_width(d.attention.value_dim), d.attention.window)
-         if d.attention is not None else _lane_state(d.linear_attention)
+         if d.attention is not None
+         else _latent_pages(d.latent_attention)
+         if d.latent_attention is not None
+         else _lane_state(d.linear_attention)
          for d in descs])
     window_layer = next((i for i, d in enumerate(descs)
                          if d.attention is not None
@@ -945,7 +1038,14 @@ def build_ragged_decode_step(model):
         for i, (d, lp) in enumerate(zip(descs, p["layers"])):
             att, ff = d.attention, d.feed_forward
             u = norm(x, lp["ln1_w"], lp.get("ln1_b"))
-            if att is None:
+            if d.latent_attention is not None:
+                lat = d.latent_attention
+                out, kept = _latent_attention_rows(
+                    lp, u, lat, pools[i], rows, page_ids, slots, kv_lens,
+                    full_tables, pos, rope[_rope_key(lat.rope_theta)], eps)
+                new_pools.append(kept)
+                x = x + out
+            elif att is None:
                 out, kept = _linear_attention_rows(
                     lp, u, d.linear_attention, pools[i], rows, state_slots,
                     pos, eps)
@@ -1060,6 +1160,86 @@ def _lane_state(kind: LinearAttentionKind) -> LaneState:
         (kind.heads, kind.key_dim, kind.value_dim),
         (kind.conv_kernel - 1,
          kind.heads * (2 * kind.key_dim + kind.value_dim))))
+
+
+def _latent_pages(kind: LatentAttentionKind) -> LatentPages:
+    """What a token leaves in a latent-attention layer's cache: its
+    latent and the shared rotated key in one row (:func:`_pool_width`:
+    512 + 64 = 576 is padded to 640, a ninth more pool memory and latent
+    bytes read, for a pool the compiler lays out row-major as it does
+    the key-value pools), and its index key where the layer has an
+    index."""
+    row = _pool_width(kind.kv_rank + kind.rope_dim)
+    if kind.index is None:
+        return LatentPages((row,))
+    return LatentPages((row, _pool_width(kind.index.dim)))
+
+
+def _latent_attention_rows(lp, u, kind: LatentAttentionKind, kept, rows,
+                           page_ids, slots, kv_lens, tables, pos, rope,
+                           eps: float):
+    """One latent-attention layer's mixer over a step's packed rows:
+    ``(out [rows, H], pools')`` from the layer's normed input ``u`` and
+    the layer's pools ``kept`` (:class:`LatentPages`).
+
+    The step's rows are written first — a token's normed latent and its
+    rotated shared key as ONE row of the latent pool, its index key as
+    one row of the index pool — so that a row attends itself; then
+    every row attends, in the latent (the absorbed form: a head's query
+    is carried through ``w_uk`` before the logits and the weighted sum
+    of latents through ``w_uv`` after, both inside
+    ``ops/latent_select.py``), the keys of its own sequence that its
+    index picks.  ``rope = (cos,
+    sin)`` are the step's rows of the layer's rotary tables."""
+    from ..ops import latent_select as ls
+    from ..ops.pallas import fused_decode as _fd
+    n, nh = rows.n, kind.heads
+    rank, nope, rd = kind.kv_rank, kind.nope_dim, kind.rope_dim
+    cos, sin = rope                                       # [rows, 1, rd]
+    turn = lambda a, interleaved: _fd.reference_rope_rows(
+        a, cos, sin, neox=not interleaved)
+    highest = jax.lax.Precision.HIGHEST
+    with jax.named_scope("latent_attention"):
+        cq = _fd.reference_rms_norm(jnp.matmul(u, lp["wq_a"]),
+                                    lp["q_norm_w"], eps)
+        q = jnp.matmul(cq, lp["wq_b"]).reshape(n, nh, nope + rd)
+        q_r = turn(q[..., nope:], kind.rope_interleaved)
+        kv = jnp.matmul(u, lp["wkv_a"])
+        latent = _fd.reference_rms_norm(kv[:, :rank], lp["kv_norm_w"], eps)
+        k_r = turn(kv[:, None, rank:], kind.rope_interleaved)[:, 0]
+        width = kept[0].shape[-1]
+        pools = [_scatter_pages(
+            kept[0], _pad_last(jnp.concatenate([latent, k_r], axis=-1),
+                               width)[:, None, :], page_ids, slots)]
+        index = None
+        if kind.index is not None:
+            ix = kind.index
+            with jax.named_scope("index_select"):
+                # a selection is discontinuous: what it is taken from
+                # runs at "highest", as the router's scores do
+                rot = lambda a: jnp.concatenate(
+                    [turn(a[..., :ix.rotary_dim], ix.rope_interleaved),
+                     a[..., ix.rotary_dim:]], axis=-1)
+                q_i = rot(jnp.matmul(cq, lp["wi_q"], precision=highest)
+                          .reshape(n, ix.heads, ix.dim))
+                k_i = rot(_fd.reference_layer_norm(
+                    jnp.matmul(u, lp["wi_k"], precision=highest),
+                    lp["wi_k_norm_w"], lp["wi_k_norm_b"],
+                    ix.norm_eps)[:, None, :])
+                w_i = jnp.matmul(u, lp["wi_w"], precision=highest) \
+                    * (ix.heads ** -0.5 * ix.dim ** -0.5)
+            pools.append(_scatter_pages(
+                kept[1], _pad_last(k_i, kept[1].shape[-1]), page_ids,
+                slots))
+            index = (_pad_last(q_i, kept[1].shape[-1]), w_i, pools[1],
+                     ix.top_k)
+        ctx = ls.attend_selected(
+            q[..., :nope], q_r, lp["w_uk"], lp["w_uv"], pools[0], index,
+            tables, kv_lens, pos, rows.offs, rows.q_lens, rows.lane,
+            rows.q_width, scale=1.0 / math.sqrt(nope + rd)
+        ).reshape(n, nh * kind.value_dim)
+        out = jnp.matmul(ctx, lp["wo"])
+    return out, tuple(pools)
 
 
 # rows a block of the chunked gated delta rule (ops/gated_delta.py)
@@ -1193,15 +1373,17 @@ def build_fused_window_step(model, max_window: int):
 
     params, step = build_ragged_decode_step(model)
     if step.cache.window is not None or step.cache.n_state \
-            or step.routing_counts:
+            or step.cache.n_latent or step.routing_counts:
         raise TypeError(
             f"build_fused_window_step does not take "
             f"{type(model).__name__}: the fused window derives one "
-            f"append cursor a lane from tables, so it can fill no ring "
-            f"of a window layer and reach no lane's state "
+            f"append cursor a lane from tables and reads its pools as "
+            f"key-value pairs, so it can fill no ring of a window layer, "
+            f"reach no lane's state and take no latent layer's pools "
             f"(step.cache.window = {step.cache.window}, "
-            f"step.cache.n_state = {step.cache.n_state}), and carries no "
-            f"routing counts (step.routing_counts = "
+            f"step.cache.n_state = {step.cache.n_state}, "
+            f"step.cache.n_latent = {step.cache.n_latent}), and carries "
+            f"no routing counts (step.routing_counts = "
             f"{step.routing_counts}); serve such a model with "
             f"FLAGS_serving_fused_steps=1")
 

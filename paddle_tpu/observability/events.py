@@ -130,7 +130,9 @@ EVENT_SCHEMA: Dict[str, Dict[str, str]] = {
                    "experts_hit": "int", "window_pages_read": "int",
                    "full_pages_read": "int", "attn_blocks": "int",
                    "state_lanes": "int",
-                   "state_resets": "int", "scan_rows": "int"},
+                   "state_resets": "int", "scan_rows": "int",
+                   "select_rows": "int", "keys_visible": "int",
+                   "keys_selected": "int"},
     # learned performance model lifecycle (tuning.learned): a versioned
     # model file was fitted/saved from accumulated telemetry
     "perf_model": {"action": "str", "version": "int", "heads": "object",

@@ -407,19 +407,21 @@ class ServingEngine:
                 f"prefix_caching=False")
         # the fused window (generation.build_fused_window_step) takes
         # one append cursor a lane from tables and carries no routing
-        # counts: a model with a ring, a state or an expert layer keeps
-        # the single-step path, and asking for more is refused here, to
-        # the caller, not later inside the serving loop
+        # counts, and reads its pools as key-value pairs: a model with a
+        # ring, a state, a latent or an expert layer keeps the
+        # single-step path, and asking for more is refused here, to the
+        # caller, not later inside the serving loop
         self._routed = self._step_fn.routing_counts
-        self._fusable = not slotted and not self._routed
+        self._fusable = not slotted and not self._routed \
+            and not self._cache.n_latent
         if not self._fusable and int(get_flag("serving_fused_steps")
                                      or 1) > 1:
             raise ValueError(
                 f"FLAGS_serving_fused_steps="
                 f"{get_flag('serving_fused_steps')} with "
                 f"{type(model).__name__}: the fused window takes no model "
-                f"with window attention, state or expert layers — set it "
-                f"to 1")
+                f"with window attention, state, latent or expert layers "
+                f"— set it to 1")
         self.pool = PagePool(num_pages, ps)
         self.prefix_cache = PrefixCache(self.pool) if prefix_caching \
             else None
@@ -430,10 +432,18 @@ class ServingEngine:
         self._dtype = dtype
         # the attention launches of a step, one entry a geometry:
         # ((key width, window or None), layers) — what _attn_blocks counts
-        from ..models.generation import LaneState
+        from ..models.generation import LaneState, LatentPages
         self._attn_launches = tuple(collections.Counter(
             (layer[1], layer[3]) for layer in self._cache.layers
-            if not isinstance(layer, LaneState)).items())
+            if not isinstance(layer, (LaneState, LatentPages))).items())
+        # keys a row of a latent layer's index keeps (0: no such layer,
+        # and a row attends what it sees) — what _select_counts counts by
+        self._index_topk = next(
+            (d.latent_attention.index.top_k
+             for d in cfg.description().layers
+             if d.latent_attention is not None
+             and d.latent_attention.index is not None), 0) \
+            if self._cache.n_latent else 0
         self._heads = int(cfg.description().heads) \
             if self._attn_launches else 0
         self._itemsize = jax.numpy.dtype(dtype).itemsize
@@ -532,6 +542,9 @@ class ServingEngine:
         self._state_lanes = self._state_resets = self._scan_rows = 0
         # key blocks the steps' attention kernels walked (_attn_blocks)
         self._n_attn_blocks = 0
+        # what the steps' rows asked of a latent layer's index
+        # (_select_counts)
+        self._select_rows = self._keys_visible = self._keys_selected = 0
 
     def _new_pools(self):
         """Zeroed device pools and states of the step's own geometry."""
@@ -1097,6 +1110,10 @@ class ServingEngine:
             self._scan_rows += state[2]
             blocks = self._attn_blocks(plan)
             self._n_attn_blocks += blocks
+            select = self._select_counts(plan)
+            self._select_rows += select[0]
+            self._keys_visible += select[1]
+            self._keys_selected += select[2]
             now = time.monotonic()
             for i, seq in enumerate(plan.seqs):
                 if seq.req.done:
@@ -1140,7 +1157,8 @@ class ServingEngine:
                 int(plan.q_width), plan.fed_prefill + plan.fed_decode,
                 step_s, flight.cold, 1, "single_step",
                 routing=toks[self.max_batch:], ahead=flight.ahead,
-                span=flight.span, state=state, attn_blocks=blocks)
+                span=flight.span, state=state, attn_blocks=blocks,
+                select=select)
         flight.span.end()
 
     def _state_counts(self, plan):
@@ -1156,6 +1174,25 @@ class ServingEngine:
         return (int((q > 0).sum()),
                 int(((q > 0) & (plan.kv_lens == q)).sum()),
                 int(q[q > 1].sum()))
+
+    def _select_counts(self, plan):
+        """``(select_rows, keys_visible, keys_selected)`` of ONE latent
+        layer with an index: the step's rows that saw more keys than the
+        index keeps (and so chose among them), and the keys the step's
+        rows saw and kept, summed over its real rows — a row at position
+        ``p`` sees ``p + 1`` keys and keeps ``min(top_k, p + 1)`` — from
+        the plan's lengths alone; zeros for a model with no such
+        layer."""
+        top_k = self._index_topk
+        if not top_k:
+            return 0, 0, 0
+        q = plan.q_lens.astype("int64")
+        before = plan.kv_lens.astype("int64") - q     # keys behind a chunk
+        visible = q * before + q * (q + 1) // 2
+        over = np.clip(before + q - top_k, 0, q)      # rows that chose
+        excess = over * np.maximum(before - top_k, 0) + over * (over + 1) // 2
+        return (int(over.sum()), int(visible.sum()),
+                int((visible - excess).sum()))
 
     def _pages_read(self, plan):
         """``(window_pages_read, full_pages_read)``: the pages the
@@ -1194,7 +1231,7 @@ class ServingEngine:
                          q_width, tokens, step_s, cold_start,
                          fused_steps, exit_reason, routing=(),
                          ahead=False, span=None, state=(0, 0, 0),
-                         attn_blocks=0) -> None:
+                         attn_blocks=0, select=(0, 0, 0)) -> None:
         """The step's ``batch_step`` record (under ``_wake``;
         ``phase_seconds`` from ``_LoopPhases.take``; ``span`` the step's
         own where no ambient one covers it).  step_s +
@@ -1236,7 +1273,8 @@ class ServingEngine:
                      full_pages_read=full_pages,
                      attn_blocks=attn_blocks,
                      state_lanes=state[0], state_resets=state[1],
-                     scan_rows=state[2])
+                     scan_rows=state[2], select_rows=select[0],
+                     keys_visible=select[1], keys_selected=select[2])
 
     def _run_window(self, plan, w, max_window, clamp_reason,
                     epoch: int, phases: _LoopPhases):
@@ -1693,6 +1731,9 @@ class ServingEngine:
                "state_resets": self._state_resets,  # noqa: PTL902 — advisory snapshot (see below)
                "scan_rows": self._scan_rows,  # noqa: PTL902 — advisory snapshot (see below)
                "attn_blocks": self._n_attn_blocks,  # noqa: PTL902 — advisory snapshot (see below)
+               "select_rows": self._select_rows,  # noqa: PTL902 — advisory snapshot (see below)
+               "keys_visible": self._keys_visible,  # noqa: PTL902 — advisory snapshot (see below)
+               "keys_selected": self._keys_selected,  # noqa: PTL902 — advisory snapshot (see below)
                "free_pages": self.pool.available(),  # noqa: PTL902 — advisory snapshot; the handle swaps atomically at relaunch
                "programs": len(self._programs),
                "health": self.health,

@@ -18,6 +18,7 @@ from paddle_tpu.models.llama import LlamaForCausalLM, llama_config
 from paddle_tpu.models.mimo_v2 import MiMoV2Config, MiMoV2ForCausalLM
 from paddle_tpu.models.solar_open2 import (SolarOpen2Config,
                                            SolarOpen2ForCausalLM)
+from paddle_tpu.models.glm5 import Glm5Config, Glm5ForCausalLM
 from paddle_tpu.serving import Request, ServingEngine
 from paddle_tpu.serving import engine as engine_mod
 
@@ -86,10 +87,33 @@ def solar():
     return m
 
 
+@pytest.fixture(scope="module")
+def glm():
+    """Latent attention whose index keeps 8 keys a row (a 40-wide latent
+    row and a 16-wide index key a token, no head in the cache) behind a
+    dense layer, then routed experts beside a shared one, as
+    ``tests/test_glm5_serving.py`` builds it."""
+    paddle.seed(11)
+    m = Glm5ForCausalLM(Glm5Config(
+        vocab_size=96, hidden_size=64, num_hidden_layers=2, num_heads=4,
+        q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, index_n_heads=16,
+        index_head_dim=16, index_topk=8, intermediate_size=128,
+        first_k_dense_replace=1, moe_intermediate_size=32,
+        n_routed_experts=16, num_experts_per_tok=2, held_experts=(4, 4),
+        max_position_embeddings=128))
+    rs = np.random.RandomState(2)
+    m.blocks[1].router_b.set_value(
+        rs.uniform(-.3, .3, m.blocks[1].router_b.shape).astype("float32"))
+    m.seed_index(rs)
+    m.eval()
+    return m
+
+
 @pytest.fixture
-def family(request, gpt, llama, mimo, solar):
+def family(request, gpt, llama, mimo, solar, glm):
     return {"gpt": gpt, "llama": llama, "mimo": mimo,
-            "solar": solar}[request.param]
+            "solar": solar, "glm": glm}[request.param]
 
 
 @pytest.fixture
@@ -154,7 +178,7 @@ def _prompts(model, lengths, seed=5):
 # the same tokens
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("family", ["gpt", "llama", "mimo", "solar"],
+@pytest.mark.parametrize("family", ["gpt", "llama", "mimo", "solar", "glm"],
                          indirect=True)
 @pytest.mark.parametrize("temperature", [0.0, 0.8],
                          ids=["greedy", "sampled"])
@@ -241,7 +265,8 @@ def test_a_budget_ends_at_exactly_max_new_tokens(gpt, n_new):
     assert engine.pool.available() == engine.pool.num_pages - 1
 
 
-@pytest.mark.parametrize("family", ["gpt", "mimo", "solar"], indirect=True)
+@pytest.mark.parametrize("family", ["gpt", "mimo", "solar", "glm"],
+                         indirect=True)
 def test_page_pressure_drains_evicts_and_keeps_the_tokens(family):
     """A plan made ahead never evicts: where a sequence cannot grow the
     loop commits the unread step and plans again, and that plan evicts

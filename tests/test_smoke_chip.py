@@ -302,6 +302,111 @@ def test_state_layer_compiles_for_v5e_and_updates_the_state_in_place():
     assert out["1"]["temp_bytes"] < out["1"]["state_bytes"], out
 
 
+_AOT_LATENT_LAYER = """
+import json
+import os
+import re
+import types
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+import jax
+import jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+import chip_smoke
+from paddle_tpu.models.generation import build_ragged_decode_step
+from paddle_tpu.models.glm5 import Glm5Config
+from paddle_tpu.serving import ServingEngine
+jax.config.update("jax_enable_compilation_cache", False)
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print("NO_TOPOLOGY " + repr(e))
+    raise SystemExit(0)
+one_chip = SingleDeviceSharding(topo.devices[0])
+# one latent-attention layer at the published widths (hidden 6144, 64
+# heads of 192 + 64 over a latent of 512, an index of 32 heads of 128
+# that keeps 2,048 keys) with one held expert of 256 behind a small
+# vocabulary; the parameters are shapes, nothing of their size is
+# allocated here
+cfg = Glm5Config(vocab_size=2048, num_hidden_layers=1,
+                 first_k_dense_replace=0, held_experts=(0, 1),
+                 max_position_embeddings=8192)
+h = 6144
+layer = {"ln1_w": (h,), "wq_a": (h, 2048), "q_norm_w": (2048,),
+         "wq_b": (2048, 16384), "wkv_a": (h, 576), "kv_norm_w": (512,),
+         "w_uk": (64, 192, 512), "w_uv": (64, 512, 256), "wo": (16384, h),
+         "wi_q": (2048, 4096), "wi_k": (h, 128), "wi_k_norm_w": (128,),
+         "wi_k_norm_b": (128,), "wi_w": (h, 32), "ln2_w": (h,),
+         "router_w": (h, 256), "router_b": (256,),
+         "wg": ((h, 2048),), "wu": ((h, 2048),), "wd": ((2048, h),),
+         "shared_wg": (h, 2048), "shared_wu": (h, 2048),
+         "shared_wd": (2048, h)}
+shapes = {"embed": (2048, h), "norm_w": (h,), "lm_w": (2048, h),
+          "rope": {"1e+06": ((8192, 64), (8192, 64))}, "layers": [layer]}
+is_shape = lambda x: isinstance(x, tuple) and all(
+    isinstance(v, int) for v in x)
+sds = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+    shape, dtype, sharding=one_chip)
+params = jax.tree.map(sds, shapes, is_leaf=is_shape)
+stub = types.SimpleNamespace(config=cfg, described_params=lambda: None)
+engine = ServingEngine(
+    types.SimpleNamespace(
+        config=cfg,
+        build_ragged_decode_step=lambda: build_ragged_decode_step(stub)),
+    max_batch=8, page_size=16, num_pages=4097, max_prefill_chunk=1024,
+    prefix_caching=False)
+pools = engine._pools[0]
+# the chip's routes for a program that is compiled here and run nowhere
+jax.default_backend = lambda: "tpu"
+out = {"pool_shapes": [list(a.shape) for a in pools]}
+for qw in (1, 1024):
+    args = list(chip_smoke._engine_program_args(engine, qw, one_chip))
+    args[0] = params
+    compiled = engine._program(qw).lower(*args).compile()
+    text = compiled.as_text()
+    ma = compiled.memory_analysis()
+    out[qw] = {"pool_copies": [chip_smoke._pool_copies(text, a)
+                               for a in pools],
+               "kernels": text.count("tpu_custom_call"),
+               "whiles": len(re.findall(r" while\\(", text)),
+               "temp_bytes": ma.temp_size_in_bytes,
+               "alias_bytes": ma.alias_size_in_bytes,
+               "pool_bytes": sum(a.size * 4 for a in pools)}
+print("RESULT " + json.dumps(out))
+"""
+
+
+def test_latent_layer_compiles_for_v5e_and_writes_its_pools_in_place():
+    """The engine's own programs for one latent-attention layer at the
+    published widths (a latent pool ``[1, 4097, 16, 640]`` and an index
+    pool ``[1, 4097, 16, 128]``, donated), compiled ahead of time for a
+    v5e: neither the decode-only program (index scores over the lanes'
+    gathered index keys, a top-k, 2,048 gathered rows a lane) nor the
+    Q=1024 program (blocks of 128 rows under a mask, whose loops the
+    decode-only program does not hold) copies a pool, both hand both
+    pools back in their own buffers, no Mosaic kernel is involved, and
+    the temporaries — printed — stay under the pools' size at Q=1 and
+    under 1 GB at Q=1024: nothing of ``[rows, 64, kv_len]`` or ``[rows,
+    2048, 640]`` is whole in memory."""
+    proc = _run(["-c", _AOT_LATENT_LAYER], env={"JAX_PLATFORMS": "cpu"})
+    lines = proc.stdout.strip().splitlines()
+    if lines and lines[-1].startswith("NO_TOPOLOGY"):
+        pytest.skip(f"no v5e topology can be described here: {lines[-1]}")
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+    out = json.loads(lines[-1].removeprefix("RESULT "))
+    print(out)
+    assert out["pool_shapes"] == [[1, 4097, 16, 640], [1, 4097, 16, 128]]
+    for qw in ("1", "1024"):
+        assert out[qw]["pool_copies"] == [0, 0], out
+        assert out[qw]["kernels"] == 0, out
+        # the latent rows and the index keys come back in place
+        assert out[qw]["alias_bytes"] >= out[qw]["pool_bytes"], out
+    assert out["1"]["whiles"] < out["1024"]["whiles"], out
+    assert out["1"]["temp_bytes"] < out["1"]["pool_bytes"], out
+    assert out["1024"]["temp_bytes"] < 1e9, out
+
+
 _REPORT_CACHE_DIR = """
 import jax
 import paddle_tpu
