@@ -444,27 +444,35 @@ def _pool_copies(text: str, pool) -> int:
 
 
 def _compile_serve_layer(qw: int, b, nh, nkv, hd, pages, ps, ppseq,
-                         sharding=None, hdv=None, window=None, sink=False):
+                         sharding=None, hdv=None, window=None, sink=False,
+                         rows=None):
     """One layer's attention of the ragged step (the k/v write into both
     donated pools, then the ragged kernel) compiled at chunk width
     ``qw``, for the attached device or for the described one that
     ``sharding`` names.  ``hdv``: values narrower than keys; ``window``,
     ``sink``: a window layer's kernel over a ring of ``ppseq`` pages a
-    lane.  Returns ``(compiled, pool)``, the key pool as its
-    abstract shape."""
+    lane.  ``rows``: the layer as the engine's step runs it, over that
+    many packed rows (``step_rows(qw, b)``) and not ``[b, qw]``.
+    Returns ``(compiled, pool)``, the key pool as its abstract shape."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.models.generation import _scatter_pages
-    from paddle_tpu.ops.pallas.ragged_paged_attention import _ragged_pallas
+    from paddle_tpu.ops.pallas.ragged_paged_attention import (
+        _ragged_pallas, _ragged_pallas_rows)
     hdv = hd if hdv is None else hdv
+    lead = (b, qw) if rows is None else (rows,)
 
     def layer(pools, q, k, v, page_ids, slots, kv_lens, q_lens, tables,
               sinks):
         kp = _scatter_pages(pools[0], k, page_ids, slots)
         vp = _scatter_pages(pools[1], v, page_ids, slots)
-        return _ragged_pallas(q, kp, vp, kv_lens, q_lens, tables,
-                              1.0 / math.sqrt(hd), window,
-                              sinks if sink else None), (kp, vp)
+        rest = (1.0 / math.sqrt(hd), window, sinks if sink else None)
+        if rows is None:
+            return _ragged_pallas(q, kp, vp, kv_lens, q_lens, tables,
+                                  *rest), (kp, vp)
+        offs = jnp.cumsum(q_lens) - q_lens
+        return _ragged_pallas_rows(q, kp, vp, kv_lens, q_lens, offs, tables,
+                                   qw, *rest), (kp, vp)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
@@ -472,10 +480,10 @@ def _compile_serve_layer(qw: int, b, nh, nkv, hd, pages, ps, ppseq,
     pool = sds((nkv, pages, ps, hd), jnp.float32)
     compiled = jax.jit(layer, donate_argnums=(0,)).lower(
         (pool, sds((nkv, pages, ps, hdv), jnp.float32)),
-        sds((b, qw, nh, hd), jnp.float32),
-        sds((b, qw, nkv, hd), jnp.float32),
-        sds((b, qw, nkv, hdv), jnp.float32), sds((b, qw), jnp.int32),
-        sds((b, qw), jnp.int32), sds((b,), jnp.int32),
+        sds((*lead, nh, hd), jnp.float32),
+        sds((*lead, nkv, hd), jnp.float32),
+        sds((*lead, nkv, hdv), jnp.float32), sds(lead, jnp.int32),
+        sds(lead, jnp.int32), sds((b,), jnp.int32),
         sds((b,), jnp.int32), sds((b, ppseq), jnp.int32),
         sds((nh,), jnp.float32)).compile()
     return compiled, pool

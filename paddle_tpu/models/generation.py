@@ -800,8 +800,8 @@ class _StepRows:
     the feed-forward) runs over ``n_rows`` flat rows, of which sequence
     ``b`` owns rows ``offs[b] .. offs[b] + q_lens[b] - 1``; the rest
     carry no token (token 0 at position 0, written to the sink page).
-    Only the attention kernel wants ``[B, Q]``: :meth:`to_lanes` gathers
-    q rows into its layout and :meth:`from_lanes` its output back.
+    The attention kernel takes the rows as they are, with ``offs``,
+    ``q_lens`` and ``q_width`` (``ragged_paged_attention_rows``).
 
     ``offs`` is nondecreasing, and two layouts use it: the packed one,
     ``offs = cumsum(q_lens) - q_lens`` in as many rows as the step's
@@ -809,34 +809,15 @@ class _StepRows:
     rows."""
 
     def __init__(self, n_rows: int, offs, q_lens, q_width: int):
-        i32 = jnp.int32
+        from ..ops.pallas.ragged_paged_attention import row_lanes
         self.n, self.q_width = int(n_rows), int(q_width)
-        self.offs = offs.astype(i32)
-        self.q_lens = q_lens.astype(i32)
-        row = jnp.arange(self.n, dtype=i32)
-        # a row's sequence is the last one that starts at or before it
-        # (an empty sequence starts where the next one does and owns no
+        self.offs = offs.astype(jnp.int32)
+        self.q_lens = q_lens.astype(jnp.int32)
+        # a row's sequence and its index in that sequence's chunk, as
+        # the attention launch reads them (an empty sequence owns no
         # row: the test of ``valid`` leaves it out)
-        self.lane = jnp.sum(row[:, None] >= self.offs[None, :], axis=1,
-                            dtype=i32) - i32(1)
-        self.at = row - self.offs[self.lane]       # index in its chunk
+        self.lane, self.at = row_lanes(self.offs, self.n)
         self.valid = self.at < self.q_lens[self.lane]
-
-    def to_lanes(self, x):
-        """``x [rows, ...]`` -> ``[B, Q, ...]``; a slot past ``q_lens``
-        holds some other row, which the kernel never reads into a valid
-        result."""
-        idx = jnp.minimum(
-            self.offs[:, None]
-            + jnp.arange(self.q_width, dtype=jnp.int32)[None, :],
-            jnp.int32(self.n - 1))
-        return x[idx]
-
-    def from_lanes(self, y):
-        """``y [B, Q, ...]`` -> ``[rows, ...]`` (rows that carry no token
-        take a finite row of ``y``)."""
-        return y[self.lane, jnp.minimum(self.at,
-                                        jnp.int32(self.q_width - 1))]
 
     def of_lanes(self, per_lane):
         """``per_lane [B, ...]`` -> ``[rows, ...]``: each row its
@@ -906,9 +887,9 @@ def build_ragged_decode_step(model):
     the same order as ``attend_cache_append``.
 
     Inside, a step's tokens are flat rows (:class:`_StepRows`): the
-    matmuls, norms, rotary and k/v write run over the rows, and only
-    the attention kernel sees ``[B, Q]``.  The call above runs the body
-    at ``B * Q`` rows; the serving engine calls the same body through
+    matmuls, norms, rotary and k/v write run over the rows, and the
+    attention kernel's grid is their live tiles.  The call above runs the
+    body at ``B * Q`` rows; the serving engine calls the same body through
     ``step.packed(params, tok [rows], pos [rows], pools, page_ids
     [rows], slots [rows], kv_lens, q_lens, tables, q_width)`` with the
     sequences' tokens packed one behind the other, so that a step with
@@ -966,7 +947,8 @@ def build_ragged_decode_step(model):
     (max over layers) and the held experts with at least one row summed
     over layers."""
     from ..ops.pallas import fused_decode as _fd
-    from ..ops.pallas.ragged_paged_attention import ragged_paged_attention
+    from ..ops.pallas.ragged_paged_attention import \
+        ragged_paged_attention_rows
     from ..ops.routed_experts import held_experts_swiglu, \
         sigmoid_topk_route
 
@@ -1071,11 +1053,12 @@ def build_ragged_decode_step(model):
                 kpg = _scatter_pages(pools[i][0], kp, ids, sl)
                 vpg = _scatter_pages(pools[i][1], vp, ids, sl)
                 new_pools.append((kpg, vpg))
-                ctx = rows.from_lanes(ragged_paged_attention(
-                    rows.to_lanes(qp), kpg, vpg, kv_lens, q_lens, tb,
-                    scale=1.0 / math.sqrt(dk), window=att.window,
+                ctx = ragged_paged_attention_rows(
+                    qp, kpg, vpg, kv_lens, q_lens, rows.offs, tb,
+                    rows.q_width, scale=1.0 / math.sqrt(dk),
+                    window=att.window,
                     sinks=lp["sink"] if att.sink else None,
-                    precision=kernel_precision))
+                    precision=kernel_precision)
                 ctx = ctx[..., :dv].reshape(-1, nh * dv)
                 if att.gate:
                     with jax.named_scope("attention_gate"):

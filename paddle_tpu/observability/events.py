@@ -129,6 +129,7 @@ EVENT_SCHEMA: Dict[str, Dict[str, str]] = {
                    "expert_rows": "int", "expert_rows_max": "int",
                    "experts_hit": "int", "window_pages_read": "int",
                    "full_pages_read": "int", "attn_blocks": "int",
+                   "attn_tiles": "int", "attn_tile_slots": "int",
                    "state_lanes": "int",
                    "state_resets": "int", "scan_rows": "int",
                    "select_rows": "int", "keys_visible": "int",

@@ -9,24 +9,34 @@ decodes exactly one token), and each sequence's KV context is a
 different length scattered across fixed-size cache pages.  The
 reference ecosystem serves this with block_multihead_attention +
 separate prefill/decode kernels; the TPU-native shape is a single
-launch whose grid walks (sequence, query tile) with the per-sequence
-lengths and page tables riding as scalar-prefetch refs.  The page pools
-stay in HBM, and the walk over a sequence's keys is a loop INSIDE the
-kernel: a tile copies its sequence's OWN pages, named by its row of the
-page table, a block of ``_KEYS_PER_STEP`` keys at a time into one of
-two VMEM buffers (the next block's copies start before the current
-block is waited for), from the sequence's first key to the block that
-holds the tile's last row's own position and no further — so copies and
-dot-product FLOPs of wildly different context lengths cost only their
-own pages.  The grid has no page axis: a tile of padding rows and an
-idle lane cost one grid step that starts no copy and writes zeros.
+launch whose grid walks the step's LIVE QUERY TILES — ``block_q`` rows
+of one sequence each, a sequence's tiles one behind the other — with the
+per-sequence lengths, the page tables and the tile list (tile -> its
+sequence and its first row) riding as scalar-prefetch refs.  The tile
+list is made inside the jitted launch from ``q_lens`` alone, and its
+length is static: ``min(B * ceil(Q / block_q), ceil(rows / block_q) +
+B)`` slots, of which those past the live tiles start no copy and write
+zeros.  The page pools stay in HBM, and the walk over a sequence's keys
+is a loop INSIDE the kernel: a tile copies its sequence's OWN pages,
+named by its row of the page table, a block of ``_KEYS_PER_STEP`` keys
+at a time into one of two VMEM buffers (the next block's copies start
+before the current block is waited for), from the sequence's first key
+to the block that holds the tile's last row's own position and no
+further — so copies and dot-product FLOPs of wildly different context
+lengths cost only their own pages, and a step of one wide chunk beside
+decoding lanes costs the tiles of its own rows: no array of q, of the
+output or of anything else is ``B x Q`` rows tall.
 
-Layout:
+Layout (:func:`ragged_paged_attention_rows`, the serving step's call):
 
-* ``q [B, Q, nh, hd]`` — per-sequence query chunks, padded to the
-  batch's widest chunk ``Q`` (decode rows use 1 of it, prefill rows up
-  to all of it).  Query token ``i`` of sequence ``b`` sits at absolute
-  position ``kv_lens[b] - q_lens[b] + i``.
+* ``q [rows, nh, hd]`` — the step's PACKED rows: sequence ``b`` owns
+  rows ``offs[b] .. offs[b] + q_lens[b] - 1`` (``offs i32[B]``
+  nondecreasing; decode sequences own 1 row, a prefill chunk up to the
+  static ``q_width``), the rest carry no token.  Query token ``i`` of
+  sequence ``b`` sits at absolute position ``kv_lens[b] - q_lens[b] +
+  i``.  The launch gathers the rows into ``[slots, nh, block_q, hd]``
+  tiles (heads-major a tile) and its ``[slots, nh, block_q, hdv]``
+  output back into rows: ``slots * block_q`` rows each way.
 * ``k_pages/v_pages [nkv, P, ps, hd]`` — the shared page pools, new
   tokens already appended (the engine scatters k/v BEFORE attending,
   mirroring ``attend_cache_append``).
@@ -35,14 +45,17 @@ Layout:
   page ids (slots past its length may point anywhere mapped; they are
   masked by ``kv_lens``).
 
-Returns ``[B, Q, nh, hd]``; rows ``i >= q_lens[b]`` are padding and
-undefined (finite, never NaN — a zero-context row is exactly zero).
+Returns ``[rows, nh, hd]``; a row that carries no token is exactly zero.
+:func:`ragged_paged_attention` is the same launch for ``q [B, Q, nh,
+hd]`` (per-sequence chunks padded to the batch's widest chunk ``Q``:
+``offs[b] = b * Q``) and the twin of the jnp oracle; it returns ``[B, Q,
+nh, hd]`` with rows ``i >= q_lens[b]`` zero.
 
 Three optional extensions, each off by default and each leaving the
 plain causal call exactly the program it was:
 
 * values narrower than keys — ``v_pages [nkv, P, ps, hdv]`` with
-  ``hdv != hd``; the result is then ``[B, Q, nh, hdv]``;
+  ``hdv != hd``; the result's last axis is then ``hdv``;
 * ``sinks f32[nh]`` — a learned logit per query head that joins the
   softmax's denominator and carries no value:
   ``p_j = exp(a_j) / (sum_visible exp(a_k) + exp(s_h))``.  In the
@@ -82,8 +95,8 @@ from jax.experimental.pallas import tpu as pltpu
 from ...flags import get_flag
 from . import kernel_enabled
 
-__all__ = ["ragged_paged_attention", "ragged_paged_attention_ref",
-           "append_positions", "available"]
+__all__ = ["ragged_paged_attention", "ragged_paged_attention_rows",
+           "ragged_paged_attention_ref", "append_positions", "available"]
 
 
 def append_positions(kv_lens, tables, live, page_size, sink):
@@ -307,7 +320,29 @@ def walk_blocks(kv_lens, q_lens, qw: int, nh: int, hd: int, itemsize: int,
     return int(blocks.sum())
 
 
-def _ragged_kernel(kv_lens_ref, q_lens_ref, tables_ref, q_ref, k_hbm, v_hbm,
+def _tile_slots(lanes: int, rows: int, qw: int, bq: int) -> int:
+    """Grid steps of a launch over ``rows`` packed rows: every tile of
+    ``bq`` rows of one sequence that ``lanes`` sequences of at most
+    ``qw`` rows each can make of them — ``sum(ceil(q_lens / bq)) <=
+    rows // bq + lanes`` — and never more than a tile a ``bq`` rows of
+    every lane's ``qw``.  Static: from shapes alone."""
+    return min(lanes * -(-qw // bq), -(-rows // bq) + lanes)
+
+
+def launch_tiles(q_lens, rows: int, qw: int, nh: int, hd: int, itemsize: int,
+                 page_size: int, ppseq: int, window=None):
+    """``(live tiles, grid steps)`` of one launch over ``rows`` packed
+    rows, by the kernel's own ``_tiling``, in numpy on the host: what
+    the serving engine's ``attn_tiles`` and ``attn_tile_slots`` count a
+    layer.  Their ratio is how much of the grid works."""
+    bq, _ = _tiling(qw, nh, hd, itemsize, page_size, ppseq, window)
+    q_lens = np.asarray(q_lens, np.int64)
+    return (int((-(-q_lens // bq)).sum()),
+            _tile_slots(len(q_lens), int(rows), int(qw), bq))
+
+
+def _ragged_kernel(kv_lens_ref, q_lens_ref, tables_ref, tiles_ref, q_ref,
+                   k_hbm, v_hbm,
                    *rest, n_kv: int, n_rep: int, block_q: int,
                    page_size: int, group: int, scale: float, window,
                    has_sink: bool, precision=None):
@@ -317,17 +352,17 @@ def _ragged_kernel(kv_lens_ref, q_lens_ref, tables_ref, q_ref, k_hbm, v_hbm,
         sink_ref, *rest = rest
     o_ref, acc_ref, m_ref, d_ref, k_buf, v_buf, sems = rest
     keys = group * page_size
-    b = pl.program_id(0)
-    t = pl.program_id(1)
+    t = pl.program_id(0)
     nh = n_kv * n_rep
     rows = n_rep * block_q               # flat (head, row) of a kv head
+    b = tiles_ref[0, t]                  # the tile's sequence
+    q0 = tiles_ref[1, t]                 # its first row in the chunk
     kv_len = kv_lens_ref[b]
     q_len = q_lens_ref[b]
-    q0 = jnp.int32(block_q) * t          # first query row of this tile
     # the walk ends at the tile's last visible key and is empty for a
-    # tile past q_len (pure padding — a decode lane in a prefill-wide
-    # step walks one tile) — so copies and compute scale with the
-    # sequence's OWN lengths, not the padded maxima
+    # slot past the step's live tiles (its q0 lies past every q_len) —
+    # so copies and compute scale with the sequence's OWN lengths, and
+    # the grid with the step's own rows
     first, n_blocks = _walk(kv_len, q_len, q0, block_q, page_size, group,
                             window)
 
@@ -413,7 +448,7 @@ def _ragged_kernel(kv_lens_ref, q_lens_ref, tables_ref, q_ref, k_hbm, v_hbm,
         return carry
 
     @pl.when(n_blocks == 0)
-    def _padding_tile():
+    def _empty_slot():
         # no copy, no turn: a zero-context row is exactly zero
         o_ref[...] = jnp.zeros_like(o_ref)
 
@@ -436,22 +471,49 @@ def _ragged_kernel(kv_lens_ref, q_lens_ref, tables_ref, q_ref, k_hbm, v_hbm,
         o_ref[0] = out.reshape(nh, block_q, -1).astype(o_ref.dtype)
 
 
+def row_lanes(offs, n_rows: int):
+    """``(lane, at)`` of each of ``n_rows`` packed rows: the sequence
+    that owns it — the last one that starts at or before it; an empty
+    sequence starts where the next one does and owns no row — and its
+    index in that sequence's chunk, which is ``>= q_lens[lane]`` for a
+    row that carries no token."""
+    row = jnp.arange(n_rows, dtype=jnp.int32)
+    lane = jnp.sum(row[:, None] >= offs[None, :], axis=1,
+                   dtype=jnp.int32) - jnp.int32(1)
+    return lane, row - offs[lane]
+
+
 def _ragged_pallas(q, k_pages, v_pages, kv_lens, q_lens, page_tables,
                    scale, window=None, sinks=None, precision=None):
+    """The kernel over a ``[B, Q]`` step: the packed launch with
+    sequence ``b``'s rows at ``offs[b] = b * Q``."""
+    b, qw, nh, hd = q.shape
+    out = _ragged_pallas_rows(
+        q.reshape(b * qw, nh, hd), k_pages, v_pages, kv_lens, q_lens,
+        jnp.arange(b, dtype=jnp.int32) * jnp.int32(qw), page_tables, qw,
+        scale, window, sinks, precision)
+    return out.reshape(b, qw, nh, -1)
+
+
+def _ragged_pallas_rows(q, k_pages, v_pages, kv_lens, q_lens, offs,
+                        page_tables, q_width, scale, window=None,
+                        sinks=None, precision=None):
     """The kernel's launch, as a jitted function of its own: a step
     calls it once a layer, and the layers of one geometry then share
     one trace and one lowering to Mosaic, a program at a time, cached
     or not."""
-    return _ragged_call(q, k_pages, v_pages, kv_lens, q_lens, page_tables,
-                        sinks, scale=float(scale),
+    return _ragged_call(q, k_pages, v_pages, kv_lens, q_lens, offs,
+                        page_tables, sinks, q_width=int(q_width),
+                        scale=float(scale),
                         window=None if window is None else int(window),
                         precision=precision, interpret=_interpret())
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "window", "precision",
-                                             "interpret"))
-def _ragged_call(q, k_pages, v_pages, kv_lens, q_lens, page_tables, sinks,
-                 *, scale, window, precision, interpret):
+@functools.partial(jax.jit, static_argnames=("q_width", "scale", "window",
+                                             "precision", "interpret"))
+def _ragged_call(q, k_pages, v_pages, kv_lens, q_lens, offs, page_tables,
+                 sinks, *, q_width, scale, window, precision, interpret):
+    i32 = jnp.int32
     hdv = v_pages.shape[-1]
     # a copy out of a pool moves whole 128-lane tiles (Mosaic refuses a
     # slice 96 wide): heads of another width are padded for the call,
@@ -459,45 +521,57 @@ def _ragged_call(q, k_pages, v_pages, kv_lens, q_lens, page_tables, sinks,
     # (ROADMAP S15); the zeros add nothing to q.k
     q, k_pages, v_pages = (
         a if a.shape[-1] % 128 == 0 else jnp.pad(
-            a, ((0, 0),) * 3 + ((0, -a.shape[-1] % 128),))
+            a, ((0, 0),) * (a.ndim - 1) + ((0, -a.shape[-1] % 128),))
         for a in (q, k_pages, v_pages))
-    b, qw, nh, hd = q.shape
+    n_rows, nh, hd = q.shape
     nkv, _, ps, _ = k_pages.shape
     hdp = v_pages.shape[-1]              # the values' width as copied
-    ppseq = page_tables.shape[1]
-    bq, group = _tiling(qw, nh, hd, q.dtype.itemsize, ps, ppseq, window)
-    qp = -(-qw // bq) * bq
-    # heads-major [B, nh, Q, hd]: the kernel collapses (n_rep, block_q)
-    # into flat rows without an in-kernel transpose
-    qt = jnp.swapaxes(q, 1, 2)
-    if qp != qw:
-        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, qp - qw), (0, 0)))
+    b, ppseq = page_tables.shape
+    bq, group = _tiling(q_width, nh, hd, q.dtype.itemsize, ps, ppseq, window)
+    n_slots = _tile_slots(b, n_rows, q_width, bq)
+    kv_lens, q_lens, offs = (a.astype(i32) for a in (kv_lens, q_lens, offs))
+    # the step's live tiles, a lane's one behind the other: slot i is
+    # tile (i - first[lane]) of the lane whose tiles it falls among, and
+    # a slot past them all starts at a row no chunk has (an empty slot:
+    # no copy, zeros out) and names the q block held already
+    tiles = -(-q_lens // i32(bq))
+    ends = jnp.cumsum(tiles, dtype=i32)
+    first = ends - tiles
+    slot = jnp.arange(n_slots, dtype=i32)
+    lane = jnp.minimum(jnp.sum(slot[:, None] >= ends[None, :], axis=1,
+                               dtype=i32), i32(b - 1))
+    live = slot < ends[-1]
+    q0 = jnp.where(live, (slot - first[lane]) * i32(bq),
+                   i32(-(-q_width // bq) * bq))
+    held = jnp.minimum(slot, jnp.maximum(ends[-1] - 1, 0))
+    # rows into tiles, heads-major a tile [T, nh, bq, hd]: the kernel
+    # collapses (n_rep, block_q) into flat rows without an in-kernel
+    # transpose.  A tile's rows past its chunk hold some other row,
+    # which the kernel never reads into a valid result
+    idx = jnp.minimum((offs[lane] + q0)[:, None]
+                      + jnp.arange(bq, dtype=i32)[None, :], i32(n_rows - 1))
+    qt = jnp.swapaxes(q[idx], 1, 2)
     if window is None and ppseq % group:
         # whole blocks: the added entries lie past every context
         page_tables = jnp.pad(
             page_tables, ((0, 0), (0, group - ppseq % group)), mode="edge")
 
-    def q_map(i, t, kl, ql, tb):
-        # a tile of padding rows names the lane's last real tile, which
-        # is held already: it copies no q
-        return (i, 0, jnp.minimum(
-            t, jnp.maximum(ql[i] - 1, 0) // jnp.int32(bq)), 0)
-
-    in_specs = [pl.BlockSpec((1, nh, bq, hd), q_map),
+    in_specs = [pl.BlockSpec((1, nh, bq, hd),
+                             lambda t, kl, ql, tb, tl: (tl[2, t], 0, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY)]
     operands = [qt, k_pages, v_pages]
     if sinks is not None:
         # one logit per flat (head, row) of the tile
         in_specs.append(pl.BlockSpec(
-            (nh * bq, 1), lambda i, t, kl, ql, tb: (0, 0)))
+            (nh * bq, 1), lambda t, kl, ql, tb, tl: (0, 0)))
         operands.append(jnp.repeat(sinks.astype(jnp.float32), bq)[:, None])
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, qp // bq),
+        num_scalar_prefetch=4,
+        grid=(n_slots,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, nh, bq, hdp),
-                               lambda i, t, kl, ql, tb: (i, 0, t, 0)),
+                               lambda t, kl, ql, tb, tl: (t, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((nh * bq, hdp), jnp.float32),  # acc
             pltpu.VMEM((nh * bq, 1), jnp.float32),    # running max
@@ -518,13 +592,26 @@ def _ragged_call(q, k_pages, v_pages, kv_lens, q_lens, page_tables, sinks,
                               has_sink=sinks is not None,
                               precision=precision),
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, nh, qp, hdp), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((n_slots, nh, bq, hdp), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_VMEM_LIMIT),
             interpret=interpret,
-        )(kv_lens.astype(jnp.int32), q_lens.astype(jnp.int32),
-          page_tables.astype(jnp.int32), *operands)
-    return jnp.swapaxes(out[:, :, :qw, :hdv], 1, 2)
+        )(kv_lens, q_lens, page_tables.astype(i32),
+          jnp.stack([lane, q0, held]), *operands)
+    # tiles back into rows: row r is row (at % bq) of its lane's tile
+    # at // bq, and a row that carries no token is zero
+    row_lane, at = row_lanes(offs, n_rows)
+    src = jnp.minimum(first[row_lane] + at // i32(bq), i32(n_slots - 1)) \
+        * i32(bq) + at % i32(bq)
+    rows = jnp.swapaxes(out, 1, 2).reshape(n_slots * bq, nh, hdp)[
+        src, :, :hdv]
+    return jnp.where((at < q_lens[row_lane])[:, None, None], rows,
+                     jnp.zeros((), rows.dtype))
+
+
+def _kernel_takes(q, k_pages, v_pages) -> bool:
+    return available() and q.shape[-2] % k_pages.shape[0] == 0 \
+        and q.shape[-1] % 8 == 0 and v_pages.shape[-1] % 8 == 0
 
 
 def ragged_paged_attention(q, k_pages, v_pages, kv_lens, q_lens,
@@ -540,18 +627,47 @@ def ragged_paged_attention(q, k_pages, v_pages, kv_lens, q_lens,
     dot products (None: Mosaic's default, one bf16 pass of float32
     operands; ``jax.lax.Precision.HIGHEST``: float32 products — Mosaic
     takes no "high").  Routes to the Pallas kernel when
-    available (TPU, or CPU interpret mode), else the jnp reference —
-    both produce the eager sdpa numerics on the valid rows
-    (``i < q_lens[b]``)."""
-    hd = q.shape[-1]
-    nh, nkv = q.shape[2], k_pages.shape[0]
+    available (TPU, or CPU interpret mode) — the packed launch of
+    :func:`ragged_paged_attention_rows` with sequence ``b``'s rows at
+    ``b * Q`` — else the jnp reference; both produce the eager sdpa
+    numerics on the valid rows (``i < q_lens[b]``)."""
     if scale is None:
-        scale = 1.0 / math.sqrt(hd)
-    if available() and nh % nkv == 0 and hd % 8 == 0 \
-            and v_pages.shape[-1] % 8 == 0:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    if _kernel_takes(q, k_pages, v_pages):
         return _ragged_pallas(q, k_pages, v_pages, kv_lens, q_lens,
                               page_tables, scale, window, sinks,
                               precision)
     return ragged_paged_attention_ref(q, k_pages, v_pages, kv_lens,
                                       q_lens, page_tables, scale, window,
                                       sinks)
+
+
+def ragged_paged_attention_rows(q, k_pages, v_pages, kv_lens, q_lens, offs,
+                                page_tables, q_width: int, scale=None,
+                                window=None, sinks=None, precision=None):
+    """:func:`ragged_paged_attention` over a step's PACKED rows, as the
+    serving step holds them: ``q [rows, nh, hd]`` in which sequence
+    ``b`` owns rows ``offs[b] .. offs[b] + q_lens[b] - 1`` (``offs``
+    nondecreasing, ``q_lens <= q_width``, static) → ``[rows, nh, hdv]``.
+    The kernel's grid is the step's live tiles, so nothing here is
+    ``B * q_width`` rows tall; only the jnp reference, the route where
+    the kernel is not available, lays the rows out as ``[B, Q]``."""
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    if _kernel_takes(q, k_pages, v_pages):
+        return _ragged_pallas_rows(q, k_pages, v_pages, kv_lens, q_lens,
+                                   offs, page_tables, q_width, scale,
+                                   window, sinks, precision)
+    n_rows = q.shape[0]
+    offs = offs.astype(jnp.int32)
+    lanes = jnp.minimum(
+        offs[:, None] + jnp.arange(q_width, dtype=jnp.int32)[None, :],
+        jnp.int32(n_rows - 1))
+    out = ragged_paged_attention_ref(q[lanes], k_pages, v_pages, kv_lens,
+                                     q_lens, page_tables, scale, window,
+                                     sinks)
+    lane, at = row_lanes(offs, n_rows)
+    return jnp.where(
+        (at < q_lens.astype(jnp.int32)[lane])[:, None, None],
+        out[lane, jnp.minimum(at, jnp.int32(q_width - 1))],
+        jnp.zeros((), out.dtype))

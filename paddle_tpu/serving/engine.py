@@ -431,7 +431,8 @@ class ServingEngine:
         self._num_pages, self._page_size = int(num_pages), ps
         self._dtype = dtype
         # the attention launches of a step, one entry a geometry:
-        # ((key width, window or None), layers) — what _attn_blocks counts
+        # ((key width, window or None), layers) — what _attn_blocks and
+        # _attn_tiles count
         from ..models.generation import LaneState, LatentPages
         self._attn_launches = tuple(collections.Counter(
             (layer[1], layer[3]) for layer in self._cache.layers
@@ -540,8 +541,11 @@ class ServingEngine:
         self._n_drained = 0
         # what the steps asked of the state layers (_state_counts)
         self._state_lanes = self._state_resets = self._scan_rows = 0
-        # key blocks the steps' attention kernels walked (_attn_blocks)
+        # key blocks the steps' attention kernels walked (_attn_blocks),
+        # their live query tiles and their grid steps (_attn_tiles)
         self._n_attn_blocks = 0
+        self._n_attn_tiles = 0
+        self._n_attn_tile_slots = 0
         # what the steps' rows asked of a latent layer's index
         # (_select_counts)
         self._select_rows = self._keys_visible = self._keys_selected = 0
@@ -1110,6 +1114,9 @@ class ServingEngine:
             self._scan_rows += state[2]
             blocks = self._attn_blocks(plan)
             self._n_attn_blocks += blocks
+            tiles = self._attn_tiles(plan)
+            self._n_attn_tiles += tiles[0]
+            self._n_attn_tile_slots += tiles[1]
             select = self._select_counts(plan)
             self._select_rows += select[0]
             self._keys_visible += select[1]
@@ -1158,7 +1165,7 @@ class ServingEngine:
                 step_s, flight.cold, 1, "single_step",
                 routing=toks[self.max_batch:], ahead=flight.ahead,
                 span=flight.span, state=state, attn_blocks=blocks,
-                select=select)
+                attn_tiles=tiles, select=select)
         flight.span.end()
 
     def _state_counts(self, plan):
@@ -1227,11 +1234,31 @@ class ServingEngine:
             for (width, window), layers in self._attn_launches
             for j in range(steps))
 
+    def _attn_tiles(self, plan, steps: int = 1):
+        """``(attn_tiles, attn_tile_slots)``: the query tiles this step's
+        attention launches work on and the grid steps they have, summed
+        over layers, by the kernel's own tiling
+        (``ragged_paged_attention.launch_tiles``) on the plan's
+        ``q_lens`` and the program's rows: host arithmetic.  Their ratio
+        is how much of the grid works.  ``steps``: a fused window's
+        iterations, each the same launches."""
+        from ..ops.pallas.ragged_paged_attention import launch_tiles
+        tiles = slots = 0
+        for (width, window), layers in self._attn_launches:
+            live, grid = launch_tiles(
+                plan.q_lens, plan.rows, int(plan.q_width), self._heads,
+                width, self._itemsize, self._page_size, self._ring_pages,
+                window)
+            tiles += layers * steps * live
+            slots += layers * steps * grid
+        return tiles, slots
+
     def _emit_batch_step(self, phase_seconds, plan, prefill_seqs,
                          q_width, tokens, step_s, cold_start,
                          fused_steps, exit_reason, routing=(),
                          ahead=False, span=None, state=(0, 0, 0),
-                         attn_blocks=0, select=(0, 0, 0)) -> None:
+                         attn_blocks=0, attn_tiles=(0, 0),
+                         select=(0, 0, 0)) -> None:
         """The step's ``batch_step`` record (under ``_wake``;
         ``phase_seconds`` from ``_LoopPhases.take``; ``span`` the step's
         own where no ambient one covers it).  step_s +
@@ -1272,6 +1299,8 @@ class ServingEngine:
                      window_pages_read=window_pages,
                      full_pages_read=full_pages,
                      attn_blocks=attn_blocks,
+                     attn_tiles=attn_tiles[0],
+                     attn_tile_slots=attn_tiles[1],
                      state_lanes=state[0], state_resets=state[1],
                      scan_rows=state[2], select_rows=select[0],
                      keys_visible=select[1], keys_selected=select[2])
@@ -1391,11 +1420,14 @@ class ServingEngine:
             self._g_occ.set(len(self.scheduler.running))
             blocks = self._attn_blocks(plan, steps)
             self._n_attn_blocks += blocks
+            tiles = self._attn_tiles(plan, steps)
+            self._n_attn_tiles += tiles[0]
+            self._n_attn_tile_slots += tiles[1]
             self._emit_batch_step(
                 phases.take(front), plan, 0, 1, fed, step_timer.seconds,
                 cold_start, steps,
                 "finished" if any_finished else clamp_reason,
-                attn_blocks=blocks)
+                attn_blocks=blocks, attn_tiles=tiles)
 
     def _cache_prompt(self, seq):
         """Share the finished prompt's full pages through the prefix
@@ -1731,6 +1763,8 @@ class ServingEngine:
                "state_resets": self._state_resets,  # noqa: PTL902 — advisory snapshot (see below)
                "scan_rows": self._scan_rows,  # noqa: PTL902 — advisory snapshot (see below)
                "attn_blocks": self._n_attn_blocks,  # noqa: PTL902 — advisory snapshot (see below)
+               "attn_tiles": self._n_attn_tiles,  # noqa: PTL902 — advisory snapshot (see below)
+               "attn_tile_slots": self._n_attn_tile_slots,  # noqa: PTL902 — advisory snapshot (see below)
                "select_rows": self._select_rows,  # noqa: PTL902 — advisory snapshot (see below)
                "keys_visible": self._keys_visible,  # noqa: PTL902 — advisory snapshot (see below)
                "keys_selected": self._keys_selected,  # noqa: PTL902 — advisory snapshot (see below)

@@ -300,25 +300,155 @@ def _pallas_calls(jaxpr):
     (64, 8, 128, 128, 4097, 512, None, 64)],
     ids=["batch", "longgen-full", "longgen-window", "longdoc"])
 @pytest.mark.parametrize("qw", [1, 1024])
-def test_the_grid_is_lanes_by_query_tiles_and_the_pools_stay_in_hbm(
+def test_the_grid_is_the_steps_live_tiles_and_the_pools_stay_in_hbm(
         nh, nkv, hd, hdv, pages, ppseq, window, bq, qw):
-    """No page axis: one grid step a (lane, query tile), whatever the
-    table's length, and one launch that takes each pool whole, in any
-    memory space, beside q and the three prefetched arrays."""
+    """No page axis and no lane axis: one grid step a tile slot of the
+    step's packed rows (8 for a decode step; ``ceil(1032 / bq) + 8`` for
+    a 1,024-row chunk beside seven decoding lanes, where lanes x q tiles
+    would be ``8 * 1024 / bq``), whatever the table's length, and one
+    launch that takes each pool whole, in any memory space, beside q and
+    the four prefetched arrays.  The output is ``[slots, nh, bq, hdv]``:
+    4-dimensional with a tile's rows third, which is how the
+    benchmark's readers tell a decode step's launch (``dims[2] <= 8``)
+    from a wide one's."""
     f32, i32 = jnp.float32, jnp.int32
     sds = jax.ShapeDtypeStruct
+    rows = 8 if qw == 1 else 1032
     jaxpr = jax.make_jaxpr(functools.partial(
-        rpa._ragged_call, scale=0.1, window=window, precision=None,
-        interpret=False))(
-        sds((8, qw, nh, hd), f32), sds((nkv, pages, 16, hd), f32),
+        rpa._ragged_call, q_width=qw, scale=0.1, window=window,
+        precision=None, interpret=False))(
+        sds((rows, nh, hd), f32), sds((nkv, pages, 16, hd), f32),
         sds((nkv, pages, 16, hdv), f32), sds((8,), i32), sds((8,), i32),
-        sds((8, ppseq), i32), None)
+        sds((8,), i32), sds((8, ppseq), i32), None)
     [call] = _pallas_calls(jaxpr.jaxpr)
     grid = call.params["grid_mapping"]
-    assert grid.grid == (8, 1 if qw == 1 else 1024 // bq)
-    assert len(call.invars) == 6
+    slots = 8 if qw == 1 else -(-1032 // bq) + 8
+    assert slots == {1: 8, 128: 17, 32: 41, 64: 25}[1 if qw == 1 else bq]
+    assert grid.grid == (slots,)
+    assert len(call.invars) == 7
     blocks = [str(b.transformed_block_aval) for b in grid.block_mappings]
     assert blocks[1] == f"Ref<any>{{float32[{nkv},{pages},16,{hd}]}}"
     assert blocks[2] == f"Ref<any>{{float32[{nkv},{pages},16,{hdv}]}}"
-    assert call.outvars[0].aval.shape == (8, nh, 8 if qw == 1 else 1024,
+    assert call.outvars[0].aval.shape == (slots, nh, 8 if qw == 1 else bq,
                                           hdv)
+    assert rpa.launch_tiles([1024] + [1] * 7 if qw > 1 else [1] * 8, rows,
+                            qw, nh, hd, 4, 16, ppseq, window) \
+        == (8 if qw == 1 else 1024 // bq + 7, slots)
+
+
+# ---------------------------------------------------------------------------
+# the packed launch: the grid is the step's live tiles
+# ---------------------------------------------------------------------------
+
+def _packed_against_reference(rng, before, q_lens, qw, n_rows, nh=4, nkv=2,
+                              hd=24, hdv=16, window=None, sink=False):
+    """``ragged_paged_attention_rows`` through the kernel on ``n_rows``
+    packed rows — sequence ``b`` feeds ``q_lens[b]`` rows behind a
+    context of ``before[b]`` keys — against ``ragged_paged_attention_ref``
+    on the same rows laid out ``[B, Q]``."""
+    ps, b = 16, len(q_lens)
+    kv_lens = [c + n for c, n in zip(before, q_lens)]
+    ppseq = -(-max(kv_lens) // ps) + 1
+    k, v, tables, _, _ = _paged(rng, kv_lens, ps, ppseq, nkv, hd, hdv)
+    q = rng.randn(n_rows, nh, hd).astype("float32")
+    offs = np.cumsum(q_lens) - np.asarray(q_lens)
+    sinks = jnp.asarray(rng.randn(nh) * 2.0, jnp.float32) if sink else None
+    i32 = jnp.int32
+    shared = (jnp.asarray(k), jnp.asarray(v), jnp.asarray(kv_lens, i32),
+              jnp.asarray(q_lens, i32))
+    assert rpa.available() and sum(q_lens) <= n_rows and max(q_lens) <= qw
+    got = np.asarray(rpa.ragged_paged_attention_rows(
+        jnp.asarray(q), *shared, jnp.asarray(offs, i32),
+        jnp.asarray(tables), qw, window=window, sinks=sinks))
+    assert got.shape == (n_rows, nh, hdv)
+    lanes = np.zeros((b, qw, nh, hd), "float32")
+    for i, n in enumerate(q_lens):
+        lanes[i, :n] = q[offs[i]:offs[i] + n]
+    want = np.asarray(rpa.ragged_paged_attention_ref(
+        jnp.asarray(lanes), *shared, jnp.asarray(tables), window=window,
+        sinks=sinks))
+    for i, n in enumerate(q_lens):
+        np.testing.assert_allclose(got[offs[i]:offs[i] + n], want[i, :n],
+                                   atol=3e-6)
+    # a row that carries no token is zero, whatever tile it sits behind
+    assert np.all(got[sum(q_lens):] == 0.0)
+
+
+# (context a lane, rows a lane, attention width, rows of the program) at
+# 4 heads of 24 (tiles of up to 128 rows): slots = min(B * ceil(Q / bq),
+# ceil(rows / bq) + B)
+_PACKED_STEPS = {
+    # one chunk between decoding lanes, as Scheduler.plan_step packs it
+    "chunk-96-between-decodes": ([300, 9, 40, 77, 130], [1, 1, 96, 1, 1],
+                                 128, 136),
+    "chunk-1024-between-decodes": ([60, 70, 17], [1, 1024, 1], 1024, 1032),
+    "two-short-chunks": ([33, 0, 250], [5, 1, 7], 8, 128),
+    "an-empty-lane-in-the-middle": ([100, 500, 0, 40], [40, 0, 0, 13],
+                                    64, 128),
+    # 130 and 134 rows in tiles of 128: both lanes end inside a tile and
+    # the second one's last six rows sit in the last slot
+    "rows-straddle-the-last-slot": ([20, 300], [130, 134], 256, 264),
+    "every-slot-live": ([129, 256, 7], [1, 1, 1], 1, 3),
+    "one-slot-live": ([129, 256, 7], [0, 1, 0], 1, 3),
+    "nothing-live": ([129, 256], [0, 0], 1, 2),
+}
+
+
+@pytest.mark.parametrize("window,sink", [(None, False), (40, True)],
+                         ids=["causal", "window-sink"])
+@pytest.mark.parametrize("name", sorted(_PACKED_STEPS))
+def test_packed_rows_launch_matches_reference(interpret, rng, name, window,
+                                              sink):
+    before, q_lens, qw, n_rows = _PACKED_STEPS[name]
+    bq, _ = rpa._tiling(qw, 4, 24, 4, 16, 99, window)
+    live, slots = rpa.launch_tiles(q_lens, n_rows, qw, 4, 24, 4, 16, 99,
+                                   window)
+    assert live == sum(-(-n // bq) for n in q_lens) <= slots
+    assert slots == min(len(q_lens) * -(-qw // bq),
+                        -(-n_rows // bq) + len(q_lens))
+    if name == "every-slot-live" or name == "rows-straddle-the-last-slot":
+        assert live == slots
+    if name == "chunk-1024-between-decodes":
+        assert (live, slots) == (10, 12)           # lanes x q tiles: 24
+    _packed_against_reference(rng, before, q_lens, qw, n_rows,
+                              window=window, sink=sink)
+
+
+# the longgen cell's heads (tiles of 32 rows) over 4 and 8 kv heads, keys
+# of 192 in 256 and values of 128, q_lens that are no multiple of a tile
+@pytest.mark.parametrize("nkv,window,sink", [(4, None, False),
+                                             (8, 32, True)],
+                         ids=["gqa16-causal", "gqa8-window-sink"])
+def test_packed_rows_at_wide_heads_take_tiles_of_32(interpret, rng, nkv,
+                                                    window, sink):
+    q_lens, n_rows = [1, 70, 0, 1, 33], 112
+    assert rpa.launch_tiles(q_lens, n_rows, 80, 64, 256, 4, 16, 40,
+                            window) == (1 + 3 + 0 + 1 + 2, 4 + 5)
+    _packed_against_reference(rng, [200, 90, 0, 17, 300], q_lens, 80,
+                              n_rows, nh=64, nkv=nkv, hd=256, hdv=128,
+                              window=window, sink=sink)
+
+
+@pytest.mark.parametrize("window", [None, WINDOW])
+@pytest.mark.parametrize("q_lens", [[CHUNK, 1, 0, 3], [CHUNK] * 4],
+                         ids=["ragged", "every-lane-full"])
+def test_the_lanes_call_is_the_packed_call_on_the_same_rows(
+        interpret, rng, window, q_lens):
+    """``ragged_paged_attention(q [B, Q])`` is the packed launch with
+    sequence ``b``'s rows at ``b * Q``: bit for bit the same rows."""
+    kv_lens = [29, 13, 5, 40]
+    args, _, _ = _case(rng, kv_lens, q_lens, CHUNK, ring=False)
+    q, k, v, kv, ql, tables = args
+    sinks = jnp.asarray(rng.randn(4), jnp.float32)
+    lanes = np.asarray(rpa.ragged_paged_attention(
+        *args, window=window, sinks=sinks))
+    rows = np.asarray(rpa.ragged_paged_attention_rows(
+        q.reshape(4 * CHUNK, 4, 24), k, v, kv, ql,
+        jnp.arange(4, dtype=jnp.int32) * CHUNK, tables, CHUNK,
+        window=window, sinks=sinks))
+    np.testing.assert_array_equal(lanes.reshape(rows.shape), rows)
+    ref = np.asarray(rpa.ragged_paged_attention_ref(
+        *args, window=window, sinks=sinks))
+    for i, n in enumerate(q_lens):
+        np.testing.assert_allclose(lanes[i, :n], ref[i, :n], atol=2e-6)
+        assert np.all(lanes[i, n:] == 0.0)

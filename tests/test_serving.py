@@ -1049,6 +1049,11 @@ def test_attn_blocks_of_a_plan_are_the_blocks_the_kernel_walks(
     assert any(e["fused_steps"] > 1 for e in steps) == (fused > 1)
     assert sum(e["attn_blocks"] for e in steps) \
         == engine.stats()["attn_blocks"]
+    # the launches' live tiles and grid steps ride beside it
+    for key in ("attn_tiles", "attn_tile_slots"):
+        assert sum(e[key] for e in steps) == engine.stats()[key] > 0
+    assert all(e["batch"] * 2 * e["fused_steps"] <= e["attn_tiles"]
+               <= e["attn_tile_slots"] for e in steps)
     # a plan by hand: a chunk of 16 beside two decoding lanes and an idle
     # one, two layers of 4 heads of 16
     plan = types.SimpleNamespace(
@@ -1061,6 +1066,42 @@ def test_attn_blocks_of_a_plan_are_the_blocks_the_kernel_walks(
     steps_3 = sum(rpa.walk_blocks(plan.kv_lens + j, plan.q_lens, 1, 4, 16,
                                   4, 8, 0) for j in range(3))
     assert engine._attn_blocks(plan, 3) == 2 * steps_3
+
+
+def test_attn_tiles_of_a_plan_are_the_launches_live_tiles_and_grid_steps(
+        gpt_model):
+    """``attn_tiles`` / ``attn_tile_slots`` are host arithmetic on the
+    plan's ``q_lens`` by the kernel's own tiling, a layer: a chunk of 64
+    beside seven decoding lanes in ``step_rows(64, 8)`` = 128 rows is
+    1 + 7 live tiles of 64 rows in ``min(8 * 1, 128 / 64 + 8)`` = 8 grid
+    steps at this model's 4 heads of 16, and 2 + 7 of ``min(8 * 2,
+    4 + 8)`` = 12 at the longgen cell's tiles of 32 rows;
+    ``attn_blocks`` is what it was."""
+    import types
+    from paddle_tpu.ops.pallas import ragged_paged_attention as rpa
+    from paddle_tpu.serving.scheduler import step_rows
+    engine = ServingEngine(gpt_model, max_batch=8, page_size=8,
+                           max_prefill_chunk=64)
+    q_lens = np.array([1, 1, 64, 1, 1, 1, 1, 1])
+    plan = types.SimpleNamespace(
+        kv_lens=np.array([9, 30, 64, 12, 70, 8, 100, 41]), q_lens=q_lens,
+        q_width=64, rows=step_rows(64, 8))
+    assert plan.rows == 128
+    a_layer = rpa.launch_tiles(q_lens, 128, 64, 4, 16, 4, 8, 0)
+    assert a_layer == (8, 8)
+    assert engine._attn_tiles(plan) == (2 * 8, 2 * 8)
+    assert engine._attn_tiles(plan, 3) == (6 * 8, 6 * 8)
+    blocks = rpa.walk_blocks(plan.kv_lens, q_lens, 64, 4, 16, 4, 8, 0)
+    assert engine._attn_blocks(plan) == 2 * blocks == 2 * 8
+    # the same plan at the longgen cell's heads: tiles of 32 rows
+    assert rpa.launch_tiles(q_lens, 128, 64, 64, 256, 4, 16, 73, 128) \
+        == (2 + 7, min(8 * 2, 4 + 8))
+    # and its own Q=1,024 step: 41 grid steps a layer where 256 stood
+    wide = np.array([1, 1, 1024, 1, 1, 1, 1, 1])
+    assert rpa.launch_tiles(wide, step_rows(1024, 8), 1024, 64, 256, 4, 16,
+                            73, 128) == (32 + 7, 41)
+    idle = np.array([1, 0, 1, 0, 0, 0, 0, 0])
+    assert rpa.launch_tiles(idle, 8, 1, 64, 256, 4, 16, 73, 128) == (2, 8)
 
 
 def test_scheduler_window_budget_clamps_pages_and_budget():

@@ -14,7 +14,10 @@ import paddle_tpu as paddle
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_config
+from paddle_tpu.flags import get_flags, set_flags
 from paddle_tpu.models.mimo_v2 import MiMoV2Config, MiMoV2ForCausalLM
+from paddle_tpu.models.solar_open2 import (SolarOpen2Config,
+                                           SolarOpen2ForCausalLM)
 from paddle_tpu.serving import PagePool, Request, Scheduler, ServingEngine
 from paddle_tpu.serving.scheduler import _bucket, step_rows
 
@@ -32,6 +35,15 @@ def _model(family: str, max_pos: int = 512):
     elif family == "llama":
         m = LlamaForCausalLM(llama_config(
             "tiny", vocab_size=VOCAB, max_position_embeddings=max_pos))
+    elif family == "solar":
+        # a gated GQA layer and a linear-attention layer (a state a slot)
+        m = SolarOpen2ForCausalLM(SolarOpen2Config(
+            vocab_size=VOCAB, hidden_size=64, num_hidden_layers=2,
+            gqa_layers=[0], num_heads=4, num_kv_heads=2, head_dim=16,
+            linear_num_heads=4, linear_head_dim=16, linear_low_rank=16,
+            moe_intermediate_size=32, n_routed_experts=16,
+            num_experts_per_tok=2, held_experts=(4, 4),
+            max_position_embeddings=max_pos))
     else:
         # a full and a window layer (a ring a lane), a dense and two
         # expert layers of which this chip holds four experts
@@ -131,6 +143,64 @@ def test_packed_rows_and_lanes_give_the_same_logits_and_pools(family, rng):
         np.testing.assert_array_equal(np.asarray(got[2]),
                                       np.asarray(want[2]))
         assert int(got[2][0]) > 0
+
+
+@pytest.mark.parametrize("name", ["llama", "described", "solar"])
+def test_the_kernel_s_packed_launch_gives_the_logits_of_both_entries(
+        name, rng):
+    """The step body with the Pallas kernel in it (interpret mode), on
+    the small stubs of the three serve families with a softmax layer —
+    LLaMA's GQA (Mistral's), MiMo's full and window layers with a sink,
+    Solar's gated GQA layer beside a state layer: ``step.packed`` at
+    ``step_rows(64, 4)`` = 128 rows, whose launches run over the live
+    tiles of a 40-row chunk, a decoding lane, an empty lane and a 13-row
+    chunk, gives the logits of the ``[B, Q]`` entry at 4 x 64 rows, and
+    both those of the jnp reference's route."""
+    model = _model(name)
+    params, step = model.build_ragged_decode_step()
+    cache = step.cache
+    ps, b, qw = 4, 4, 64
+    seqs = [rng.randint(0, VOCAB, (120,)) for _ in range(b)]
+    before, q_lens = [0, 57, 0, 41], [40, 1, 0, 13]
+    ppseq = -(-120 // ps)
+    sink = b * ppseq
+    ring = cache.ring_pages(ps, qw)
+    full = np.arange(b * ppseq, dtype="int32").reshape(b, ppseq)
+    tables = cache.tables(full, np.arange(b), ring)
+    pools = cache.new_pools(sink + 1, ps, "float32", b, ring)
+    tok, pos, pid, slot, kv, ql = _feed(seqs, [0] * b, before, full, sink,
+                                        ps, qw)
+    pools = jax.jit(step)(params, tok, pos, pools, pid, slot, kv, ql,
+                          tables)[1]
+    tok, pos, pid, slot, kv, ql = _feed(seqs, before, q_lens, full, sink,
+                                        ps, qw)
+    want = np.asarray(jax.jit(step)(params, tok, pos, pools, pid, slot, kv,
+                                    ql, tables)[0])
+    n_rows = step_rows(qw, b)
+    assert n_rows == 128
+    keep = get_flags(["FLAGS_pallas_interpret"])
+    set_flags({"FLAGS_pallas_interpret": True})
+    try:
+        # a function jax has not traced: the route is chosen in the trace
+        lanes = jax.jit(lambda *args: step(*args))
+        assert "pallas_call" in str(jax.make_jaxpr(lanes)(
+            params, tok, pos, pools, pid, slot, kv, ql, tables))
+        lanes = np.asarray(lanes(params, tok, pos, pools, pid, slot, kv, ql,
+                                 tables)[0])
+        packed = np.asarray(jax.jit(
+            step.packed, static_argnames=("q_width",))(
+            params, _pack(tok, q_lens, n_rows, 0),
+            _pack(pos, q_lens, n_rows, 0), pools,
+            _pack(pid, q_lens, n_rows, sink),
+            _pack(slot, q_lens, n_rows, 0), kv, ql, tables, q_width=qw)[0])
+    finally:
+        set_flags(keep)
+    live = [i for i, n in enumerate(q_lens) if n]
+    np.testing.assert_allclose(packed[live], lanes[live], rtol=2e-5,
+                               atol=2e-5)
+    np.testing.assert_allclose(packed[live], want[live], rtol=2e-5,
+                               atol=2e-5)
+    assert np.all(np.isfinite(packed))
 
 
 # ---------------------------------------------------------------------------

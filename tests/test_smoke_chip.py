@@ -199,13 +199,71 @@ def test_q1024_program_multiplies_packed_rows_and_copies_no_pool():
     assert out["1024"]["kernels"] == 1, out
     assert out["1024"]["pool_copies"] == 0, out
     # the attention call walks the pools itself: the key pool and the
-    # value pool once each beside q and the three prefetched arrays,
-    # where a block a page of the causal walk was sixteen pool operands
+    # value pool once each beside q and the four prefetched arrays (the
+    # lengths, the tables, the tile list), where a block a page of the
+    # causal walk was sixteen pool operands
     assert out["attn_pool_operands"] == 2, out
-    assert out["attn_operands"] == 6, out
+    assert out["attn_operands"] == 7, out
     # q gathered for the kernel and its output, no [8192, ...] buffers
     # of the feed-forward's width: well under a pool and a half
     assert out["1024"]["temp_bytes"] < 3 * out["1024"]["pool_bytes"], out
+
+
+_AOT_PACKED_ATTENTION = _AOT_SERVE_LAYER.replace(
+    """for qw in (1, 128):
+    compiled, pool = chip_smoke._compile_serve_layer(
+        qw, sharding=one_chip, **chip_smoke._HD128_LAYER)
+    text = compiled.as_text()
+""", """import re
+for kind, geometry in (("full", chip_smoke._KEY192_FULL_LAYER),
+                       ("window", chip_smoke._KEY192_WINDOW_LAYER)):
+    compiled, pool = chip_smoke._compile_serve_layer(
+        1024, sharding=one_chip, rows=1032, **geometry)
+    text = compiled.as_text()
+    qw = kind
+    [call] = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    out[kind + "_kernel_out"] = re.search(r"= f32\\[([\\d,]+)\\]", call)[1]
+    out[kind + "_shapes"] = sorted(set(re.findall(
+        r"= f32\\[(\\d+(?:,\\d+){2,})\\]", text)))
+""")
+
+# the temporaries of the same layer at the parent of PR 34, where q and
+# the output were laid out [8 lanes, 1,024] around the launch (sandbox
+# AOT for a v5e): 1,074,128,896 B a full layer, 1,074,451,456 a window one
+_Q1024_ATTENTION_TEMP_AT_PR33 = {"full": 1074128896, "window": 1074451456}
+
+
+def test_q1024_attention_layer_holds_no_lanes_by_width_array():
+    """The guard beside the one above: one full and one window attention
+    layer at the longgen cell's widths (64 heads, keys of 192 in 256,
+    values of 128) as the engine's Q=1,024 step runs them — 1,032 packed
+    rows of 8 lanes — compiled ahead of time for a v5e.  No array of q,
+    of the output or of anything else has 8 x 1,024 rows (dimensions 8
+    and 1,024 side by side, or 8,192): the launch's grid is the step's
+    41 tile slots, and what is gathered for it is 41 x 32 = 1,312 rows.
+    Its temporaries are 87 MB where the parent's were 1,074 MB.
+
+    The kernel's output stays ``f32[slots, heads, block_q, 128]``:
+    4-dimensional with a tile's rows THIRD.  The benchmark's readers
+    (``benchmark/layer_metrics/experts_window.py``) call a launch narrow
+    — a decode-only step's — when that third dimension is at most 8, and
+    the expert roofline counts its steps by the same flag."""
+    assert "_KEY192_WINDOW_LAYER" in _AOT_PACKED_ATTENTION
+    proc = _run(["-c", _AOT_PACKED_ATTENTION], env={"JAX_PLATFORMS": "cpu"})
+    lines = proc.stdout.strip().splitlines()
+    if lines and lines[-1].startswith("NO_TOPOLOGY"):
+        pytest.skip(f"no v5e topology can be described here: {lines[-1]}")
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+    out = json.loads(lines[-1].removeprefix("RESULT "))
+    for kind in ("full", "window"):
+        assert out[kind]["kernels"] == 1 and out[kind]["pool_copies"] == 0
+        assert out[kind + "_kernel_out"] == "41,64,32,128", out
+        for shape in out[kind + "_shapes"]:
+            dims = [int(n) for n in shape.split(",")]
+            assert 8192 not in dims, shape
+            assert not (8 in dims and 1024 in dims), shape
+        assert out[kind]["temp_bytes"] \
+            < _Q1024_ATTENTION_TEMP_AT_PR33[kind] // 8, out
 
 
 _AOT_STATE_LAYER = """
