@@ -21,6 +21,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+# jax.named_scope pushes onto this stack; jax has no public getter for it
+from jax._src import source_info_util as _name_stack
 
 from .. import dtype as dtypes
 from ..flags import get_flag
@@ -106,7 +108,7 @@ class GradNode:
     """One recorded op on the tape."""
 
     __slots__ = ("vjp_fn", "inputs", "out_avals", "multi_out", "op_name",
-                 "__weakref__")
+                 "scopes", "__weakref__")
 
     def __init__(self, vjp_fn, inputs: Sequence[Tensor],
                  out_avals: List[Tuple[tuple, Any]], multi_out: bool,
@@ -116,6 +118,12 @@ class GradNode:
         self.out_avals = out_avals  # [(shape, dtype), ...]
         self.multi_out = multi_out
         self.op_name = op_name
+        # the ``jax.named_scope`` names the op was recorded under:
+        # ``run_backward`` re-opens them around the op's vjp, so that a
+        # device trace names a backward operation by its forward's part
+        # (``backward/attention/transpose(jvp())/dot_general``) — a
+        # ``jax.vjp`` taken op by op keeps no scope of its caller's
+        self.scopes = _name_stack.current_name_stack()
 
     def release(self):
         self.vjp_fn = None
@@ -387,6 +395,7 @@ def run_backward(root: Tensor, grad_tensor=None, retain_graph: bool = False,
     node_by_id = {id(node): node}
     ready = [node]
     released = []
+    open_now = _name_stack.current_name_stack()
     while ready:
         n = ready.pop()
         cots = pending.pop(id(n))
@@ -405,7 +414,12 @@ def run_backward(root: Tensor, grad_tensor=None, retain_graph: bool = False,
             raise RuntimeError(
                 "Trying to backward through the graph a second time "
                 "(set retain_graph=True if needed)")
-        in_cots = n.vjp_fn(tuple(full) if n.multi_out else full[0])
+        # the node's own scopes, less those it shares with this call's
+        # (a recompute replays, and differentiates, under ``backward``)
+        own = n.scopes[len(open_now):] \
+            if n.scopes[:len(open_now)] == open_now else n.scopes
+        with _name_stack.set_name_stack(open_now + own):
+            in_cots = n.vjp_fn(tuple(full) if n.multi_out else full[0])
         if not retain_graph:
             released.append(n)
         for t, c in zip(n.inputs, in_cots):

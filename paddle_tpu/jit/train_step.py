@@ -185,13 +185,20 @@ class TrainStep:
                 else:
                     out = model(ts[0])
                     loss = loss_fn(out, *ts[1:])
+                # the trace's parts (models.generation.TRAIN_STEP_PARTS):
+                # the tape's operations under "backward", the update's
+                # under "optimizer"
                 if scaler is not None:
-                    scaler.scale(loss).backward()
-                    scaler.step(opt)
-                    scaler.update()
+                    with jax.named_scope("backward"):
+                        scaler.scale(loss).backward()
+                    with jax.named_scope("optimizer"):
+                        scaler.step(opt)
+                        scaler.update()
                 elif opt is not None:
-                    loss.backward()
-                    opt.step()
+                    with jax.named_scope("backward"):
+                        loss.backward()
+                    with jax.named_scope("optimizer"):
+                        opt.step()
                 if opt is not None:
                     opt.clear_grad()
                 # 3. collect new state

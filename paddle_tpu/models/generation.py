@@ -741,6 +741,34 @@ class CacheDescription:
 # the ragged batched decode step (continuous-batching serving engine)
 # ---------------------------------------------------------------------------
 
+# The parts of a serve step, as a device trace names them: every
+# operation of a ``serve_step_q<Q>`` program runs under exactly one of
+# these ``jax.named_scope`` names (the step body below opens all but
+# ``sample``, which is the engine's: everything its program does around
+# the step).  A part's name is a component of an operation's ``tf_op``
+# path; ``benchmark/layer_metrics/step_parts.py`` turns the paths into
+# each part's share of the device's busy seconds and imports this tuple.
+# A new mixer opens a part of its own and adds its name here
+STEP_PARTS = ("embed", "attention", "linear_attention", "latent_attention",
+              "feed_forward", "experts", "lm_head", "sample")
+# The train step's parts (``models/gpt.py``, ``jit/train_step.py``,
+# ``optimizer/optimizer.py``).  ``backward`` is no part of the model: the
+# tape's operations run under it, those of a part as
+# ``backward/<part>/transpose(jvp())/...`` (``core/dispatch.py`` re-opens
+# a node's scopes around its vjp) and a recomputed forward's as
+# ``backward/<part>/jvp()/...``, and what the tape adds itself under
+# ``backward`` alone
+TRAIN_STEP_PARTS = ("embed", "attention", "mlp", "lm_head", "backward",
+                    "optimizer", "cast_params")
+
+
+def _mixer_part(d: "LayerDescription") -> str:
+    """The part of ``STEP_PARTS`` a layer's token mixer runs under."""
+    if d.latent_attention is not None:
+        return "latent_attention"
+    return "linear_attention" if d.attention is None else "attention"
+
+
 # XLA's TPU scatter takes index rows as they come at ~73 ns a row and,
 # once they are sorted, at ~0.4 ms flat plus ~9 ns a row; by itself it
 # sorts only from 65,536 rows.  The step's write sorts from this many,
@@ -752,6 +780,7 @@ class CacheDescription:
 _SORT_ROWS_FROM = 8192
 
 
+@jax.named_scope("kv_write")
 def _scatter_pages(pages, vals, page_ids, slots):
     """Write one step's new k/v rows into the page pools.  ``pages
     [nkv, P, ps, hd]``; ``vals [rows, nkv, hd]``; ``page_ids/slots
@@ -846,19 +875,21 @@ def _two_ways_in(rows_body):
 
     def step(p, tok, pos, pools, page_ids, slots, kv_lens, q_lens, tables):
         b, qw = tok.shape
-        offs = jnp.arange(b, dtype=jnp.int32) * jnp.int32(qw)
+        with jax.named_scope("embed"):
+            offs = jnp.arange(b, dtype=jnp.int32) * jnp.int32(qw)
+            rows = _StepRows(b * qw, offs, q_lens, qw)
         return rows_body(p, tok.reshape(-1), pos.reshape(-1), pools,
                          page_ids.reshape(-1), slots.reshape(-1), kv_lens,
-                         q_lens, tables,
-                         _StepRows(b * qw, offs, q_lens, qw))
+                         q_lens, tables, rows)
 
     def packed(p, tok, pos, pools, page_ids, slots, kv_lens, q_lens,
                tables, q_width: int):
-        ql = q_lens.astype(jnp.int32)
-        offs = jnp.cumsum(ql, dtype=jnp.int32) - ql
+        with jax.named_scope("embed"):
+            ql = q_lens.astype(jnp.int32)
+            offs = jnp.cumsum(ql, dtype=jnp.int32) - ql
+            rows = _StepRows(tok.shape[0], offs, q_lens, q_width)
         return rows_body(p, tok, pos, pools, page_ids, slots, kv_lens,
-                         q_lens, tables,
-                         _StepRows(tok.shape[0], offs, q_lens, q_width))
+                         q_lens, tables, rows)
 
     step.packed = packed
     return step
@@ -992,115 +1023,131 @@ def build_ragged_decode_step(model):
 
     def body(p, tok, pos, pools, page_ids, slots, kv_lens, q_lens,
              tables, rows):
-        x = jnp.take(p["embed"], tok, axis=0)             # [rows, H]
-        if md.embed_scale != 1.0:
-            x = x * md.embed_scale
-        valid = rows.valid
-        pos = pos.astype(i32)
-        if md.learned_positions:
-            x = x + jnp.take(p["positions"], pos, axis=0)
-        tables, state_slots = cache.split_slots(tables)
-        full_tables = tables
-        if window_layer is not None:
-            wpool = pools[window_layer][0]
-            ps = wpool.shape[2]
-            full_tables, ring = cache.split_tables(tables, wpool.shape[1])
-            entry = (pos // i32(ps)) % i32(ring.shape[1])
-            ring_ids = jnp.where(
-                valid, jnp.take_along_axis(
-                    rows.of_lanes(ring.astype(i32)), entry[:, None],
-                    axis=1)[:, 0],
-                i32(wpool.shape[1] - 1))                  # padding: sink
-            ring_slots = jnp.where(valid, pos % i32(ps), i32(0))
-        rope = {theta: (jnp.take(cos, pos, axis=0)[:, None, :],
-                        jnp.take(sin, pos, axis=0)[:, None, :])
-                for theta, (cos, sin) in p.get("rope", {}).items()}
+        # every operation runs under one of STEP_PARTS, for the device
+        # trace: the parts are siblings, none opens inside another
+        with jax.named_scope("embed"):
+            x = jnp.take(p["embed"], tok, axis=0)         # [rows, H]
+            if md.embed_scale != 1.0:
+                x = x * md.embed_scale
+            valid = rows.valid
+            pos = pos.astype(i32)
+            if md.learned_positions:
+                x = x + jnp.take(p["positions"], pos, axis=0)
+            tables, state_slots = cache.split_slots(tables)
+            full_tables = tables
+            if window_layer is not None:
+                wpool = pools[window_layer][0]
+                ps = wpool.shape[2]
+                full_tables, ring = cache.split_tables(tables,
+                                                       wpool.shape[1])
+                entry = (pos // i32(ps)) % i32(ring.shape[1])
+                ring_ids = jnp.where(
+                    valid, jnp.take_along_axis(
+                        rows.of_lanes(ring.astype(i32)), entry[:, None],
+                        axis=1)[:, 0],
+                    i32(wpool.shape[1] - 1))              # padding: sink
+                ring_slots = jnp.where(valid, pos % i32(ps), i32(0))
+            rope = {theta: (jnp.take(cos, pos, axis=0)[:, None, :],
+                            jnp.take(sin, pos, axis=0)[:, None, :])
+                    for theta, (cos, sin) in p.get("rope", {}).items()}
         counts = [i32(0), i32(0), i32(0)]
         new_pools = []
         for i, (d, lp) in enumerate(zip(descs, p["layers"])):
             att, ff = d.attention, d.feed_forward
-            u = norm(x, lp["ln1_w"], lp.get("ln1_b"))
-            if d.latent_attention is not None:
-                lat = d.latent_attention
-                out, kept = _latent_attention_rows(
-                    lp, u, lat, pools[i], rows, page_ids, slots, kv_lens,
-                    full_tables, pos, rope[_rope_key(lat.rope_theta)], eps)
+            # the mixer's part holds the layer's first norm and the
+            # residual's add too
+            with jax.named_scope(_mixer_part(d)):
+                u = norm(x, lp["ln1_w"], lp.get("ln1_b"))
+                if d.latent_attention is not None:
+                    lat = d.latent_attention
+                    out, kept = _latent_attention_rows(
+                        lp, u, lat, pools[i], rows, page_ids, slots,
+                        kv_lens, full_tables, pos,
+                        rope[_rope_key(lat.rope_theta)], eps)
+                elif att is None:
+                    out, kept = _linear_attention_rows(
+                        lp, u, d.linear_attention, pools[i], rows,
+                        state_slots, pos, eps)
+                else:
+                    dk, dv = att.key_dim, att.value_dim
+                    with jax.named_scope("qkv_proj"):
+                        qp, kp, vp = _qkv_rows(lp, u, nh, att)
+                        if att.value_scale != 1.0:
+                            vp = vp * att.value_scale
+                        if att.rotary_dim:
+                            cos, sin = rope[_rope_key(att.rope_theta)]
+                            qp = rotated(qp, att, cos, sin)
+                            kp = rotated(kp, att, cos, sin)
+                    windowed = att.window is not None
+                    ids, sl, tb = (ring_ids, ring_slots, ring) if windowed \
+                        else (page_ids, slots, full_tables)
+                    # rows as wide as the pools (_pool_width): the zeros
+                    # add nothing to q.k, and the scale stays the head's
+                    # own
+                    qp, kp, vp = (_pad_last(a, pool.shape[-1])
+                                  for a, pool in ((qp, pools[i][0]),
+                                                  (kp, pools[i][0]),
+                                                  (vp, pools[i][1])))
+                    kpg = _scatter_pages(pools[i][0], kp, ids, sl)
+                    vpg = _scatter_pages(pools[i][1], vp, ids, sl)
+                    kept = (kpg, vpg)
+                    ctx = ragged_paged_attention_rows(
+                        qp, kpg, vpg, kv_lens, q_lens, rows.offs, tb,
+                        rows.q_width, scale=1.0 / math.sqrt(dk),
+                        window=att.window,
+                        sinks=lp["sink"] if att.sink else None,
+                        precision=kernel_precision)
+                    ctx = ctx[..., :dv].reshape(-1, nh * dv)
+                    if att.gate:
+                        with jax.named_scope("attention_gate"):
+                            ctx = ctx * jax.nn.sigmoid(
+                                jnp.matmul(u, lp["wgate"]))
+                    with jax.named_scope("attn_out"):
+                        out = jnp.matmul(ctx, lp["wo"])
+                        if lp.get("bo") is not None:
+                            out = out + lp["bo"]
                 new_pools.append(kept)
                 x = x + out
-            elif att is None:
-                out, kept = _linear_attention_rows(
-                    lp, u, d.linear_attention, pools[i], rows, state_slots,
-                    pos, eps)
-                new_pools.append(kept)
-                x = x + out
-            else:
-                dk, dv = att.key_dim, att.value_dim
-                qp, kp, vp = _qkv_rows(lp, u, nh, att)
-                if att.value_scale != 1.0:
-                    vp = vp * att.value_scale
-                if att.rotary_dim:
-                    cos, sin = rope[_rope_key(att.rope_theta)]
-                    qp = rotated(qp, att, cos, sin)
-                    kp = rotated(kp, att, cos, sin)
-                windowed = att.window is not None
-                ids, sl, tb = (ring_ids, ring_slots, ring) if windowed \
-                    else (page_ids, slots, full_tables)
-                # rows as wide as the pools (_pool_width): the zeros add
-                # nothing to q.k, and the scale stays the head's own
-                qp, kp, vp = (_pad_last(a, pool.shape[-1]) for a, pool in
-                              ((qp, pools[i][0]), (kp, pools[i][0]),
-                               (vp, pools[i][1])))
-                kpg = _scatter_pages(pools[i][0], kp, ids, sl)
-                vpg = _scatter_pages(pools[i][1], vp, ids, sl)
-                new_pools.append((kpg, vpg))
-                ctx = ragged_paged_attention_rows(
-                    qp, kpg, vpg, kv_lens, q_lens, rows.offs, tb,
-                    rows.q_width, scale=1.0 / math.sqrt(dk),
-                    window=att.window,
-                    sinks=lp["sink"] if att.sink else None,
-                    precision=kernel_precision)
-                ctx = ctx[..., :dv].reshape(-1, nh * dv)
-                if att.gate:
-                    with jax.named_scope("attention_gate"):
-                        ctx = ctx * jax.nn.sigmoid(
-                            jnp.matmul(u, lp["wgate"]))
-                out = jnp.matmul(ctx, lp["wo"])
-                if lp.get("bo") is not None:
-                    out = out + lp["bo"]
-                x = x + out
-            if ff.held is not None:
-                h2 = norm(x, lp["ln2_w"], lp.get("ln2_b"))
-                picks, weights = sigmoid_topk_route(
-                    h2, lp["router_w"], lp["router_b"], ff.top_k)
-                y, n_rows = held_experts_swiglu(
-                    h2, picks, weights, valid, lp["wg"], lp["wu"],
-                    lp["wd"], ff.held[0])
-                if ff.routed_scale != 1.0:
-                    y = y * ff.routed_scale
-                if ff.shared_width:
-                    with jax.named_scope("shared_expert"):
-                        y = y + jnp.matmul(
-                            jax.nn.silu(jnp.matmul(h2, lp["shared_wg"]))
-                            * jnp.matmul(h2, lp["shared_wu"]),
-                            lp["shared_wd"])
-                counts = [counts[0] + jnp.sum(n_rows, dtype=i32),
-                          jnp.maximum(counts[1], jnp.max(n_rows)),
-                          counts[2] + jnp.sum(n_rows > 0, dtype=i32)]
-            elif ff.gated:
-                y = _fd.norm_mlp(x, kind=md.norm, norm_w=lp["ln2_w"],
-                                 w_gate=lp["wg"], w1=lp["wu"], w2=lp["wd"],
-                                 eps=eps, act=ff.act)
-            else:
-                y = _fd.norm_mlp(x, kind=md.norm, norm_w=lp["ln2_w"],
-                                 norm_b=lp["ln2_b"], w1=lp["w1"],
-                                 b1=lp["b1"], w2=lp["w2"], b2=lp["b2"],
-                                 eps=eps, act=ff.act)
-            x = x + y
-        h = norm(x, p["norm_w"], p.get("norm_b"))
-        w = p["embed"] if md.tied_head else p["lm_w"]
-        logits = jnp.matmul(rows.last_rows(h), jnp.swapaxes(w, -1, -2))
+            with jax.named_scope("feed_forward" if ff.held is None
+                                 else "experts"):
+                if ff.held is not None:
+                    h2 = norm(x, lp["ln2_w"], lp.get("ln2_b"))
+                    with jax.named_scope("router"):
+                        picks, weights = sigmoid_topk_route(
+                            h2, lp["router_w"], lp["router_b"], ff.top_k)
+                    y, n_rows = held_experts_swiglu(
+                        h2, picks, weights, valid, lp["wg"], lp["wu"],
+                        lp["wd"], ff.held[0])
+                    if ff.routed_scale != 1.0:
+                        y = y * ff.routed_scale
+                    if ff.shared_width:
+                        with jax.named_scope("shared_expert"):
+                            y = y + jnp.matmul(
+                                jax.nn.silu(
+                                    jnp.matmul(h2, lp["shared_wg"]))
+                                * jnp.matmul(h2, lp["shared_wu"]),
+                                lp["shared_wd"])
+                    counts = [counts[0] + jnp.sum(n_rows, dtype=i32),
+                              jnp.maximum(counts[1], jnp.max(n_rows)),
+                              counts[2] + jnp.sum(n_rows > 0, dtype=i32)]
+                elif ff.gated:
+                    y = _fd.norm_mlp(x, kind=md.norm, norm_w=lp["ln2_w"],
+                                     w_gate=lp["wg"], w1=lp["wu"],
+                                     w2=lp["wd"], eps=eps, act=ff.act)
+                else:
+                    y = _fd.norm_mlp(x, kind=md.norm, norm_w=lp["ln2_w"],
+                                     norm_b=lp["ln2_b"], w1=lp["w1"],
+                                     b1=lp["b1"], w2=lp["w2"], b2=lp["b2"],
+                                     eps=eps, act=ff.act)
+                x = x + y
+        with jax.named_scope("lm_head"):
+            h = norm(x, p["norm_w"], p.get("norm_b"))
+            w = p["embed"] if md.tied_head else p["lm_w"]
+            logits = jnp.matmul(rows.last_rows(h), jnp.swapaxes(w, -1, -2))
         if has_experts:
-            return logits, tuple(new_pools), jnp.stack(counts)
+            with jax.named_scope("experts"):
+                counts = jnp.stack(counts)
+            return logits, tuple(new_pools), counts
         return logits, tuple(new_pools)
 
     def rows_body(*args):
@@ -1173,7 +1220,8 @@ def _latent_attention_rows(lp, u, kind: LatentAttentionKind, kept, rows,
     of latents through ``w_uv`` after, both inside
     ``ops/latent_select.py``), the keys of its own sequence that its
     index picks.  ``rope = (cos,
-    sin)`` are the step's rows of the layer's rotary tables."""
+    sin)`` are the step's rows of the layer's rotary tables.  The caller
+    holds the ``latent_attention`` scope open."""
     from ..ops import latent_select as ls
     from ..ops.pallas import fused_decode as _fd
     n, nh = rows.n, kind.heads
@@ -1182,46 +1230,45 @@ def _latent_attention_rows(lp, u, kind: LatentAttentionKind, kept, rows,
     turn = lambda a, interleaved: _fd.reference_rope_rows(
         a, cos, sin, neox=not interleaved)
     highest = jax.lax.Precision.HIGHEST
-    with jax.named_scope("latent_attention"):
-        cq = _fd.reference_rms_norm(jnp.matmul(u, lp["wq_a"]),
-                                    lp["q_norm_w"], eps)
-        q = jnp.matmul(cq, lp["wq_b"]).reshape(n, nh, nope + rd)
-        q_r = turn(q[..., nope:], kind.rope_interleaved)
-        kv = jnp.matmul(u, lp["wkv_a"])
-        latent = _fd.reference_rms_norm(kv[:, :rank], lp["kv_norm_w"], eps)
-        k_r = turn(kv[:, None, rank:], kind.rope_interleaved)[:, 0]
-        width = kept[0].shape[-1]
-        pools = [_scatter_pages(
-            kept[0], _pad_last(jnp.concatenate([latent, k_r], axis=-1),
-                               width)[:, None, :], page_ids, slots)]
-        index = None
-        if kind.index is not None:
-            ix = kind.index
-            with jax.named_scope("index_select"):
-                # a selection is discontinuous: what it is taken from
-                # runs at "highest", as the router's scores do
-                rot = lambda a: jnp.concatenate(
-                    [turn(a[..., :ix.rotary_dim], ix.rope_interleaved),
-                     a[..., ix.rotary_dim:]], axis=-1)
-                q_i = rot(jnp.matmul(cq, lp["wi_q"], precision=highest)
-                          .reshape(n, ix.heads, ix.dim))
-                k_i = rot(_fd.reference_layer_norm(
-                    jnp.matmul(u, lp["wi_k"], precision=highest),
-                    lp["wi_k_norm_w"], lp["wi_k_norm_b"],
-                    ix.norm_eps)[:, None, :])
-                w_i = jnp.matmul(u, lp["wi_w"], precision=highest) \
-                    * (ix.heads ** -0.5 * ix.dim ** -0.5)
-            pools.append(_scatter_pages(
-                kept[1], _pad_last(k_i, kept[1].shape[-1]), page_ids,
-                slots))
-            index = (_pad_last(q_i, kept[1].shape[-1]), w_i, pools[1],
-                     ix.top_k)
-        ctx = ls.attend_selected(
-            q[..., :nope], q_r, lp["w_uk"], lp["w_uv"], pools[0], index,
-            tables, kv_lens, pos, rows.offs, rows.q_lens, rows.lane,
-            rows.q_width, scale=1.0 / math.sqrt(nope + rd)
-        ).reshape(n, nh * kind.value_dim)
-        out = jnp.matmul(ctx, lp["wo"])
+    cq = _fd.reference_rms_norm(jnp.matmul(u, lp["wq_a"]),
+                                lp["q_norm_w"], eps)
+    q = jnp.matmul(cq, lp["wq_b"]).reshape(n, nh, nope + rd)
+    q_r = turn(q[..., nope:], kind.rope_interleaved)
+    kv = jnp.matmul(u, lp["wkv_a"])
+    latent = _fd.reference_rms_norm(kv[:, :rank], lp["kv_norm_w"], eps)
+    k_r = turn(kv[:, None, rank:], kind.rope_interleaved)[:, 0]
+    width = kept[0].shape[-1]
+    pools = [_scatter_pages(
+        kept[0], _pad_last(jnp.concatenate([latent, k_r], axis=-1),
+                           width)[:, None, :], page_ids, slots)]
+    index = None
+    if kind.index is not None:
+        ix = kind.index
+        with jax.named_scope("index_select"):
+            # a selection is discontinuous: what it is taken from
+            # runs at "highest", as the router's scores do
+            rot = lambda a: jnp.concatenate(
+                [turn(a[..., :ix.rotary_dim], ix.rope_interleaved),
+                 a[..., ix.rotary_dim:]], axis=-1)
+            q_i = rot(jnp.matmul(cq, lp["wi_q"], precision=highest)
+                      .reshape(n, ix.heads, ix.dim))
+            k_i = rot(_fd.reference_layer_norm(
+                jnp.matmul(u, lp["wi_k"], precision=highest),
+                lp["wi_k_norm_w"], lp["wi_k_norm_b"],
+                ix.norm_eps)[:, None, :])
+            w_i = jnp.matmul(u, lp["wi_w"], precision=highest) \
+                * (ix.heads ** -0.5 * ix.dim ** -0.5)
+        pools.append(_scatter_pages(
+            kept[1], _pad_last(k_i, kept[1].shape[-1]), page_ids,
+            slots))
+        index = (_pad_last(q_i, kept[1].shape[-1]), w_i, pools[1],
+                 ix.top_k)
+    ctx = ls.attend_selected(
+        q[..., :nope], q_r, lp["w_uk"], lp["w_uv"], pools[0], index,
+        tables, kv_lens, pos, rows.offs, rows.q_lens, rows.lane,
+        rows.q_width, scale=1.0 / math.sqrt(nope + rd)
+    ).reshape(n, nh * kind.value_dim)
+    out = jnp.matmul(ctx, lp["wo"])
     return out, tuple(pools)
 
 
@@ -1241,7 +1288,8 @@ def _linear_attention_rows(lp, u, kind: LinearAttentionKind, kept, rows,
     (``linear_attn_scan``; a decode-only program has no such sequence
     and holds no scan).  A sequence whose first row of the step is at
     position 0 starts from zeros; one with no row, and every row that
-    carries no token, moves nothing."""
+    carries no token, moves nothing.  The caller holds the
+    ``linear_attention`` scope open."""
     from ..ops import gated_delta as gd
     from ..ops.pallas.fused_decode import reference_rms_norm
     nh, dk, dv = kind.heads, kind.key_dim, kind.value_dim
@@ -1249,47 +1297,46 @@ def _linear_attention_rows(lp, u, kind: LinearAttentionKind, kept, rows,
     f32 = jnp.float32
     offs, q_lens, n = rows.offs, rows.q_lens, rows.n
     reset = (q_lens > 0) & (pos[jnp.minimum(offs, jnp.int32(n - 1))] == 0)
-    with jax.named_scope("linear_attention"):
-        # q, k and v: projection, short convolution, silu
-        mixed, new_tail, col = [], [], 0
-        for name, width in (("q", nh * dk), ("k", nh * dk), ("v", nh * dv)):
-            a, t = gd.short_conv_rows(
-                jnp.matmul(u, lp["w" + name]).astype(f32),
-                lp["conv_" + name].astype(f32),
-                tail[:, :, col:col + width], offs, q_lens, slots, reset,
-                rows.at)
-            mixed.append(jax.nn.silu(a).reshape(n, nh, -1))
-            new_tail.append(t)
-            col += width
-        q, k, v = mixed
-        unit = lambda a: a * jax.lax.rsqrt(
-            jnp.sum(a * a, axis=-1, keepdims=True) + f32(1e-6))
-        q, k = unit(q) * f32(dk ** -0.5), unit(k)
-        g = -jnp.exp(lp["a_log"].astype(f32))[None, :, None] \
-            * jax.nn.softplus(
-                jnp.matmul(jnp.matmul(u, lp["wf_down"]), lp["wf_up"])
-                .astype(f32) + lp["dt_bias"]).reshape(n, nh, dk)
-        beta = f32(kind.beta_scale) * jax.nn.sigmoid(
-            jnp.matmul(u.astype(f32), lp["wbeta"].astype(f32),
-                       precision=jax.lax.Precision.HIGHEST))
-        beta = jnp.where(rows.valid[:, None], beta, 0.0)
-        with jax.named_scope("linear_attn_step"):
-            o_slot, state = gd.gated_delta_step(
-                state, q, k, v, g, beta, offs, q_lens, slots, reset)
-        o = o_slot[slots[rows.lane]]
-        if rows.q_width > 1:
-            with jax.named_scope("linear_attn_scan"):
-                o_chunk, state = gd.gated_delta_chunks(
-                    state, q, k, v, g, beta, offs, q_lens, slots,
-                    rows.lane, rows.at, min(_SCAN_BLOCK, rows.q_width))
-            o = jnp.where((q_lens[rows.lane] > 1)[:, None, None],
-                          o_chunk, o)
-        # a norm over each head's values, then the output gate
-        o = reference_rms_norm(o, lp["out_norm_w"].astype(f32), eps)
-        gate = jnp.matmul(jnp.matmul(u, lp["wgate_down"]),
-                          lp["wgate_up"])
-        o = o.reshape(n, nh * dv).astype(u.dtype) * jax.nn.sigmoid(gate)
-        out = jnp.matmul(o, lp["wo"])
+    # q, k and v: projection, short convolution, silu
+    mixed, new_tail, col = [], [], 0
+    for name, width in (("q", nh * dk), ("k", nh * dk), ("v", nh * dv)):
+        a, t = gd.short_conv_rows(
+            jnp.matmul(u, lp["w" + name]).astype(f32),
+            lp["conv_" + name].astype(f32),
+            tail[:, :, col:col + width], offs, q_lens, slots, reset,
+            rows.at)
+        mixed.append(jax.nn.silu(a).reshape(n, nh, -1))
+        new_tail.append(t)
+        col += width
+    q, k, v = mixed
+    unit = lambda a: a * jax.lax.rsqrt(
+        jnp.sum(a * a, axis=-1, keepdims=True) + f32(1e-6))
+    q, k = unit(q) * f32(dk ** -0.5), unit(k)
+    g = -jnp.exp(lp["a_log"].astype(f32))[None, :, None] \
+        * jax.nn.softplus(
+            jnp.matmul(jnp.matmul(u, lp["wf_down"]), lp["wf_up"])
+            .astype(f32) + lp["dt_bias"]).reshape(n, nh, dk)
+    beta = f32(kind.beta_scale) * jax.nn.sigmoid(
+        jnp.matmul(u.astype(f32), lp["wbeta"].astype(f32),
+                   precision=jax.lax.Precision.HIGHEST))
+    beta = jnp.where(rows.valid[:, None], beta, 0.0)
+    with jax.named_scope("linear_attn_step"):
+        o_slot, state = gd.gated_delta_step(
+            state, q, k, v, g, beta, offs, q_lens, slots, reset)
+    o = o_slot[slots[rows.lane]]
+    if rows.q_width > 1:
+        with jax.named_scope("linear_attn_scan"):
+            o_chunk, state = gd.gated_delta_chunks(
+                state, q, k, v, g, beta, offs, q_lens, slots,
+                rows.lane, rows.at, min(_SCAN_BLOCK, rows.q_width))
+        o = jnp.where((q_lens[rows.lane] > 1)[:, None, None],
+                      o_chunk, o)
+    # a norm over each head's values, then the output gate
+    o = reference_rms_norm(o, lp["out_norm_w"].astype(f32), eps)
+    gate = jnp.matmul(jnp.matmul(u, lp["wgate_down"]),
+                      lp["wgate_up"])
+    o = o.reshape(n, nh * dv).astype(u.dtype) * jax.nn.sigmoid(gate)
+    out = jnp.matmul(o, lp["wo"])
     return out, (state, jnp.concatenate(new_tail, axis=-1))
 
 

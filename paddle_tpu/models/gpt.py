@@ -20,6 +20,7 @@ import math
 from dataclasses import dataclass, field
 from typing import Optional
 
+import jax
 import numpy as np
 
 from .. import nn
@@ -177,16 +178,21 @@ class GPTBlock(nn.Layer):
         self.drop_p = c.hidden_dropout_prob
 
     def forward(self, x, past=None, use_cache: bool = False):
-        if use_cache:
-            h, new_past = self.attn(self.ln1(x), training=self.training,
-                                    past=past, use_cache=True)
-        else:
-            h = self.attn(self.ln1(x), training=self.training, past=past)
-        h = F.dropout(h, self.drop_p, training=self.training)
-        x = x + h
-        h = self.mlp(self.ln2(x))
-        h = F.dropout(h, self.drop_p, training=self.training)
-        x = x + h
+        # the device trace's parts (generation.TRAIN_STEP_PARTS): each
+        # holds its norm, its dropout and the residual's add
+        with jax.named_scope("attention"):
+            if use_cache:
+                h, new_past = self.attn(self.ln1(x), training=self.training,
+                                        past=past, use_cache=True)
+            else:
+                h = self.attn(self.ln1(x), training=self.training,
+                              past=past)
+            h = F.dropout(h, self.drop_p, training=self.training)
+            x = x + h
+        with jax.named_scope("mlp"):
+            h = self.mlp(self.ln2(x))
+            h = F.dropout(h, self.drop_p, training=self.training)
+            x = x + h
         return (x, new_past) if use_cache else x
 
 
@@ -202,6 +208,7 @@ class GPTEmbeddings(nn.Layer):
             weight_attr=ParamAttr(initializer=Normal(std=c.initializer_range)))
         self.drop_p = c.hidden_dropout_prob
 
+    @jax.named_scope("embed")
     def forward(self, input_ids, pos_offset: int = 0):
         S = input_ids.shape[-1]
         if pos_offset + S > self.position_embeddings.weight.shape[0]:
@@ -258,7 +265,8 @@ class GPTModel(nn.Layer):
                 x, p = block(x, past=past[i] if past is not None
                              else None, use_cache=True)
                 new_pasts.append(p)
-            return self.final_ln(x), new_pasts
+            with jax.named_scope("lm_head"):
+                return self.final_ln(x), new_pasts
         for i, block in enumerate(self.layers):
             if past is not None:
                 x = block(x, past=past[i])
@@ -266,7 +274,8 @@ class GPTModel(nn.Layer):
                 x = recompute(block, x)
             else:
                 x = block(x)
-        return self.final_ln(x)
+        with jax.named_scope("lm_head"):
+            return self.final_ln(x)
 
 
 class GPTForPretraining(nn.Layer):
@@ -290,13 +299,15 @@ class GPTForPretraining(nn.Layer):
             h, new_past = self.gpt(input_ids, past=past, use_cache=True)
         else:
             h = self.gpt(input_ids, past=past)        # [B, S, H]
-        if last_logits_only:
-            h = h[:, -1:]
-        w = (self.gpt.embeddings.word_embeddings.weight
-             if self.config.tie_word_embeddings else self.lm_head_weight)
-        logits = paddle.matmul(h, w, transpose_y=True)  # [B, S, V]
-        logits = sharding_constraint(logits, ("dp", "sharding"), None,
-                                     "mp")
+        with jax.named_scope("lm_head"):
+            if last_logits_only:
+                h = h[:, -1:]
+            w = (self.gpt.embeddings.word_embeddings.weight
+                 if self.config.tie_word_embeddings
+                 else self.lm_head_weight)
+            logits = paddle.matmul(h, w, transpose_y=True)  # [B, S, V]
+            logits = sharding_constraint(logits, ("dp", "sharding"), None,
+                                         "mp")
         return (logits, new_past) if use_cache else logits
 
     def generate(self, input_ids, **kwargs):
@@ -414,6 +425,7 @@ class GPTPretrainingCriterion(nn.Layer):
         super().__init__()
         self.ce = ParallelCrossEntropy(ignore_index=-100)
 
+    @jax.named_scope("lm_head")
     def forward(self, logits, labels):
         # logits [B, S, V]; labels [B, S].  Mean over VALID tokens only —
         # ignore_index positions must not dilute the loss (reference's

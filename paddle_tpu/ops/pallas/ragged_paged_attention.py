@@ -642,6 +642,7 @@ def ragged_paged_attention(q, k_pages, v_pages, kv_lens, q_lens,
                                       sinks)
 
 
+@jax.named_scope("attn_launch")
 def ragged_paged_attention_rows(q, k_pages, v_pages, kv_lens, q_lens, offs,
                                 page_tables, q_width: int, scale=None,
                                 window=None, sinks=None, precision=None):
@@ -651,7 +652,10 @@ def ragged_paged_attention_rows(q, k_pages, v_pages, kv_lens, q_lens, offs,
     nondecreasing, ``q_lens <= q_width``, static) → ``[rows, nh, hdv]``.
     The kernel's grid is the step's live tiles, so nothing here is
     ``B * q_width`` rows tall; only the jnp reference, the route where
-    the kernel is not available, lays the rows out as ``[B, Q]``."""
+    the kernel is not available, lays the rows out as ``[B, Q]``.
+    All of it — tile list, gathers, re-lays, the kernel under its own
+    scope — runs under ``attn_launch`` in a device trace (the jitted
+    launch's operations carry their caller's path)."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if _kernel_takes(q, k_pages, v_pages):

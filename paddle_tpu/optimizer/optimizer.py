@@ -231,7 +231,8 @@ class Optimizer:
             new_p = self._update_param(p, pv, gv, lr, group, idx)
             if use_master:
                 self._master_weights[_param_key(p, idx)] = new_p
-                p._data = new_p.astype(p._data.dtype)
+                with jax.named_scope("cast_params"):
+                    p._data = new_p.astype(p._data.dtype)
             else:
                 p._data = new_p
 

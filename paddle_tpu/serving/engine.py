@@ -1678,34 +1678,40 @@ class ServingEngine:
             # and the host throws its output away when it reads the -1.)
             # One program a width serves both forms: with nothing
             # unread take is -1 throughout and prev a resident zero row
-            tok = jnp.where(take >= 0,
-                            jnp.maximum(prev[jnp.maximum(take, 0)], 0),
-                            tok.astype(jnp.int32))
+            # everything around the step is the trace's part "sample"
+            # (models.generation.STEP_PARTS)
+            with jax.named_scope("sample"):
+                tok = jnp.where(take >= 0,
+                                jnp.maximum(prev[jnp.maximum(take, 0)], 0),
+                                tok.astype(jnp.int32))
             out = step.packed(params, tok, pos, pools, page_ids, slots,
                               kv_lens, q_lens, tables, qw)
             logits, pools = out[0], out[1]
-            # chaos bias (zeros in production — a no-op add) lets the
-            # fault injector NaN one lane's logits without a host hook
-            logits = logits + poison[:, None]
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            rng, sub = jax.random.split(rng)
-            t32 = temps.astype(jnp.float32)
-            scaled = logits.astype(jnp.float32) \
-                / jnp.maximum(t32, jnp.float32(1e-6))[:, None]
-            sampled = jax.random.categorical(sub, scaled, axis=-1) \
-                .astype(jnp.int32)
-            nxt = jnp.where(t32 > jnp.float32(0.0), sampled, greedy)
-            # on-device NaN-logits sentinel: a NaN row (injected or a
-            # genuine numeric blow-up) collapses the sampled token to
-            # -1, so the host's ONE boundary read doubles as the
-            # detector and the lane quarantines with no extra sync
-            bad = jnp.isnan(logits).any(axis=-1)
-            nxt = jnp.where(bad, jnp.int32(-1), nxt)
-            if routed:
-                # a model with expert layers: its step's routing counts
-                # ride behind the sampled tokens in the one array the
-                # host reads (no other model's program has this branch)
-                nxt = jnp.concatenate([nxt, out[2].astype(jnp.int32)])
+            with jax.named_scope("sample"):
+                # chaos bias (zeros in production — a no-op add) lets the
+                # fault injector NaN one lane's logits without a host
+                # hook
+                logits = logits + poison[:, None]
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                rng, sub = jax.random.split(rng)
+                t32 = temps.astype(jnp.float32)
+                scaled = logits.astype(jnp.float32) \
+                    / jnp.maximum(t32, jnp.float32(1e-6))[:, None]
+                sampled = jax.random.categorical(sub, scaled, axis=-1) \
+                    .astype(jnp.int32)
+                nxt = jnp.where(t32 > jnp.float32(0.0), sampled, greedy)
+                # on-device NaN-logits sentinel: a NaN row (injected or a
+                # genuine numeric blow-up) collapses the sampled token to
+                # -1, so the host's ONE boundary read doubles as the
+                # detector and the lane quarantines with no extra sync
+                bad = jnp.isnan(logits).any(axis=-1)
+                nxt = jnp.where(bad, jnp.int32(-1), nxt)
+                if routed:
+                    # a model with expert layers: its step's routing
+                    # counts ride behind the sampled tokens in the one
+                    # array the host reads (no other model's program has
+                    # this branch)
+                    nxt = jnp.concatenate([nxt, out[2].astype(jnp.int32)])
             return nxt, pools, rng
 
         # the name the program carries in a profiler trace and in HLO
