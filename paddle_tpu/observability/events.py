@@ -127,7 +127,8 @@ EVENT_SCHEMA: Dict[str, Dict[str, str]] = {
                    "commit_s": "float", "host_gap_s": "float",
                    "wait_s": "float", "admit_queue_s": "object",
                    "expert_rows": "int", "expert_rows_max": "int",
-                   "experts_hit": "int", "window_pages_read": "int",
+                   "experts_hit": "int", "expert_kernel_layers": "int",
+                   "window_pages_read": "int",
                    "full_pages_read": "int", "attn_blocks": "int",
                    "attn_tiles": "int", "attn_tile_slots": "int",
                    "state_lanes": "int",

@@ -22,8 +22,30 @@ Two functions, pure ``jnp`` under trace:
   held expert that has a row runs a loop over ITS tiles, which gathers
   a tile's rows, multiplies them with the expert's three matrices and
   adds the weighted result back.  No capacity and no dropped row: the
-  work and the expert weights read follow the routing (an expert nobody
-  picked is a branch not taken), and the largest buffer is one tile.
+  work follows the routing (an expert nobody picked is a branch not
+  taken), and the largest buffer is one tile.
+
+**What a branch keeps the compiler from, and what not.**  A branch an
+expert keeps XLA from MULTIPLYING with, and from casting for the matrix
+unit, the weights of an expert nobody picked.  It does not keep XLA
+from READING them: its memory-space assignment treats a branch's
+weights as operands of the ``conditional`` and, where a matrix fits its
+budget of VMEM, fetches it there BEFORE the ``conditional``, taken or
+not.  Solar-Open2's 21 MB matrices fit: a decode step of 8 rows, which
+hits 10 of the 80 held experts, read three quarters of all 80 ahead of
+branches it then did not take, 4.6 ms of a 14.7 ms step (PERF.md
+section 6, PR 36; ``tools/aot_prefetch_tally.py`` counts such fetches
+in a compiled step).  So in a **narrow step** — the step's (row, pick)
+pairs fit one tile, ``T * k <= _TILE_ROWS`` (:func:`narrow_step`): the
+decode-only program — a taken branch is one Pallas kernel
+(``ops/pallas/expert_swiglu.py``) that is handed the three matrices in
+HBM and copies them block by block itself: what is read is what the
+routing picked.  It multiplies all ``T`` rows (an expert cannot be given
+more, and at so few rows the time is the weights' bytes) and the branch
+adds back the rows that picked the expert.  A wider step keeps XLA's
+products: there every held expert is hit, the fetch ahead of the branch
+is wanted work that overlaps the expert before, and the products are
+the matrix unit's time, at three passes where the kernel has six.
 
 Index constants are pinned int32 (``jax_enable_x64`` is on).
 """
@@ -32,9 +54,22 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["sigmoid_topk_route", "held_experts_swiglu"]
+from .pallas import expert_swiglu
+
+__all__ = ["sigmoid_topk_route", "held_experts_swiglu", "narrow_step"]
 
 _TILE_ROWS = 256
+
+
+def narrow_step(rows: int, top_k: int) -> bool:
+    """Whether :func:`held_experts_swiglu` over a step of ``rows`` rows
+    of ``top_k`` picks each runs its taken branches as the kernel that
+    reads an expert's weights itself: the pairs fit one tile, and the
+    kernel is available (a TPU, or interpret mode).  Shapes alone: the
+    serving engine counts its ``expert_kernel_layers`` by the same
+    rule."""
+    return int(rows) * int(top_k) <= _TILE_ROWS \
+        and expert_swiglu.available()
 
 
 def sigmoid_topk_route(h, w_router, bias, top_k: int):
@@ -84,6 +119,24 @@ def held_experts_swiglu(h, ids, weights, valid, w_gate, w_up, w_down,
         [weights.reshape(n)[order].astype(jnp.float32),
          jnp.zeros((tm,), jnp.float32)])
 
+    def rows_of(e: int):
+        """Held expert ``e`` over a narrow step: the kernel multiplies
+        every row of the step and reads the three matrices itself,
+        here, inside the branch; a row that did not pick ``e`` adds
+        nothing, whatever the kernel made of it."""
+        wg, wu, wd = w_gate[e], w_up[e], w_down[e]
+        mine = here & (local == i32(e))
+        w = jnp.sum(jnp.where(mine, weights.astype(jnp.float32),
+                              jnp.float32(0.0)), axis=1)
+
+        def add(y):
+            out = expert_swiglu.expert_swiglu(h, wg, wu, wd)
+            return y + jnp.where(jnp.any(mine, axis=1)[:, None],
+                                 out * w[:, None].astype(out.dtype),
+                                 jnp.zeros((), out.dtype))
+
+        return add
+
     def tiles_of(e: int):
         """The loop over held expert ``e``'s tiles (``e`` is static: its
         three matrices are the only weights the branch below is given)."""
@@ -104,6 +157,7 @@ def held_experts_swiglu(h, ids, weights, valid, w_gate, w_up, w_down,
 
         return lambda y: jax.lax.fori_loop(i32(0), tiles[e], one_tile, y)
 
+    branch = rows_of if narrow_step(t, k) else tiles_of
     y = jnp.zeros_like(h)
     with jax.named_scope("expert_matmul"):
         for e in range(c):
@@ -114,6 +168,9 @@ def held_experts_swiglu(h, ids, weights, valid, w_gate, w_up, w_down,
             # array had that cast hoisted above the loop and over all C
             # experts: every held expert's weights read and re-written a
             # layer a step, 11 ms of a 26 ms decode step (PERF.md
-            # section 6, PR 27)
-            y = jax.lax.cond(rows[e] > 0, tiles_of(e), lambda y: y, y)
+            # section 6, PR 27).  The branch does not keep XLA from
+            # fetching the weights into VMEM ahead of it (the module
+            # docstring): in a narrow step the branch is the kernel
+            # that reads them itself
+            y = jax.lax.cond(rows[e] > 0, branch(e), lambda y: y, y)
     return y, rows

@@ -447,6 +447,12 @@ class ServingEngine:
             if self._cache.n_latent else 0
         self._heads = int(cfg.description().heads) \
             if self._attn_launches else 0
+        # the expert layers of a step, one entry a number of picks a
+        # row: (top_k, layers) — what _expert_kernel_layers counts by
+        self._expert_layers = tuple(collections.Counter(
+            d.feed_forward.top_k for d in cfg.description().layers
+            if d.feed_forward.held is not None).items()) \
+            if self._routed else ()
         self._itemsize = jax.numpy.dtype(dtype).itemsize
         self._prefix_caching = bool(prefix_caching)
         # predicted-cost admission (FLAGS_serving_predicted_admission,
@@ -546,6 +552,9 @@ class ServingEngine:
         self._n_attn_blocks = 0
         self._n_attn_tiles = 0
         self._n_attn_tile_slots = 0
+        # expert layers the steps ran as the kernel that reads a picked
+        # expert's weights itself (_expert_kernel_layers)
+        self._n_expert_kernel_layers = 0
         # what the steps' rows asked of a latent layer's index
         # (_select_counts)
         self._select_rows = self._keys_visible = self._keys_selected = 0
@@ -1121,6 +1130,8 @@ class ServingEngine:
             self._select_rows += select[0]
             self._keys_visible += select[1]
             self._keys_selected += select[2]
+            kernel_layers = self._expert_kernel_layers(plan)
+            self._n_expert_kernel_layers += kernel_layers
             now = time.monotonic()
             for i, seq in enumerate(plan.seqs):
                 if seq.req.done:
@@ -1165,7 +1176,8 @@ class ServingEngine:
                 step_s, flight.cold, 1, "single_step",
                 routing=toks[self.max_batch:], ahead=flight.ahead,
                 span=flight.span, state=state, attn_blocks=blocks,
-                attn_tiles=tiles, select=select)
+                attn_tiles=tiles, select=select,
+                expert_kernel_layers=kernel_layers)
         flight.span.end()
 
     def _state_counts(self, plan):
@@ -1253,12 +1265,23 @@ class ServingEngine:
             slots += layers * steps * grid
         return tiles, slots
 
+    def _expert_kernel_layers(self, plan) -> int:
+        """The step's expert layers whose taken branches are the kernel
+        that reads a picked expert's weights itself, by the op's own
+        shape rule (``ops.routed_experts.narrow_step``) on the program's
+        rows: static a program, so host arithmetic.  All of them in a
+        decode-only step, none in a step with a chunk."""
+        from ..ops.routed_experts import narrow_step
+        return sum(layers for top_k, layers in self._expert_layers
+                   if narrow_step(plan.rows, top_k))
+
     def _emit_batch_step(self, phase_seconds, plan, prefill_seqs,
                          q_width, tokens, step_s, cold_start,
                          fused_steps, exit_reason, routing=(),
                          ahead=False, span=None, state=(0, 0, 0),
                          attn_blocks=0, attn_tiles=(0, 0),
-                         select=(0, 0, 0)) -> None:
+                         select=(0, 0, 0),
+                         expert_kernel_layers=0) -> None:
         """The step's ``batch_step`` record (under ``_wake``;
         ``phase_seconds`` from ``_LoopPhases.take``; ``span`` the step's
         own where no ambient one covers it).  step_s +
@@ -1296,6 +1319,7 @@ class ServingEngine:
                      expert_rows=expert_rows,
                      expert_rows_max=expert_rows_max,
                      experts_hit=experts_hit,
+                     expert_kernel_layers=expert_kernel_layers,
                      window_pages_read=window_pages,
                      full_pages_read=full_pages,
                      attn_blocks=attn_blocks,
@@ -1771,6 +1795,7 @@ class ServingEngine:
                "attn_blocks": self._n_attn_blocks,  # noqa: PTL902 — advisory snapshot (see below)
                "attn_tiles": self._n_attn_tiles,  # noqa: PTL902 — advisory snapshot (see below)
                "attn_tile_slots": self._n_attn_tile_slots,  # noqa: PTL902 — advisory snapshot (see below)
+               "expert_kernel_layers": self._n_expert_kernel_layers,  # noqa: PTL902 — advisory snapshot (see below)
                "select_rows": self._select_rows,  # noqa: PTL902 — advisory snapshot (see below)
                "keys_visible": self._keys_visible,  # noqa: PTL902 — advisory snapshot (see below)
                "keys_selected": self._keys_selected,  # noqa: PTL902 — advisory snapshot (see below)

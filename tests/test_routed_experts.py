@@ -1,13 +1,18 @@
 """One chip's share of an expert layer (``ops/routed_experts.py``): the
 shares of all chips add up to the whole layer, and no row is dropped."""
+import json
+import os
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu.flags import get_flags, set_flags
 from paddle_tpu.ops import routed_experts
 from paddle_tpu.ops.routed_experts import (held_experts_swiglu,
-                                           sigmoid_topk_route)
+                                           narrow_step, sigmoid_topk_route)
 
 H, I, E, K = 32, 16, 16, 2
 
@@ -132,3 +137,153 @@ def test_the_selection_bias_steers_the_pick_and_not_the_weight(rng):
                                   jnp.zeros((E,)), K)
     assert (np.sort(np.asarray(plain), -1)
             != np.sort(np.asarray(ids), -1)).any()
+
+
+# ---------------------------------------------------------------------------
+# the narrow step: a taken branch is the kernel that reads the expert's
+# weights itself (ops/pallas/expert_swiglu.py, interpret mode here)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def interpret():
+    """Run a body with ``FLAGS_pallas_interpret`` as given: on, a narrow
+    step takes the kernel path on this CPU; off, XLA's."""
+    keep = get_flags(["FLAGS_pallas_interpret"])
+
+    def under(flag: bool, body):
+        set_flags({"FLAGS_pallas_interpret": flag})
+        try:
+            return body()
+        finally:
+            set_flags(keep)
+    return under
+
+
+def _rehearsal_widths(config: str):
+    """``(T, hidden, expert width, held, router width, top_k)`` of a
+    benchmark configuration's rehearsal: its decode-only step."""
+    with open(os.path.join(os.path.dirname(__file__), os.pardir,
+                           "benchmark", "configs", config + ".json")) as fh:
+        r = json.load(fh)["rehearse"]
+    return dict(t=8, h=r["hidden_size"], i=r["moe_intermediate_size"],
+                count=r["n_routed_experts"], e=r["n_router_outputs"],
+                k=r["num_experts_per_tok"], first=0)
+
+
+# t rows of h wide, experts i wide, `count` held from `first` of e, top k;
+# `bias`: the selection bias by expert id, `valid`: rows that carry a token
+_NARROW = {
+    "one_row": dict(t=1, bias={5: 9.0}),
+    "eight_rows": dict(t=8),
+    "twenty_four_rows": dict(t=24),
+    "an_expert_with_no_row": dict(t=8, bias={5: -9.0}),
+    "an_expert_given_every_row": dict(t=8, bias={5: 9.0, 6: 9.0}),
+    "held_from_expert_twelve": dict(t=8, first=12),
+    "padding_rows": dict(t=8, valid=5),
+    "three_blocks_of_columns": dict(t=8, h=128, i=384, e=8, first=0,
+                                    count=8),
+    "solar_open2_rehearsal": _rehearsal_widths("solar-open2-8l-ep32"),
+    "mimo_v2_5_rehearsal": _rehearsal_widths("mimo-v2.5-7l-ep32"),
+    "glm_5_rehearsal": _rehearsal_widths("glm-5-5l-ep32"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_NARROW))
+def test_the_kernel_path_is_the_xla_path_and_a_float64_brute_force(
+        rng, interpret, case):
+    """A narrow step through the kernel (interpret mode), through XLA's
+    products and written out in float64: the same share, to 1e-5 of its
+    largest entry."""
+    c = dict(dict(h=H, i=I, e=E, k=K, first=4, count=4, bias={},
+                  valid=None), **_NARROW[case])
+    t, h_, i_, first, count = c["t"], c["h"], c["i"], c["first"], c["count"]
+    lp = {"router_w": rng.randn(h_, c["e"]).astype("float32"),
+          "router_b": np.zeros((c["e"],), "float32"),
+          "wg": rng.randn(c["e"], h_, i_).astype("float32") * 0.2,
+          "wu": rng.randn(c["e"], h_, i_).astype("float32") * 0.2,
+          "wd": rng.randn(c["e"], i_, h_).astype("float32") * 0.2}
+    for e, b in c["bias"].items():
+        lp["router_b"][e] = b
+    h = rng.randn(t, h_).astype("float32")
+    valid = np.arange(t) < (t if c["valid"] is None else c["valid"])
+    assert routed_experts._TILE_ROWS >= t * c["k"]
+
+    def share():
+        with jax.default_matmul_precision("highest"):
+            ids, w = sigmoid_topk_route(jnp.asarray(h), lp["router_w"],
+                                        lp["router_b"], c["k"])
+            y, rows = jax.jit(lambda *a: held_experts_swiglu(*a, first))(
+                jnp.asarray(h), ids, w, jnp.asarray(valid),
+                tuple(lp["wg"][first:first + count]),
+                tuple(lp["wu"][first:first + count]),
+                tuple(lp["wd"][first:first + count]))
+        return (np.asarray(y), np.asarray(rows), np.asarray(ids),
+                np.asarray(w))
+
+    kernel, rows, ids, w = interpret(True, share)
+    xla, xla_rows, _, _ = interpret(False, share)
+    want = np.zeros((t, h_))
+    for r in np.flatnonzero(valid):
+        for e, we in zip(ids[r], w[r]):
+            if first <= e < first + count:
+                a = h[r].astype("float64") @ lp["wg"][e]
+                want[r] += we * ((a / (1.0 + np.exp(-a))
+                                  * (h[r].astype("float64") @ lp["wu"][e]))
+                                 @ lp["wd"][e])
+    held = (ids >= first) & (ids < first + count) & valid[:, None]
+    assert rows.tolist() == xla_rows.tolist() == [
+        int((held & (ids == first + e)).sum()) for e in range(count)]
+    if case == "an_expert_with_no_row":
+        assert rows[5 - first] == 0
+    if case == "an_expert_given_every_row":
+        assert rows[5 - first] == rows[6 - first] == t
+    if c["valid"] is not None:
+        assert not kernel[c["valid"]:].any()
+    assert want.any() and not kernel[~held.any(-1)].any()
+    scale = np.abs(want).max()
+    assert np.abs(kernel - want).max() <= 1e-5 * scale
+    assert np.abs(xla - want).max() <= 1e-5 * scale
+    assert np.abs(kernel - xla).max() <= 1e-5 * scale
+
+
+def _traced(t, k, count=4):
+    f32 = lambda *shape: jnp.zeros(shape, jnp.float32)
+    args = (f32(t, H), jnp.zeros((t, k), jnp.int32), f32(t, k),
+            jnp.ones((t,), bool), (f32(H, I),) * count,
+            (f32(H, I),) * count, (f32(I, H),) * count)
+    return jax.jit(lambda *a: held_experts_swiglu(*a, 0)), args
+
+
+@pytest.mark.parametrize("t,k,kernel", [(8, 8, True), (32, 8, True),
+                                        (128, 2, True), (40, 8, False),
+                                        (128, 4, False), (1032, 8, False)])
+def test_shapes_alone_send_a_step_down_the_kernel_or_the_xla_path(
+        interpret, t, k, kernel):
+    """``T * k <= 256`` pairs are one tile: the decode-only program's 8
+    rows of 8 picks take the kernel, a 128-row step of 4 picks (the
+    narrowest step with a chunk, at any top-k over 2) and a chunk's 1,032
+    rows take XLA's loop over tiles.  Without the kernel (a CPU out of
+    interpret mode) every step takes XLA's."""
+    for flag in (True, False):
+        fn, args = _traced(t, k)      # the route is read when it is traced
+        assert interpret(flag, lambda: narrow_step(t, k)) \
+            == (kernel and flag)
+        traced = interpret(flag, lambda: str(jax.make_jaxpr(fn)(*args)))
+        assert ("pallas_call" in traced) == (kernel and flag)
+
+
+@pytest.mark.parametrize("count", [4, 10])
+def test_a_narrow_step_still_holds_one_conditional_a_held_expert(
+        interpret, count):
+    """The benchmark's readers find the expert layer's device seconds by
+    its ``conditional`` operations, one a held expert, whose output is
+    the layer's ``[rows, hidden]``, under the scope ``expert_matmul``."""
+    fn, args = _traced(8, 8, count)
+    text = interpret(True, lambda: fn.lower(*args).as_text(debug_info=True))
+    # the end of a case whose one output is [8 rows, hidden] (the
+    # interpreted kernel's own `pl.when` is a case too, of other outputs)
+    ends = [line for line in text.splitlines()
+            if re.match(rf"\s*\}}\) : \(tensor<i32>\) -> tensor<8x{H}xf32>",
+                        line)]
+    assert len(ends) == count, text[-3000:]
+    assert text.count("expert_matmul") >= count

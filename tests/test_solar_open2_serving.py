@@ -349,6 +349,46 @@ def test_engine_serves_mixed_lengths_as_the_step_fed_by_hand(
     assert sorted(engine.scheduler._free_slots) == [0, 1, 2]
 
 
+@pytest.mark.parametrize("interpret", [True, False])
+def test_the_records_count_the_expert_layers_that_took_the_kernel(
+        rng, tmp_path, interpret):
+    """``expert_kernel_layers``: every expert layer of a decode-only
+    step (its 3 rows of 4 picks fit one tile, so a taken branch is the
+    kernel that reads the expert's weights itself — interpreted here),
+    none of a step with a chunk (128 rows of 4 picks do not), none at
+    all where the kernel is not available; ``engine.stats()`` sums it,
+    and the tokens are XLA's either way."""
+    from paddle_tpu.observability import events as obs_events
+    paddle.seed(5)
+    model = SolarOpen2ForCausalLM(_config(num_experts_per_tok=4))
+    _reseed(model, 3)
+    model.eval()
+    prompts = [rng.randint(0, VOCAB, (n,)).tolist() for n in (21, 6)]
+    keep = get_flags(["FLAGS_pallas_interpret"])
+    set_flags({"FLAGS_observability_dir": str(tmp_path),
+               "FLAGS_pallas_interpret": interpret})
+    try:
+        engine = ServingEngine(model, max_batch=3, page_size=4,
+                               max_prefill_chunk=16, prefix_caching=False)
+        with engine:
+            reqs = [engine.submit(p, max_new_tokens=4) for p in prompts]
+            got = [r.wait(timeout=300) for r in reqs]
+        stats = engine.stats()
+    finally:
+        set_flags({"FLAGS_observability_dir": "", **keep})
+    assert got == [_greedy_by_hand(model, p, 4) for p in prompts]
+    steps = [e for e in obs_events.read_events(str(tmp_path))
+             if e["kind"] == "batch_step"]
+    narrow = [e for e in steps if e["q_width"] == 1]
+    assert narrow and len(narrow) < len(steps)
+    assert all(e["expert_kernel_layers"] == (4 if interpret else 0)
+               for e in narrow)
+    assert all(e["expert_kernel_layers"] == 0
+               for e in steps if e["q_width"] > 1)
+    assert stats["expert_kernel_layers"] == \
+        sum(e["expert_kernel_layers"] for e in steps)
+
+
 def test_eviction_and_resume_reproduce_the_tokens(model, rng):
     """Too few pages for three sequences to end: one is evicted, its
     slot goes back, and its re-prefill from position 0 clears whatever
