@@ -431,6 +431,13 @@ _KEY192_FULL_LAYER = dict(b=8, nh=64, nkv=4, hd=256, hdv=128, pages=4097,
                           ps=16, ppseq=512)
 _KEY192_WINDOW_LAYER = dict(b=8, nh=64, nkv=8, hd=256, hdv=128, pages=585,
                             ps=16, ppseq=73, window=128, sink=True)
+# the sixth configuration's attention layer (lfm2-24b-a2b-5l: 8 lanes, 32
+# heads over 8 kv heads of 64, 5121 pages of 16, 1024 a sequence).  A
+# pool 64 wide is half a lane tile: the k/v write's flat view of it is a
+# copy, not a bitcast, and the launch pads it to 128 (hd=64 here shows
+# it), so the program pads heads of 64 to 128 (generation._pool_width),
+# which is the geometry guarded here
+_HD64_LAYER = dict(b=8, nh=32, nkv=8, hd=128, pages=5121, ps=16, ppseq=1024)
 
 
 def _pool_copies(text: str, pool) -> int:
@@ -557,26 +564,28 @@ def _check_packed_rows(rehearse: bool) -> dict:
 
 
 def _check_pool_copies(engine, rehearse: bool) -> dict:
-    """Whole-pool copies in the compiled decode-only step.  At heads of
-    128 there must be none: the k/v write is a row scatter into the
-    pool seen as ``[nkv * P * ps, hd]``, whose layout is the Mosaic
-    call's.  The smoke's own heads of 96 are not a whole lane tile and
-    the compiler still re-lays such a pool around the kernel: counted
-    and reported, not failed.  The CPU backend donates nothing and has
-    layouts of its own, so the rehearsal only exercises the code."""
+    """Whole-pool copies in the compiled decode-only step.  There must
+    be none: the k/v write is a row scatter into the pool seen as
+    ``[nkv * P * ps, hd]``, whose layout is the Mosaic call's, and every
+    pool is a whole number of lane tiles wide (the smoke's own heads of
+    96, the batch cell's of 128, the sixth configuration's of 64 in
+    pools of 128).  The CPU backend donates nothing and has layouts of
+    its own, so the rehearsal only exercises the code."""
     own = engine._program(1).lower(
         *_engine_program_args(engine, 1)).compile()
     n_own = _pool_copies(own.as_text(), engine._pools[0][0])
-    geometry = dict(_HD128_LAYER)
-    if rehearse:
-        geometry.update(pages=33, ppseq=4)
-    layer, pool = _compile_serve_layer(1, **geometry)
-    n_128 = _pool_copies(layer.as_text(), pool)
+    narrow = {}
+    for name, geometry in (("hd128", _HD128_LAYER), ("hd64", _HD64_LAYER)):
+        geometry = dict(geometry)
+        if rehearse:
+            geometry.update(pages=33, ppseq=4)
+        layer, pool = _compile_serve_layer(1, **geometry)
+        narrow[name] = _pool_copies(layer.as_text(), pool)
     print(f"  whole-pool copies in the compiled Q=1 programs: "
           f"{n_own} in the engine's step (pools "
-          f"{list(engine._pools[0][0].shape)}), {n_128} in one layer at "
-          f"the batch cell's geometry (pools {list(pool.shape)})",
-          flush=True)
+          f"{list(engine._pools[0][0].shape)}), {narrow['hd128']} in one "
+          f"layer at the batch cell's geometry, {narrow['hd64']} in one at "
+          f"heads of 64 in pools of 128", flush=True)
     # the third configuration's two kinds of layer: keys of 192 in pools
     # padded to 256, values of 128; a ring, a window and sinks in one
     wide = {}
@@ -590,12 +599,20 @@ def _check_pool_copies(engine, rehearse: bool) -> dict:
     print(f"  and in one full and one window layer at keys of 192 "
           f"(pools 256 wide): {wide}", flush=True)
     if not rehearse:
-        _check(n_128 == 0, "the k/v write and the ragged kernel at heads "
-                           "of 128 compile with no copy of a page pool")
+        _check(n_own == 0, "the engine's own step at heads of 96 in pools "
+                           "of 128 compiles with no copy of a page pool")
+        _check(narrow["hd128"] == 0,
+               "the k/v write and the ragged kernel at heads of 128 "
+               "compile with no copy of a page pool")
+        _check(narrow["hd64"] == 0,
+               "a layer at heads of 64 in pools of 128 compiles with no "
+               "copy of a page pool")
         _check(not any(wide.values()),
                "a full and a window layer at keys of 192 padded to 256 "
                "compile with no copy of a key pool")
-    return {"pool_copies_q1": n_own, "pool_copies_q1_hd128_layer": n_128,
+    return {"pool_copies_q1": n_own,
+            "pool_copies_q1_hd128_layer": narrow["hd128"],
+            "pool_copies_q1_hd64_layer": narrow["hd64"],
             "pool_copies_q1_key192_full_layer": wide["full"],
             "pool_copies_q1_key192_window_layer": wide["window"]}
 

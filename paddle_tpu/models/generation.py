@@ -1413,16 +1413,18 @@ def _linear_attention_rows(lp, u, kind: LinearAttentionKind, kept, rows,
 
 
 def _pool_width(dim: int) -> int:
-    """The last axis of a page pool for rows of ``dim``.  A head wider
-    than the 128-lane tile and not a whole number of tiles (keys of
-    192) is padded up to one: the compiler's own
-    layout for ``f32[nkv, P, ps, 192]`` is not the row-major one the
-    Mosaic call takes, and every step would re-lay each such pool twice
-    (two whole-pool copies a pool a step at Q=1 and Q=1024, sandbox AOT
-    for a v5e, PR 27; none at 256).  The price is pool memory and key
-    bytes read: a third more at 192.  Narrower heads stay as they are
-    (heads of 96 copy too; ROADMAP S15)."""
-    return dim if dim <= 128 else -(-dim // 128) * 128
+    """The last axis of a page pool for rows of ``dim``: ``dim`` rounded
+    up to a whole number of 128-lane tiles, whatever ``dim`` is.  The
+    compiler's own layout for a pool of any other width (``f32[nkv, P,
+    ps, 192]``, or 64 or 96) is not the row-major one the Mosaic call
+    takes: the k/v write's flat view stops being a bitcast, the launch
+    pads the pool to whole tiles, and every step re-lays such pools two
+    or three times over (a compile for a v5e: two whole-pool copies a
+    key pool a step at keys of 192, and a copy, a flat copy and a pad a
+    step at heads of 64; none at a whole number of tiles).  The price
+    is pool memory and key bytes read: a third more at 192, twice as
+    much at 64."""
+    return -(-dim // 128) * 128
 
 
 def _pad_last(a, width: int):

@@ -516,9 +516,10 @@ def _ragged_call(q, k_pages, v_pages, kv_lens, q_lens, offs, page_tables,
     i32 = jnp.int32
     hdv = v_pages.shape[-1]
     # a copy out of a pool moves whole 128-lane tiles (Mosaic refuses a
-    # slice 96 wide): heads of another width are padded for the call,
-    # which copies such a pool as the compiler's own re-lay of it did
-    # (ROADMAP S15); the zeros add nothing to q.k
+    # slice 96 wide).  The serving step's pools are whole tiles wide
+    # already (generation._pool_width), so this is no operation there;
+    # a direct caller's pools of another width are padded for the call,
+    # a copy of each pool a launch.  The zeros add nothing to q.k
     q, k_pages, v_pages = (
         a if a.shape[-1] % 128 == 0 else jnp.pad(
             a, ((0, 0),) * (a.ndim - 1) + ((0, -a.shape[-1] % 128),))

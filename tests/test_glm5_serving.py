@@ -171,9 +171,11 @@ def test_step_matches_reference_through_chunks_that_cross_top_k(model, rng):
     step = Step(model, seqs)
     assert step.cache.n_latent == 3 and step.cache.n_full == 0 \
         and step.cache.n_state == 0 and step.cache.window is None
-    assert step.cache.layers[0] == LatentPages((40, 16))
+    # the latent row of 40 and the index key of 16 each in a pool of a
+    # whole lane tile (generation._pool_width)
+    assert step.cache.layers[0] == LatentPages((128, 128))
     assert [tuple(a.shape for a in kept) for kept in step.pools] \
-        == [((1, 29, 4, 40), (1, 29, 4, 16))] * 3
+        == [((1, 29, 4, 128), (1, 29, 4, 128))] * 3
     got = step.run([51, 10], 14)
     for i, s in enumerate(seqs):
         assert len(got[i]) == (9, 13)[i]       # a chunk's last row too

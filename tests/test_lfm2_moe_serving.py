@@ -92,13 +92,14 @@ def _reference(model, ids, dtype=jnp.float32, omit=()):
 
 def test_the_description_and_the_caches(model):
     """Four conv layers keep a tail of two rows a lane and nothing else,
-    the attention layer's heads of 16 a pool pair; every attention layer
-    norms q and k, the leading layer is dense."""
+    the attention layer's heads of 16 a pool pair, each a whole lane
+    tile wide; every attention layer norms q and k, the leading layer is
+    dense."""
     _, step = model.build_ragged_decode_step()
     cache = step.cache
     assert cache.n_state == 4 and cache.n_full == 1
     assert cache.layers[0] == LaneState(((2, 64),))
-    assert cache.layers[1] == (2, 16, 16, None)
+    assert cache.layers[1] == (2, 128, 128, None)
     assert [tuple(a.shape for a in p) for p in
             cache.new_pools(9, 4, "float32", 3)[2:]] == [((3, 2, 64),)] * 3
     md = model.config.description()
@@ -244,6 +245,30 @@ def test_engine_serves_mixed_lengths_as_the_step_fed_by_hand(
     assert all(e["state_lanes"] == e["batch"] for e in steps)
     assert stats["state_lanes"] > 0 and stats["scan_rows"] == 0
     assert sorted(engine.scheduler._free_slots) == [0, 1, 2]
+
+
+def test_engine_at_heads_of_64_serves_the_reference_greedy_tokens(rng):
+    """The cell's attention geometry — 8 kv heads of 64 — at a small
+    hidden width: the engine's pools are ``[8, P, ps, 128]`` (heads of 64
+    padded to a whole lane tile, ``generation._pool_width``), and a
+    prompt fed in chunks of 16 that cross a page and a chunk boundary
+    decodes the plain reference's greedy tokens."""
+    paddle.seed(13)
+    m = Lfm2MoeForCausalLM(_config(hidden_size=512, num_attention_heads=8,
+                                   num_key_value_heads=8))
+    _reseed(m, 6)
+    m.eval()
+    engine = ServingEngine(m, max_batch=8, page_size=16, num_pages=33,
+                           max_prefill_chunk=16, prefix_caching=False)
+    assert [tuple(a.shape) for a in engine._pools[1]] == [(8, 33, 16, 128)] * 2
+    prompt = rng.randint(0, VOCAB, (37,)).tolist()
+    with engine:
+        got = engine.submit(prompt, max_new_tokens=6).wait(timeout=300)
+    # greedy from the reference: each new token is the argmax of the
+    # reference's logits over the prompt and the tokens before it
+    want = _reference(m, np.asarray(prompt + got[:-1]))
+    assert got == [int(np.argmax(want[len(prompt) - 1 + i]))
+                   for i in range(6)]
 
 
 def test_prefix_cache_and_fused_window_refuse_a_model_that_keeps_tails(

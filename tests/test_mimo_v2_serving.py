@@ -271,19 +271,20 @@ def test_window_layers_pool_bytes_do_not_depend_on_the_positions():
     window_64k, full_64k = _window_pool_bytes(65536)
     assert window_8k == window_64k
     assert full_64k > 7 * full_8k
-    # a ring of ceil((8 + 64) / 16) + 1 = 6 pages a lane, and the sink
-    assert window_8k == 2 * (2 * 6 + 1) * 16 * (24 + 16) * 4
+    # a ring of ceil((8 + 64) / 16) + 1 = 6 pages a lane, and the sink;
+    # keys of 24 and values of 16 each in pools a lane tile wide
+    assert window_8k == 2 * (2 * 6 + 1) * 16 * (128 + 128) * 4
 
 
 def test_a_key_wider_than_a_lane_tile_gets_a_pool_of_whole_tiles(rng):
     """Keys of 136 live in pools 256 wide (``generation._pool_width``),
-    and the padding changes no logit."""
+    values of 16 in pools 128 wide, and the padding changes no logit."""
     paddle.seed(5)
     m = MiMoV2ForCausalLM(_config(head_dim=136, hybrid_layer_pattern=[0, 1],
                                   moe_layer_freq=[0, 1]))
     m.eval()
     _, step = m.build_ragged_decode_step()
-    assert [layer[1:3] for layer in step.cache.layers] == [(256, 16)] * 2
+    assert [layer[1:3] for layer in step.cache.layers] == [(256, 128)] * 2
     seq = rng.randint(0, VOCAB, (30,))
     got, _, _ = _through_the_step(m, [seq], [21], 8, 4)
     want = _reference(m, seq)
@@ -299,7 +300,8 @@ def test_gpt_and_llama_pools_from_the_description_are_todays_shapes():
         max_position_embeddings=64, hidden_dropout_prob=0.0,
         attention_dropout_prob=0.0))
     llama = LlamaForCausalLM(llama_config("tiny"))
-    for m, nkv, hd, layers in ((gpt, 4, 8, 2), (llama, 2, 16, 2)):
+    # heads of 8 and 16, each in pools a whole lane tile wide
+    for m, nkv, hd, layers in ((gpt, 4, 128, 2), (llama, 2, 128, 2)):
         _, step = m.build_ragged_decode_step()
         cache = step.cache
         assert isinstance(cache, CacheDescription)

@@ -123,3 +123,14 @@ def test_ragged_step_logits_are_the_eager_forwards(case, entry):
         mine = np.stack([g[i] for g in got])
         err = float(np.max(np.abs(mine - want)) / np.max(np.abs(want)))
         assert err <= 1e-5, (case, entry, i, err)
+
+
+@pytest.mark.parametrize("dim, width", [(16, 128), (64, 128), (96, 128),
+                                        (128, 128), (136, 256), (192, 256),
+                                        (576, 640)])
+def test_a_pool_is_a_whole_number_of_lane_tiles_wide(dim, width):
+    """Every head, narrower or wider than the 128-lane tile, gets a page
+    pool a whole number of tiles wide: the layout the ragged kernel's
+    Mosaic call takes, so the step neither re-lays nor pads a pool."""
+    from paddle_tpu.models.generation import _pool_width
+    assert _pool_width(dim) == width

@@ -39,7 +39,7 @@ def test_chip_smoke_rehearsal_is_green():
     assert set(detail["phases"]) == {"train", "serve"}
     # counted on the CPU too, asserted only where a chip compiles it
     assert {"pool_copies_q1", "pool_copies_q1_hd128_layer",
-            "pool_copies_q1_key192_full_layer",
+            "pool_copies_q1_hd64_layer", "pool_copies_q1_key192_full_layer",
             "pool_copies_q1_key192_window_layer", "pool_copies_packed"} \
         <= set(detail["phases"]["serve"])
     # the packed step's guard: rows beside q_width, no product at 8 x Q
@@ -140,6 +140,40 @@ def test_window_and_full_layers_of_wide_keys_compile_with_no_pool_copy():
         assert out[kind]["kernels"] == 1, out
         assert out[kind]["pool_copies"] == 0, out
     assert out["unpadded"]["pool_copies"] == 2, out
+
+
+_AOT_HD64_LAYERS = _AOT_SERVE_LAYER.replace(
+    """for qw in (1, 128):
+    compiled, pool = chip_smoke._compile_serve_layer(
+        qw, sharding=one_chip, **chip_smoke._HD128_LAYER)
+""", """for kind, geometry, qw, rows in (
+        ("q1", chip_smoke._HD64_LAYER, 1, None),
+        ("q1024", chip_smoke._HD64_LAYER, 1024, 1032),
+        ("unpadded", dict(chip_smoke._HD64_LAYER, hd=64), 1, None)):
+    compiled, pool = chip_smoke._compile_serve_layer(
+        qw, sharding=one_chip, rows=rows, **geometry)
+    qw = kind
+""")
+
+
+def test_layer_of_heads_of_64_compiles_with_no_pool_copy():
+    """The sixth configuration's attention layer (8 lanes, 32 heads over
+    8 kv heads of 64, 5121 pages of 16) in pools padded to 128, compiled
+    for a v5e: no copy of a pool at Q=1 nor as the Q=1024 step runs it
+    (1,032 packed rows).  The same layer left 64 wide re-lays its pools,
+    which is why ``generation._pool_width`` pads every head to whole
+    lane tiles."""
+    assert "_HD64_LAYER" in _AOT_HD64_LAYERS
+    proc = _run(["-c", _AOT_HD64_LAYERS], env={"JAX_PLATFORMS": "cpu"})
+    lines = proc.stdout.strip().splitlines()
+    if lines and lines[-1].startswith("NO_TOPOLOGY"):
+        pytest.skip(f"no v5e topology can be described here: {lines[-1]}")
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+    out = json.loads(lines[-1].removeprefix("RESULT "))
+    for kind in ("q1", "q1024"):
+        assert out[kind]["kernels"] == 1, out
+        assert out[kind]["pool_copies"] == 0, out
+    assert out["unpadded"]["pool_copies"] >= 2, out
 
 
 _AOT_PACKED_STEP = _AOT_SERVE_LAYER.replace(
