@@ -128,6 +128,7 @@ EVENT_SCHEMA: Dict[str, Dict[str, str]] = {
                    "wait_s": "float", "admit_queue_s": "object",
                    "expert_rows": "int", "expert_rows_max": "int",
                    "experts_hit": "int", "expert_kernel_layers": "int",
+                   "scan_kernel_layers": "int",
                    "window_pages_read": "int",
                    "full_pages_read": "int", "attn_blocks": "int",
                    "attn_tiles": "int", "attn_tile_slots": "int",

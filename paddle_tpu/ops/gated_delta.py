@@ -27,10 +27,15 @@ slot that has no row in the step as it was.
       S_C = diag(exp(G_C)) S_0 + (K exp(G_C - G))^T U~
 
   with ``G`` the running sum of ``g`` inside the block, ``K+ = K
-  exp(G)``, ``K- = K exp(-G)``, ``B = diag(beta)``.  Everything that
-  does not hold ``S_0`` (``T V``, ``T K+``, the two triangles) is
-  computed for all blocks at once; a loop over the blocks, as many
-  turns as the step has blocks, carries the state through them.  The
+  exp(G)``, ``K- = K exp(-G)``, ``B = diag(beta)``.  On a TPU (and in
+  interpret mode), for an even number of heads, the blocks run as ONE
+  kernel, ``pallas/gated_delta_scan.py``, that reads them from the
+  packed rows, walks them in order and keeps a sequence's state in VMEM
+  from its first block to its last (``gated_delta_scan.takes``).
+  Elsewhere the XLA form runs on the rows gathered into blocks:
+  everything that does not hold ``S_0`` (``T V``, ``T K+``, the two
+  triangles) for all blocks at once, then a loop over the blocks, as
+  many turns as the step has blocks, that carries the state.  The
   products ``K+ K-^T`` are taken about the block's middle row
   (``exp(G - G_mid)``, ``exp(G_mid - G)``) so that neither factor
   leaves float32's range while the log decay summed over HALF a block
@@ -51,6 +56,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from .pallas import gated_delta_scan
 
 __all__ = ["short_conv_rows", "gated_delta_step", "gated_delta_chunks",
            "chunk_blocks"]
@@ -146,9 +153,9 @@ def gated_delta_chunks(state, q, k, v, g, beta, offs, q_lens, slot,
     row, in place.  Arguments as :func:`gated_delta_step` (a sequence
     that starts has been cleared by it); ``lane, at [n]`` each row's
     sequence and index in its chunk.  Returns ``(o [n, H, dv],
-    state')``: a row of a sequence that feeds one row or none holds
-    anything."""
-    n, nh, dk = q.shape
+    state')``: a row of a sequence that feeds one row or none, or past
+    its chunk's end, holds anything finite."""
+    n, nh, _ = q.shape
     dv = v.shape[-1]
     c = int(block)
     nb = chunk_blocks(n, offs.shape[0], c)
@@ -160,18 +167,59 @@ def gated_delta_chunks(state, q, k, v, g, beta, offs, q_lens, slot,
     # a block's sequence: the last one whose blocks start at or before it
     of = jnp.clip(jnp.sum(j[:, None] >= first[None, :], axis=1,
                           dtype=i32) - i32(1), 0, offs.shape[0] - 1)
-    r = ((j - first[of]) * i32(c))[:, None] \
-        + jnp.arange(c, dtype=i32)[None, :]                  # [nb, C]
-    ok = (j < total)[:, None] & (r < q_lens[of][:, None])
-    src = jnp.clip(offs[of][:, None] + r, 0, n - 1)
+    # a block's first row, and its rows (at most C; none past the live
+    # blocks)
+    begin = offs[of] + (j - first[of]) * i32(c)
+    count = jnp.where(j < total, jnp.clip(
+        q_lens[of] - (j - first[of]) * i32(c), 0, c), i32(0))
+    i = jnp.arange(c, dtype=i32)[None, :]
+    if gated_delta_scan.takes(nh):
+        # the kernel reads a block's C rows from the packed arrays itself,
+        # from ``start``: a block that would run past the last row is
+        # pulled back to end there, its own rows ``lo`` rows in
+        start = jnp.minimum(begin, i32(n - c))
+        lo = begin - start
+        live = (i >= lo[:, None]) & (i < (lo + count)[:, None])
+        bb = jnp.where(live[:, None, :], jnp.swapaxes(
+            beta[start[:, None] + i], 1, 2), 0.0)            # [nb, H, C]
+        marks = jnp.stack([slot[of], (j == first[of]).astype(i32),
+                           (j == first[of] + blocks[of] - 1).astype(i32),
+                           start, lo, lo + count])
+        out, state = gated_delta_scan.gated_delta_scan(
+            state, q, k, v, g, bb, marks, total)
+    else:
+        lo = jnp.zeros_like(j)
+        live = i < count[:, None]
+        src = jnp.clip(begin[:, None] + i, 0, n - 1)         # [nb, C]
 
-    def heads_first(a):                   # [nb, C, H, d] -> [nb, H, C, d]
-        return jnp.swapaxes(a[src], 1, 2)
+        def heads_first(a):               # [nb, C, H, d] -> [nb, H, C, d]
+            return jnp.swapaxes(a[src], 1, 2)
 
-    qb, kb, vb = heads_first(q), heads_first(k), heads_first(v)
-    okh = ok[:, None, :]                                     # [nb, 1, C]
-    gb = jnp.where(okh[..., None], heads_first(g), 0.0)
-    bb = jnp.where(okh, jnp.swapaxes(beta[src], 1, 2), 0.0)  # [nb, H, C]
+        okh = live[:, None, :]                               # [nb, 1, C]
+        out, state = _scan_blocks(
+            state, heads_first(q), heads_first(k), heads_first(v),
+            jnp.where(okh[..., None], heads_first(g), 0.0),
+            jnp.where(okh, jnp.swapaxes(beta[src], 1, 2), 0.0),
+            slot[of], total)
+    # back to rows: row ``at`` of sequence ``lane`` sits in that
+    # sequence's block ``at // C`` at ``lo + at % C``; a row past its
+    # chunk's end can land in a block past the live ones, which the
+    # kernel never writes, and reads 0 there as the XLA form's does
+    held = jnp.clip(first[lane] + at // i32(c), 0, nb - 1)
+    where = jnp.clip(held * i32(c) + lo[held] + at % i32(c), 0, nb * c - 1)
+    flat = jnp.swapaxes(out, 1, 2).reshape(nb * c, nh, dv)
+    return jnp.where((where < total * i32(c))[:, None, None], flat[where],
+                     0.0), state
+
+
+def _scan_blocks(state, qb, kb, vb, gb, bb, slot_of, total):
+    """The XLA form of the chunked scan over blocks heads first (``qb,
+    kb, gb [nb, H, C, dk]``, ``vb [nb, H, C, dv]``, ``bb [nb, H, C]``;
+    block ``i`` of slot ``slot_of[i]``, the first ``total`` live):
+    ``(out [nb, H, C, dv], state')``, what
+    ``pallas.gated_delta_scan`` computes as one kernel."""
+    nb, nh, c, _ = qb.shape
+    dv = vb.shape[-1]
     run = jnp.cumsum(gb, axis=2)                             # G
     mid = run[:, :, c // 2:c // 2 + 1]
     up, down = jnp.exp(run - mid), jnp.exp(mid - run)
@@ -188,7 +236,6 @@ def gated_delta_chunks(state, q, k, v, g, beta, offs, q_lens, slot,
         lower, bb[..., None] * jnp.concatenate([vb, k_plus], axis=-1),
         lower=True, unit_diagonal=True)
     u_all, w_all = solved[..., :dv], solved[..., dv:]
-    slot_of = slot[of]
 
     def one_block(i, carry):
         st, out = carry
@@ -204,8 +251,4 @@ def gated_delta_chunks(state, q, k, v, g, beta, offs, q_lens, slot,
     state, out = jax.lax.fori_loop(
         i32(0), total, one_block,
         (state, jnp.zeros((nb, nh, c, dv), state.dtype)))
-    # back to rows: row ``at`` of sequence ``lane`` sits in that
-    # sequence's block ``at // C`` at ``at % C``
-    where = (first[lane] + at // i32(c)) * i32(c) + at % i32(c)
-    flat = jnp.swapaxes(out, 1, 2).reshape(nb * c, nh, dv)
-    return flat[jnp.clip(where, 0, nb * c - 1)], state
+    return out, state

@@ -393,11 +393,14 @@ class ServingEngine:
         # layers that keep something a running sequence owns alone, a
         # ring or a state: the sequence then holds one of max_batch slots
         self._n_state = self._cache.n_state
-        # whether a chunk of more than one row takes the chunked form of
-        # a linear-attention layer (a short-convolution layer, the other
-        # kind of state layer, has none)
-        self._scans = any(d.linear_attention is not None
-                          for d in cfg.description().layers)
+        # the heads of each linear-attention layer: whether a chunk of
+        # more than one row takes the chunked form of such a layer (a
+        # short-convolution layer, the other kind of state layer, has
+        # none), and whether that form is the kernel
+        # (_scan_kernel_layers)
+        self._linear_heads = tuple(
+            d.linear_attention.heads for d in cfg.description().layers
+            if d.linear_attention is not None)
         slotted = bool(self._ring_pages or self._n_state)
         if slotted and prefix_caching:
             raise ValueError(
@@ -562,6 +565,9 @@ class ServingEngine:
         # expert layers the steps ran as the kernel that reads a picked
         # expert's weights itself (_expert_kernel_layers)
         self._n_expert_kernel_layers = 0
+        # linear-attention layers the steps ran as the chunked scan's
+        # kernel (_scan_kernel_layers)
+        self._n_scan_kernel_layers = 0
         # what the steps' rows asked of a latent layer's index
         # (_select_counts)
         self._select_rows = self._keys_visible = self._keys_selected = 0
@@ -1142,6 +1148,8 @@ class ServingEngine:
             self._keys_selected += select[2]
             kernel_layers = self._expert_kernel_layers(plan)
             self._n_expert_kernel_layers += kernel_layers
+            scan_layers = self._scan_kernel_layers(plan)
+            self._n_scan_kernel_layers += scan_layers
             now = time.monotonic()
             for i, seq in enumerate(plan.seqs):
                 if seq.req.done:
@@ -1187,7 +1195,8 @@ class ServingEngine:
                 routing=toks[self.max_batch:], ahead=flight.ahead,
                 span=flight.span, state=state, attn_blocks=blocks,
                 attn_tiles=tiles, attn_keys=keys, select=select,
-                expert_kernel_layers=kernel_layers)
+                expert_kernel_layers=kernel_layers,
+                scan_kernel_layers=scan_layers)
         flight.span.end()
 
     def _state_counts(self, plan):
@@ -1203,7 +1212,7 @@ class ServingEngine:
         q = plan.q_lens
         return (int((q > 0).sum()),
                 int(((q > 0) & (plan.kv_lens == q)).sum()),
-                int(q[q > 1].sum()) if self._scans else 0)
+                int(q[q > 1].sum()) if self._linear_heads else 0)
 
     def _select_counts(self, plan):
         """``(select_rows, keys_visible, keys_selected)`` of ONE latent
@@ -1307,13 +1316,26 @@ class ServingEngine:
         return sum(layers for top_k, layers in self._expert_layers
                    if narrow_step(plan.rows, top_k))
 
+    def _scan_kernel_layers(self, plan) -> int:
+        """The step's linear-attention layers whose chunked form ran as
+        the kernel that keeps a sequence's state in VMEM, by the op's own
+        rule: a program wider than a token holds the chunked form
+        (``generation._linear_attention_rows``), and the kernel takes
+        the layer's heads (``ops.pallas.gated_delta_scan.takes``).  Host
+        arithmetic: all of them in a step with a chunk, none in a
+        decode-only step."""
+        from ..ops.pallas.gated_delta_scan import takes
+        return sum(takes(heads) for heads in self._linear_heads) \
+            if plan.q_width > 1 else 0
+
     def _emit_batch_step(self, phase_seconds, plan, prefill_seqs,
                          q_width, tokens, step_s, cold_start,
                          fused_steps, exit_reason, routing=(),
                          ahead=False, span=None, state=(0, 0, 0),
                          attn_blocks=0, attn_tiles=(0, 0),
                          attn_keys=(0, 0), select=(0, 0, 0),
-                         expert_kernel_layers=0) -> None:
+                         expert_kernel_layers=0,
+                         scan_kernel_layers=0) -> None:
         """The step's ``batch_step`` record (under ``_wake``;
         ``phase_seconds`` from ``_LoopPhases.take``; ``span`` the step's
         own where no ambient one covers it).  step_s +
@@ -1352,6 +1374,7 @@ class ServingEngine:
                      expert_rows_max=expert_rows_max,
                      experts_hit=experts_hit,
                      expert_kernel_layers=expert_kernel_layers,
+                     scan_kernel_layers=scan_kernel_layers,
                      window_pages_read=window_pages,
                      full_pages_read=full_pages,
                      attn_blocks=attn_blocks,
@@ -1835,6 +1858,7 @@ class ServingEngine:
                "attn_keys_full": self._attn_keys_full,  # noqa: PTL902 — advisory snapshot (see below)
                "attn_keys_window": self._attn_keys_window,  # noqa: PTL902 — advisory snapshot (see below)
                "expert_kernel_layers": self._n_expert_kernel_layers,  # noqa: PTL902 — advisory snapshot (see below)
+               "scan_kernel_layers": self._n_scan_kernel_layers,  # noqa: PTL902 — advisory snapshot (see below)
                "select_rows": self._select_rows,  # noqa: PTL902 — advisory snapshot (see below)
                "keys_visible": self._keys_visible,  # noqa: PTL902 — advisory snapshot (see below)
                "keys_selected": self._keys_selected,  # noqa: PTL902 — advisory snapshot (see below)

@@ -303,7 +303,6 @@ def test_q1024_attention_layer_holds_no_lanes_by_width_array():
 _AOT_STATE_LAYER = """
 import json
 import os
-import re
 import types
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 import jax
@@ -363,7 +362,10 @@ for qw in (1, 128):
     text = compiled.as_text()
     ma = compiled.memory_analysis()
     out[qw] = {"state_copies": chip_smoke._pool_copies(text, state),
-               "whiles": len(re.findall(r" while\\(", text)),
+               "scan_kernels": sum("tpu_custom_call" in l
+                                   and "gated_delta_scan" in l
+                                   for l in text.splitlines()),
+               "solves": text.count("InvertDiagBlocksLowerTriangular"),
                "temp_bytes": ma.temp_size_in_bytes,
                "alias_bytes": ma.alias_size_in_bytes,
                "state_bytes": state.size * 4}
@@ -375,10 +377,10 @@ def test_state_layer_compiles_for_v5e_and_updates_the_state_in_place():
     """The engine's own programs for one linear-attention layer at the
     published widths (a state of 8 x 64 x 128 x 128 float32, donated),
     compiled ahead of time for a v5e: neither the decode-only program
-    (the one-token update) nor a prefill program (the chunked scan,
-    whose loop carries the state) holds a layout copy of the state, both
-    hand the donated state back in its own buffer, and the decode-only
-    program's temporaries stay under one state array."""
+    (the one-token update) nor a prefill program (the chunked scan, one
+    kernel that carries the state in VMEM) holds a layout copy of the
+    state, both hand the donated state back in its own buffer, and the
+    decode-only program's temporaries stay under one state array."""
     proc = _run(["-c", _AOT_STATE_LAYER], env={"JAX_PLATFORMS": "cpu"})
     lines = proc.stdout.strip().splitlines()
     if lines and lines[-1].startswith("NO_TOPOLOGY"):
@@ -390,7 +392,11 @@ def test_state_layer_compiles_for_v5e_and_updates_the_state_in_place():
         assert out[qw]["state_copies"] == 0, out
         # the state and the convolution's tail come back in place
         assert out[qw]["alias_bytes"] >= out[qw]["state_bytes"], out
-    assert out["1"]["whiles"] < out["128"]["whiles"], out   # no scan at Q=1
+    # the chunked scan is one kernel in the prefill program, with no
+    # triangular solve of XLA's beside it, and the decode-only program
+    # holds none
+    assert out["1"]["scan_kernels"] == 0 and out["128"]["scan_kernels"] == 1
+    assert out["1"]["solves"] == out["128"]["solves"] == 0, out
     assert out["1"]["temp_bytes"] < out["1"]["state_bytes"], out
 
 

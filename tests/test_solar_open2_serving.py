@@ -183,12 +183,12 @@ def _recurrence(state, q, k, v, g, beta):
     return np.stack(out), state
 
 
-def test_chunked_form_and_one_token_update_match_the_recurrence(rng):
-    """One layer's state over packed rows: a chunk of 70 rows (blocks of
-    64 and 6), a single row, an idle lane and a chunk of 5 rows that
-    starts its sequence; slots are not lanes, and the slot of the idle
-    lane and the slot nobody owns must not move."""
-    nh, d, n_slots = 2, 8, 6
+def _one_layer_rows(rng, nh, d):
+    """One layer's packed rows: a chunk of 70 rows (past a whole block
+    it ends mid-block), a single row, an idle lane and a chunk of 5 rows
+    that starts its sequence; slots are not lanes.  Returns the
+    arguments of both forms and the state before them."""
+    n_slots = 6
     q_lens = np.asarray([70, 1, 0, 5], "int32")
     offs = np.cumsum(q_lens) - q_lens
     slot = np.asarray([3, 0, 5, 2], "int32")
@@ -203,31 +203,72 @@ def test_chunked_form_and_one_token_update_match_the_recurrence(rng):
     g = -rng.uniform(0.001, 0.7, (n, nh, d)).astype("float32")
     beta = rng.uniform(0.0, 2.0, (n, nh)).astype("float32")
     beta[76:] = 0.0                          # as the step masks them
-    state = f(n_slots, nh, d, d)
+    rows = (q, k, v, g, beta, offs, q_lens, slot)
+    return rows, reset, lane, at, f(n_slots, nh, d, d)
 
-    @jax.jit
-    def both(state):
-        args = [jnp.asarray(a) for a in (q, k, v, g, beta, offs, q_lens,
-                                         slot)]
-        o_slot, st = gd.gated_delta_step(state, *args, jnp.asarray(reset))
-        o_rows, st = gd.gated_delta_chunks(st, *args, jnp.asarray(lane),
-                                           jnp.asarray(at), 64)
-        return o_slot, o_rows, st
 
-    o_slot, o_rows, new = (np.asarray(a) for a in both(jnp.asarray(state)))
+def _both_forms(rows, reset, lane, at, state, block, kernel):
+    """``(o_slot, o_rows, state')`` of the one-token update and then the
+    chunked form, the latter as the kernel (interpreted) or XLA's."""
+    keep = get_flags(["FLAGS_pallas_interpret"])
+    set_flags({"FLAGS_pallas_interpret": kernel})
+    try:
+        @jax.jit
+        def both(state):
+            args = [jnp.asarray(a) for a in rows]
+            o_slot, st = gd.gated_delta_step(state, *args,
+                                             jnp.asarray(reset))
+            o_rows, st = gd.gated_delta_chunks(
+                st, *args, jnp.asarray(lane), jnp.asarray(at), block)
+            return o_slot, o_rows, st
+
+        return [np.asarray(a) for a in both(jnp.asarray(state))]
+    finally:
+        set_flags(keep)
+
+
+@pytest.mark.parametrize("block,nh", [(64, 2), (32, 2), (16, 2), (8, 2),
+                                      (2, 2), (64, 16), (64, 3)])
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
+def test_chunked_form_and_one_token_update_match_the_recurrence(
+        rng, block, nh, kernel):
+    """One layer's state over packed rows (``_one_layer_rows``): blocks
+    of 64 (the chunk of 70 is 64 + 6) and of 32 (32 + 32 + 6), and the
+    blocks of 16, 8 and 2 rows of the programs narrower than 32 rows;
+    block slots past the live ones, blocks that end past the last row
+    (the kernel reads them from 16 or 48 rows on), rows past the last
+    chunk's end that land in a block past the live ones (finite there),
+    two groups of 8 heads at 16, and 3 heads, which do not pair up and
+    keep XLA's form; the chunked form as XLA's and as the kernel
+    (interpreted, and against XLA's), both against the recurrence; the
+    slot of the idle lane and the slot nobody owns must not move."""
+    d = 8
+    rows, reset, lane, at, state = _one_layer_rows(rng, nh, d)
+    q, k, v, g, beta, offs, q_lens, slot = rows
+    o_slot, o_rows, new = _both_forms(rows, reset, lane, at, state, block,
+                                      kernel)
+    assert np.isfinite(o_rows).all()
+    if kernel:
+        _, xla_rows, xla_new = _both_forms(rows, reset, lane, at, state,
+                                           block, False)
+        wide = q_lens[lane] > 1
+        np.testing.assert_allclose(o_rows[wide], xla_rows[wide],
+                                   rtol=2e-4, atol=2e-5)
+        np.testing.assert_allclose(new, xla_new, rtol=2e-4, atol=2e-5)
     for b, (lo, cnt, s) in enumerate(zip(offs, q_lens, slot)):
         if not cnt:
             continue
         start = np.zeros_like(state[s]) if reset[b] else state[s]
-        rows = slice(lo, lo + cnt)
-        want_o, want_s = _recurrence(start, q[rows], k[rows], v[rows],
-                                     g[rows], beta[rows])
-        got_o = o_slot[s][None] if cnt == 1 else o_rows[rows]
+        span = slice(lo, lo + cnt)
+        want_o, want_s = _recurrence(start, q[span], k[span], v[span],
+                                     g[span], beta[span])
+        got_o = o_slot[s][None] if cnt == 1 else o_rows[span]
         np.testing.assert_allclose(got_o, want_o, rtol=2e-4, atol=2e-5)
         np.testing.assert_allclose(new[s], want_s, rtol=2e-4, atol=2e-5)
     for s in (5, 1, 4):                      # the idle lane's, nobody's
         np.testing.assert_array_equal(new[s], state[s])
     assert gd.chunk_blocks(80, 4, 64) == 5 and gd.chunk_blocks(8, 8, 1) == 8
+    assert gd.chunk_blocks(80, 4, 32) == 6
 
 
 def test_short_convolution_carries_its_tail_from_chunk_to_chunk(rng):
@@ -356,8 +397,12 @@ def test_the_records_count_the_expert_layers_that_took_the_kernel(
     step (its 3 rows of 4 picks fit one tile, so a taken branch is the
     kernel that reads the expert's weights itself — interpreted here),
     none of a step with a chunk (128 rows of 4 picks do not), none at
-    all where the kernel is not available; ``engine.stats()`` sums it,
-    and the tokens are XLA's either way."""
+    all where the kernel is not available; ``scan_kernel_layers``: the
+    other way round, every linear-attention layer of a step with a chunk
+    (its chunked form is the kernel that keeps the state in VMEM; eight
+    layers of the benchmark's configuration hold six, these four three),
+    none of a decode-only step; ``engine.stats()`` sums both, and the
+    tokens are XLA's either way."""
     from paddle_tpu.observability import events as obs_events
     paddle.seed(5)
     model = SolarOpen2ForCausalLM(_config(num_experts_per_tok=4))
@@ -387,6 +432,13 @@ def test_the_records_count_the_expert_layers_that_took_the_kernel(
                for e in steps if e["q_width"] > 1)
     assert stats["expert_kernel_layers"] == \
         sum(e["expert_kernel_layers"] for e in steps)
+    wide = [e for e in steps if e["q_width"] > 1]
+    assert all(e["scan_kernel_layers"] == (3 if interpret else 0)
+               for e in wide)
+    assert all(e["scan_kernel_layers"] == 0 for e in narrow)
+    assert stats["scan_kernel_layers"] == \
+        sum(e["scan_kernel_layers"] for e in steps) \
+        == (3 * len(wide) if interpret else 0)
 
 
 def test_eviction_and_resume_reproduce_the_tokens(model, rng):
